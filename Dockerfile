@@ -30,6 +30,9 @@ COPY --from=builder /usr/local/bin/pilosa-tpu /usr/local/bin/pilosa-tpu
 
 EXPOSE 10101
 VOLUME /data
+# The compile cache's directory is part of its key: one fixed path on
+# the volume, so a restarted container finds its programs again.
+ENV JAX_COMPILATION_CACHE_DIR=/data/.jax-compile-cache
 
 ENTRYPOINT ["pilosa-tpu"]
 CMD ["server", "--data-dir", "/data", "--bind", "0.0.0.0:10101"]

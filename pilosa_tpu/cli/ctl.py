@@ -106,7 +106,7 @@ def build_server(cfg: config_mod.Config):
         max_writes_per_request=cfg.max_writes_per_request,
         logger=logger,
         stats=stats,
-        compilation_cache_dir=_resolve_cache_dir(cfg),
+        compilation_cache_dir=cfg.tpu.compilation_cache_dir,
         prewarm=cfg.tpu.prewarm,
         stream_chunk_bytes=cfg.net.stream_chunk_bytes,
         slow_query_ms=cfg.obs.slow_query_ms,
@@ -176,17 +176,6 @@ def build_server(cfg: config_mod.Config):
         ingest_scatter=cfg.ingest.scatter,
         ingest_wal_segment_bytes=cfg.ingest.wal_segment_bytes,
     )
-
-
-def _resolve_cache_dir(cfg) -> str | None:
-    """tpu.compilation-cache-dir: "" -> <data-dir>/.jax-compile-cache,
-    "off" -> disabled, else the given path."""
-    raw = cfg.tpu.compilation_cache_dir
-    if raw == "off":
-        return None
-    if raw:
-        return os.path.expanduser(raw)
-    return os.path.join(os.path.expanduser(cfg.data_dir), ".jax-compile-cache")
 
 
 def run_server(args) -> int:
@@ -299,9 +288,9 @@ def run_warm(args) -> int:
     from pilosa_tpu.exec import warmup
 
     cfg = config_mod.load(args.config or None)
-    cache_dir = _resolve_cache_dir(cfg)
-    if cache_dir and warmup.enable_compile_cache(cache_dir):
-        print(f"compilation cache: {warmup.enabled_cache_dir()}", file=sys.stderr)
+    cache_dir = warmup.enable_compile_cache(cfg.tpu.compilation_cache_dir)
+    if cache_dir is not None:
+        print(f"compilation cache: {cache_dir}", file=sys.stderr)
     else:
         print(
             "warning: persistent compile cache disabled; warming only "

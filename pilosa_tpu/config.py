@@ -130,9 +130,8 @@ _KNOWN_KEYS = {
     "tpu.compilation-cache-dir",
     "tpu.prewarm",
     # Accepted and ignored: the Pallas kernel path this toggled was
-    # removed (measured 0.068x plain XLA, see ops/bitplane.py), but
-    # config files generated by earlier releases carry the key and
-    # must not be fatally rejected on upgrade.
+    # removed, but config files generated by earlier releases carry
+    # the key and must not be fatally rejected on upgrade.
     "tpu.use-pallas",
 }
 
@@ -449,8 +448,11 @@ class TPUConfig:
     """TPU-native additions (no reference counterpart)."""
 
     mesh_shape: str = ""  # e.g. "8" or "4x2"; empty = all local devices
-    # On-disk XLA compilation cache: "" = <data-dir>/.jax-compile-cache,
-    # "off" disables.  Kills the multi-second cold-query compile on
+    # On-disk XLA compilation cache: "" = one fixed path inside the
+    # checkout (<repo>/.jax-compile-cache — the path is part of the
+    # cache key, so it must not move between runs), "off" disables.
+    # JAX_COMPILATION_CACHE_DIR, when set, wins over both: the program
+    # then sets no directory in code.  Kills the cold-query compile on
     # process restart (the reference's cold path is an O(containers)
     # mmap open, fragment.go:154-242 — boot-latency parity in spirit).
     compilation_cache_dir: str = ""

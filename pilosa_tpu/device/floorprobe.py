@@ -5,9 +5,8 @@ the access pattern the bitmap kernels actually have — a jitted
 read-everything reduction over a contiguous uint32 buffer (HBM → VMEM →
 VPU, no MXU).  The mean across devices becomes the roofline denominator
 (``device.streamFloorGbps``): ``exec.launch.floorPct[site:*]`` is
-achieved GB/s over THIS number, which is the online version of the
-``bench.py`` stream-floor measurement ROADMAP item 2 tracks (BENCH_r05:
-390.5 GB/s achieved vs 602.8 GB/s floor = 64.8%).
+achieved GB/s over THIS number, the online version of the stream-floor
+measurement ``bench.py`` takes.
 
 The probe runs once per process per backend (in-memory cache) and is
 additionally cached in the server's artifact dir (``floorprobe.json``)
@@ -135,36 +134,31 @@ def probe(
     stats=None,
     logger=None,
     force: bool = False,
-) -> dict | None:
+) -> dict:
     """Measure (or load cached) per-device stream GB/s.
 
     Returns ``{"key", "probe_bytes", "iters", "gbps": {dev_id: g},
-    "mean_gbps"}`` or None when jax is unavailable.  Emits the
+    "mean_gbps"}``.  A device that cannot run the probe's one jitted
+    sum cannot run a query either, so a failure here raises (and fails
+    ``Server.open``) instead of leaving the floor unset.  Emits the
     ``device.streamFloorGbps`` gauge (aggregate + per-device) when a
     stats client is passed."""
-    try:
-        import jax
-    except Exception:  # pragma: no cover - jax is baked into the image
-        return None
-    try:
-        key = _backend_key(jax)
-        with _mu:
-            cached = None if force else _cache.get(key)
-        result = cached
-        source = "memory"
-        if result is None and not force:
-            result = _load_disk(artifact_dir, key)
-            source = "disk"
-        if result is None:
-            result = _measure(jax, key)
-            source = "probe"
-            _store_disk(artifact_dir, key, result)
-        with _mu:
-            _cache[key] = result
-    except Exception as e:  # noqa: BLE001 - probe must never block open
-        if logger is not None:
-            logger(f"stream floor probe failed: {e}")
-        return None
+    import jax
+
+    key = _backend_key(jax)
+    with _mu:
+        cached = None if force else _cache.get(key)
+    result = cached
+    source = "memory"
+    if result is None and not force:
+        result = _load_disk(artifact_dir, key)
+        source = "disk"
+    if result is None:
+        result = _measure(jax, key)
+        source = "probe"
+        _store_disk(artifact_dir, key, result)
+    with _mu:
+        _cache[key] = result
     if stats is not None:
         stats.gauge("device.streamFloorGbps", result["mean_gbps"])
         for dev_id, g in result["gbps"].items():

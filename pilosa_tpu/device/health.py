@@ -13,7 +13,8 @@ instead of bricking it:
   failure kind — ``oom`` (RESOURCE_EXHAUSTED / allocator text),
   ``hang`` (a watchdog trip), ``error`` (an XLA/injected runtime
   error) — or None for exceptions that are not device faults at all
-  (semantic errors, deadlines), which the launch sites re-raise.
+  (semantic errors, deadlines, a program that fails to lower or
+  compile), which the launch sites re-raise.
 
 * **State machine.**  Each path — ``device:<ordinal>`` per
   participating device, plus ``collective`` for the mesh-psum launch
@@ -72,6 +73,17 @@ DEFAULT_WATCHDOG_MS = 60_000.0
 
 _OOM_MARKERS = ("RESOURCE_EXHAUSTED", "out of memory", "OUT_OF_MEMORY")
 
+# What the runtime writes into a JaxRuntimeError when a program fails
+# to COMPILE, as opposed to a fault while it loads or runs — on a v5e,
+# "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of
+# memory in memory space hbm."  Such a program can never run on this
+# device, so the error propagates to the client instead of being
+# answered from the host planes: a server whose programs do not compile
+# must not look healthy.  (No room to LOAD a compiled program, "Error
+# loading program ... Attempting to reserve", and "Error allocating
+# device buffer" are run-time OOMs and stay device faults.)
+_COMPILE_MARKER = "compil"
+
 
 class LaunchWatchdogTimeout(RuntimeError):
     """A device launch exceeded the watchdog deadline — the shape of a
@@ -86,38 +98,36 @@ class CollectiveUnavailable(RuntimeError):
 def classify(exc: BaseException) -> str | None:
     """Failure kind of a device-launch exception, or None when the
     exception is NOT a device fault (semantic errors, deadlines,
-    scheduler shutdowns) and must propagate unchanged.
+    scheduler shutdowns, a program that does not lower or compile) and
+    must propagate unchanged.
 
     The allowlist is deliberately narrow: only the watchdog's own
-    timeout, the chaos layer's injected device faults, and the JAX/XLA
-    runtime's error types (by module, plus the RESOURCE_EXHAUSTED /
-    out-of-memory text real allocator failures carry) count — an
-    unrecognized exception fails the query loudly rather than silently
-    rerouting a logic bug through the host path."""
+    timeout, the chaos layer's injected device faults, and what the
+    JAX runtime raises while a program EXECUTES (``JaxRuntimeError``,
+    plus the RESOURCE_EXHAUSTED / out-of-memory text real allocator
+    failures carry).  Tracing and lowering raise ordinary Python
+    exceptions, and a failed XLA/Mosaic compile is a ``JaxRuntimeError``
+    that says so: both fail the query loudly rather than silently
+    rerouting it through the host path."""
     if isinstance(exc, LaunchWatchdogTimeout):
         return KIND_HANG
+    from jax.errors import JaxRuntimeError
+
     from pilosa_tpu.testing import faults
 
     if isinstance(exc, faults.FaultOOM):
         return KIND_OOM
     if isinstance(exc, faults.FaultError):
         return KIND_ERROR
-    mod = type(exc).__module__ or ""
-    name = type(exc).__name__
-    if (
-        mod.startswith("jaxlib")
-        or mod.startswith("jax")
-        or name == "XlaRuntimeError"
-    ):
-        msg = str(exc)
-        if any(m in msg for m in _OOM_MARKERS):
-            return KIND_OOM
-        return KIND_ERROR
-    if isinstance(exc, RuntimeError) and any(
-        m in str(exc) for m in _OOM_MARKERS
-    ):
+    if not isinstance(exc, RuntimeError):
+        return None
+    msg = str(exc)
+    runtime = isinstance(exc, JaxRuntimeError)
+    if runtime and _COMPILE_MARKER in msg.lower():
+        return None
+    if any(m in msg for m in _OOM_MARKERS):
         return KIND_OOM
-    return None
+    return KIND_ERROR if runtime else None
 
 
 class _PathState:

@@ -31,8 +31,9 @@ Design points:
 Budget resolution (per device): an explicit positive ``configure``
 value wins, then the ``PILOSA_DEVICE_HBM_BUDGET_BYTES`` env override,
 then a safe fraction of the detected device memory
-(``memory_stats()['bytes_limit']``), else unbounded — which is what the
-CPU backend reports, so tests and laptops never evict unless asked to.
+(``memory_stats()['bytes_limit']``).  The CPU backend reports none and
+is unbounded, so tests and laptops never evict unless asked to; an
+accelerator that reports none is an error, never "unbounded".
 """
 
 from __future__ import annotations
@@ -112,7 +113,7 @@ class PlanePool:
         # plane_nbytes x writes here, vs one upload with scatter on.
         self._restage_uploads = 0
         self._restage_bytes = 0
-        # 0 = auto (env -> detect -> unbounded); > 0 = explicit bytes.
+        # 0 = auto (env -> detect); > 0 = explicit bytes.
         self._budget = int(budget_bytes or 0)
         self._detected: int | None = None
         self.stats = stats or NopStatsClient()
@@ -150,19 +151,27 @@ class PlanePool:
         return self._detect_budget()
 
     def _detect_budget(self) -> int:
+        """``DEFAULT_BUDGET_FRACTION`` of the first local device's
+        ``bytes_limit``.  The CPU backend reports no memory stats and is
+        unbounded (0); an accelerator that reports no limit is an
+        error — an unbounded pool there ends in a device OOM."""
         detected = self._detected
         if detected is None:
-            limit = 0
-            try:
-                import jax
+            import jax
 
-                ms = getattr(jax.local_devices()[0], "memory_stats", None)
-                mem = ms() if callable(ms) else None
-                if mem and mem.get("bytes_limit"):
-                    limit = int(mem["bytes_limit"] * DEFAULT_BUDGET_FRACTION)
-            except Exception:  # noqa: BLE001 — detection is best-effort
-                limit = 0
-            self._detected = detected = limit
+            dev = jax.local_devices()[0]
+            mem = dev.memory_stats()
+            if mem and mem.get("bytes_limit"):
+                detected = int(mem["bytes_limit"] * DEFAULT_BUDGET_FRACTION)
+            elif dev.platform == "cpu":
+                detected = 0
+            else:
+                raise RuntimeError(
+                    f"device {_device_label(dev)} reports no bytes_limit "
+                    "(memory_stats() = "
+                    f"{mem!r}); set [device] hbm-budget-bytes explicitly"
+                )
+            self._detected = detected
         return detected
 
     # ------------------------------------------------------------------

@@ -1,10 +1,9 @@
 """Cross-query coalescing: one device launch for many concurrent queries.
 
-BENCH_r05: the fused kernel answers Intersect+Count in 0.64 ms, yet 128
-client threads only reach 0.88 ms/query end to end — every query
-dispatches its OWN fused-XLA launch, so per-launch dispatch overhead,
-GIL contention, and host assembly dominate, not compute.  The idiom that
-closes this gap in production inference stacks is continuous
+Without it every query dispatches its OWN fused-XLA launch, so
+per-launch dispatch overhead, GIL contention, and host assembly
+dominate, not compute.  The idiom that closes this gap in production
+inference stacks is continuous
 micro-batching, and the compile model here is already shaped for it:
 ``plan.compiled_batched`` keys programs by (tree shape, reduce kind) and
 vmaps over a leading batch axis, so concurrent queries that share that
@@ -96,6 +95,16 @@ DEFAULT_FUSE_MAX_PROGRAMS = 16
 # bounds device memory, not correctness).  64 leaves x 128 KiB = 8 MiB
 # per batch row.
 MAX_FUSE_LEAVES = 64
+# Scratch budget for one fused launch, per device.  The interpreter
+# holds a register file of (leaf bucket + op bucket) slice-rows for
+# EVERY batch row at once, beside the combined leaf array, so its
+# footprint grows with slices x programs where MAX_FUSE_LEAVES only
+# counts leaves: at 1024 batch rows, 8 leaves and 16 programs compile
+# to 6.8 GiB of HLO temp on a v5e, and 32 leaves do not compile at all
+# (PERF.md, PR 21).  Program sets past the budget split into further
+# launches; like the leaf budget this bounds device memory, not
+# correctness.
+MAX_FUSE_BYTES = 2 << 30
 # Reduce kinds the interpreter can evaluate; "agg" trees reduce inside
 # the expression (BSI aggregates) and stay on the per-compile-key path.
 # "total" is the ICI-reduced count: per-register limb pairs summed
@@ -789,10 +798,12 @@ class CoalesceScheduler:
         failed: set = set()
         fused: list = []  # (item, out_reg)
         fallback: "OrderedDict[tuple, list]" = OrderedDict()
+        pks: list = []  # per pair: its (tree, leaf layout) program key
         for key, it in pairs:
             expr = key[0]
             lmap = leaf_maps[seg_of[id(it.batch)]]
             pk = (expr, tuple(lmap))
+            pks.append(pk)
             reg = out_of.get(pk)
             if reg is None and pk not in failed:
                 cp = em.checkpoint()
@@ -806,9 +817,31 @@ class CoalesceScheduler:
             else:
                 fused.append((it, reg))
 
+        # Scratch budget (MAX_FUSE_BYTES): a program set whose register
+        # file would not fit splits in two by program and each half
+        # launches on its own; a pair that still does not fit takes the
+        # concat path like any unfusable tree.
+        p_bucket = bp.pow2_bucket(max(len(em.rows), 1), plan.FUSE_OPS_FLOOR)
+        # Per device: a mesh-sharded batch splits its rows evenly.
+        rows_per_device = n_rows // len(segs[0].devices())
+        row_bytes = int(segs[0].shape[-1]) * segs[0].dtype.itemsize
+        over = (
+            rows_per_device * (l_bucket + p_bucket) * row_bytes
+            > MAX_FUSE_BYTES
+        )
+        if over and len(out_of) > 2:
+            progs = list(out_of)
+            first = set(progs[: len(progs) // 2])
+            halves: tuple[list, list] = ([], [])
+            for pair, pk in zip(pairs, pks):
+                halves[0 if pk in first else 1].append(pair)
+            for sub in halves:
+                self._launch_interp(reduce, n_rows, sub)
+            return
+
         # Fewer than two distinct programs fused = nothing to fuse;
         # the concat path handles identity dedup with zero copies.
-        if fused and len(out_of) < 2:
+        if fused and (len(out_of) < 2 or over):
             for it, _reg in fused:
                 fallback.setdefault(
                     next(k for k, i2 in pairs if i2 is it), []
@@ -842,7 +875,6 @@ class CoalesceScheduler:
                 else jnp.concatenate(parts, axis=1)
             )
             n_ops = len(em.rows)
-            p_bucket = bp.pow2_bucket(max(n_ops, 1), plan.FUSE_OPS_FLOOR)
             prog = np.zeros((p_bucket, 4), dtype=np.int32)
             if n_ops:
                 prog[:n_ops] = np.asarray(em.rows, dtype=np.int32)

@@ -3,11 +3,10 @@
 The reference's cold path is an O(containers) mmap open
 (reference: fragment.go:154-242) — a restarted node answers its first
 query in milliseconds.  Our executor instead compiles one fused XLA
-program per (tree shape, slice bucket), which cost ~5 s per shape on
-every process restart (BENCH_r04 "e2e executor COLD").  Two fixes,
-both here:
+program per (tree shape, slice bucket) on every process start.  Two
+fixes, both here:
 
-* ``enable_compile_cache(dir)`` turns on JAX's persistent compilation
+* ``enable_compile_cache()`` turns on JAX's persistent compilation
   cache so every shape is compiled once per MACHINE, not once per
   process — a restart deserializes the executable from disk.
 * ``prewarm()`` compiles the standard query-shape buckets (the shapes
@@ -27,42 +26,68 @@ import numpy as np
 from pilosa_tpu.exec import plan
 from pilosa_tpu.ops import bitplane as bp
 
+# JAX's own variable for the cache directory.  Where it is set, JAX
+# has already taken the directory from it and this module sets none.
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+
+# The directory is part of the cache key, so the default is ONE fixed
+# path inside the checkout (git-ignored): a path under a data dir, a
+# temp name, a pid or a time moves between runs and never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax-compile-cache",
+)
+
 _enabled_dir: str | None = None
 _lock = threading.Lock()
 
 
-def enable_compile_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+def resolve_cache_dir(configured: str = "") -> str | None:
+    """The directory the persistent cache lives in:
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else an explicit
+    ``[tpu] compilation-cache-dir``, else :data:`DEFAULT_CACHE_DIR`;
+    None when the config says "off" and the variable is unset."""
+    env_dir = os.environ.get(ENV_CACHE_DIR)
+    if env_dir:
+        return env_dir
+    if configured == "off":
+        return None
+    return os.path.expanduser(configured) if configured else DEFAULT_CACHE_DIR
 
+
+def enable_compile_cache(configured: str = "") -> str | None:
+    """Turn JAX's persistent compilation cache on and return the
+    directory in use (None when disabled by config or the directory
+    cannot be created).
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, only the entry threshold is
+    touched — the directory stays the one JAX read from the variable.
     Idempotent; first caller wins (the cache dir is process-global in
-    JAX).  Returns True when the cache is active.  Entry criteria are
-    relaxed so the multi-second fused-tree programs always land on
-    disk; sub-100 ms host compiles stay out to keep the dir small.
+    JAX).  The compile-time threshold is dropped to zero: with any
+    other value a program that compiles in about that time lands on
+    disk in one boot and not in the next, so "a restart compiles
+    nothing" could not be checked.
     """
     global _enabled_dir
     with _lock:
         if _enabled_dir is not None:
-            return True
+            return _enabled_dir
+        cache_dir = resolve_cache_dir(configured)
+        if cache_dir is None:
+            return None
         import jax
 
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-        except (OSError, AttributeError, ValueError):
-            return False
-        # The cache is ACTIVE from here on; the threshold knobs are
-        # best-effort tuning (a JAX version lacking one must not make
-        # us report the cache as off while it writes entries).
-        for knob, val in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.1),
-            ("jax_persistent_cache_min_entry_size_bytes", 0),
-        ):
+        if not os.environ.get(ENV_CACHE_DIR):
             try:
-                jax.config.update(knob, val)
-            except (AttributeError, ValueError):
-                pass
+                os.makedirs(cache_dir, exist_ok=True)
+            except OSError:
+                return None
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         _enabled_dir = cache_dir
-        return True
+        return cache_dir
 
 
 def enabled_cache_dir() -> str | None:
@@ -229,17 +254,24 @@ def prewarm(buckets=(1, 2, 4, 8), exprs=_STANDARD_EXPRS, coalesce=False) -> int:
 
 def prewarm_async(logger=None, coalesce=False) -> threading.Thread:
     """Run :func:`prewarm` on a daemon thread (server open must not
-    block on compiles); returns the thread for tests to join."""
+    block on compiles) and return the thread, which carries the
+    outcome once it ends: ``programs`` (the count compiled) or
+    ``error`` (the exception that stopped it — a standard program that
+    cannot compile here; ``GET /debug/health`` shows it)."""
 
     def run():
         try:
-            n = prewarm(coalesce=coalesce)
+            t.programs = prewarm(coalesce=coalesce)
+        except Exception as e:  # noqa: BLE001 — recorded, not swallowed
+            t.error = e
             if logger is not None:
-                logger(f"prewarm: {n} standard query programs compiled")
-        except Exception as e:  # pragma: no cover - diagnostics only
-            if logger is not None:
-                logger(f"prewarm failed: {e}")
+                logger(f"prewarm failed: {e!r}")
+            return
+        if logger is not None:
+            logger(f"prewarm: {t.programs} standard query programs compiled")
 
     t = threading.Thread(target=run, daemon=True, name="prewarm")
+    t.programs = None
+    t.error = None
     t.start()
     return t

@@ -260,6 +260,10 @@ class Handler:
         # Base dir for /debug/profile trace tarballs, wired by the
         # Server (data dir); bare handlers fall back to a tempdir.
         self.profile_dir = None
+        # The server's background prewarm thread (exec/warmup.py
+        # prewarm_async), wired by the Server; GET /debug/health shows
+        # its outcome.  None = prewarm off.
+        self.prewarm = None
         # Single-flight guard for /debug/profile: one device trace at a
         # time, concurrent requests answer 409.
         self._profile_mu = threading.Lock()
@@ -1880,6 +1884,16 @@ class Handler:
             # healthy/suspect/quarantined, watchdog trips, and the
             # node-level degraded flag peers see via gossip.
             out["device"] = dh.snapshot()
+        if self.prewarm is not None:
+            out["prewarm"] = {
+                "done": not self.prewarm.is_alive(),
+                "programs": self.prewarm.programs,
+                "error": (
+                    None
+                    if self.prewarm.error is None
+                    else repr(self.prewarm.error)
+                ),
+            }
         return Response.json(out)
 
     def handle_get_tenants(self, req: Request) -> Response:
@@ -2403,4 +2417,10 @@ def make_http_server(handler: Handler, host: str = "127.0.0.1", port: int = 0):
         def log_message(self, fmt, *args):  # quiet
             pass
 
-    return ThreadingHTTPServer((host, port), _Adapter)
+    class _Server(ThreadingHTTPServer):
+        # socketserver's default listen backlog is 5: a burst of a few
+        # dozen clients connecting at once was reset at the socket,
+        # before admission (32 point slots + a queue of 64) ever saw it.
+        request_queue_size = 128
+
+    return _Server((host, port), _Adapter)

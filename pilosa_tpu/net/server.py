@@ -13,6 +13,8 @@ from __future__ import annotations
 import threading
 import time
 
+import jax
+
 from pilosa_tpu import __version__
 from pilosa_tpu.cluster import broadcast as bc
 from pilosa_tpu.cluster.topology import Cluster, Node
@@ -392,6 +394,11 @@ class Server:
         # visible devices) must survive in-process multi-server setups.
         if self.mesh_devices > 0:
             bp.configure_mesh_devices(self.mesh_devices)
+        devs = jax.devices()
+        self.logger(
+            f"devices: platform={devs[0].platform} "
+            f"kind={devs[0].device_kind!r} count={len(devs)}"
+        )
         n_mesh = bp.mesh_device_count()
         if n_mesh > 1:
             self.logger(
@@ -403,6 +410,13 @@ class Server:
             budget_bytes=self.hbm_budget_bytes,
             stats=self.stats,
             tracer=self.tracer,
+        )
+        # Resolve the budget NOW: an accelerator that reports no
+        # bytes_limit must fail open(), not the first admission.
+        budget = device_mod.pool().budget_bytes()
+        self.logger(
+            "hbm budget: "
+            + (f"{budget} bytes per device" if budget else "unbounded")
         )
         # One-shot stream-floor probe ([obs] floor-probe): measures
         # per-device achievable streaming GB/s (cached process-wide AND
@@ -418,30 +432,26 @@ class Server:
                 stats=self.stats,
                 logger=self.logger,
             )
-            if fp is not None:
-                perf_mod.registry().set_floor(fp["mean_gbps"])
+            perf_mod.registry().set_floor(fp["mean_gbps"])
         # Cold-start elimination (see exec/warmup.py): persistent XLA
         # compile cache so restarts deserialize programs from disk, and
         # a background pre-warm of the standard query shapes so even a
         # first boot doesn't pay compiles at query time.
-        if self.compilation_cache_dir:
-            if warmup.enable_compile_cache(self.compilation_cache_dir):
-                # First caller in the process wins the dir — log the
-                # ACTIVE one so operators never chase an empty dir.
-                active = warmup.enabled_cache_dir()
-                note = (
-                    "" if active == self.compilation_cache_dir
-                    else f" (configured {self.compilation_cache_dir})"
-                )
-                self.logger(f"compilation cache: {active}{note}")
-            else:
-                # A configured-but-broken cache dir (unwritable path,
-                # JAX without the knob) must be VISIBLE: every restart
+        if self.compilation_cache_dir is not None:
+            # The ACTIVE dir is logged (JAX_COMPILATION_CACHE_DIR, the
+            # configured one, or the fixed default; first caller in the
+            # process wins) so operators never chase an empty dir.
+            active = warmup.enable_compile_cache(self.compilation_cache_dir)
+            if active is not None:
+                self.logger(f"compilation cache: {active}")
+            elif self.compilation_cache_dir != "off":
+                # An unwritable cache dir must be VISIBLE: every restart
                 # silently pays full recompiles otherwise.
+                wanted = warmup.resolve_cache_dir(self.compilation_cache_dir)
                 self.logger(
-                    "compilation cache DISABLED: could not enable "
-                    f"{self.compilation_cache_dir!r}; queries recompile "
-                    "from scratch on every process start"
+                    f"compilation cache DISABLED: could not create {wanted!r}"
+                    "; queries recompile from scratch on every process "
+                    "start"
                 )
         # Durable ingest: flip the module-level scatter switch and
         # register the WAL manager BEFORE holder.open() — fragments
@@ -520,11 +530,12 @@ class Server:
                 fuse_max_programs=self.fuse_max_programs,
                 health=self.device_health,
             )
+        prewarm_thread = None
         if self.prewarm:
             # With coalescing on, also compile the coalescer's
             # power-of-two bucket shapes for the common Count trees so
             # the first coalesced batch doesn't eat a cold compile.
-            warmup.prewarm_async(
+            prewarm_thread = warmup.prewarm_async(
                 logger=self.logger, coalesce=self.coalesce
             )
 
@@ -554,6 +565,9 @@ class Server:
         # Profiler captures (GET /debug/profile) tar under the data dir
         # so the artifact survives the request and ships with backups.
         self.handler.profile_dir = self.data_dir
+        # The prewarm's outcome (programs compiled, or the error that
+        # stopped it) shows at GET /debug/health.
+        self.handler.prewarm = prewarm_thread
         # Migration arrivals (?stage=true restores) register their HBM
         # mirrors through the background staging lane.
         self.handler.prefetcher = device_mod.prefetcher()

@@ -16,6 +16,7 @@ import concurrent.futures
 import time
 from contextlib import suppress
 
+import jax
 import numpy as np
 import pytest
 
@@ -98,16 +99,51 @@ def test_classify_kinds():
     assert health_mod.classify(KeyError("x")) is None
 
 
-def test_classify_xla_shaped_errors():
-    class XlaRuntimeError(Exception):
-        pass
+def test_classify_runtime_errors():
+    from jax.errors import JaxRuntimeError
 
-    XlaRuntimeError.__module__ = "jaxlib.xla_extension"
-    assert health_mod.classify(XlaRuntimeError("boom")) == KIND_ERROR
+    assert health_mod.classify(JaxRuntimeError("INTERNAL: boom")) == KIND_ERROR
     assert (
-        health_mod.classify(XlaRuntimeError("RESOURCE_EXHAUSTED: 1GiB"))
+        health_mod.classify(
+            JaxRuntimeError(
+                "RESOURCE_EXHAUSTED: Error allocating device buffer: "
+                "Attempting to allocate 1.00G. That was not possible."
+            )
+        )
         == KIND_OOM
     )
+
+
+def test_classify_propagates_lowering_and_compile_errors():
+    """A program that does not lower or compile can never run here:
+    serving it from the host planes would hide a broken device path, so
+    the launch sites must re-raise it."""
+    from jax.errors import JaxRuntimeError, TracerBoolConversionError
+
+    # Tracing / lowering: ordinary Python exceptions, whatever module
+    # their type lives in.
+    try:
+        jax.jit(lambda x: 1 if x > 0 else 0)(np.int32(1))
+    except TracerBoolConversionError as e:
+        assert health_mod.classify(e) is None
+    else:
+        raise AssertionError("expected a tracer error")
+    assert (
+        health_mod.classify(
+            ValueError("The Pallas TPU lowering currently requires ...")
+        )
+        is None
+    )
+    assert health_mod.classify(NotImplementedError("no lowering")) is None
+    # XLA / Mosaic compile failures arrive as JaxRuntimeError and say so
+    # — an HBM overrun found at compile time included.
+    for msg in (
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        "memory in memory space hbm. Used 20.5G of 15.75G hbm.",
+        "INTERNAL: Mosaic failed to compile TPU kernel: unsupported shape",
+        "INVALID_ARGUMENT: during compilation: bad layout",
+    ):
+        assert health_mod.classify(JaxRuntimeError(msg)) is None, msg
 
 
 # ---------------------------------------------------------------------------

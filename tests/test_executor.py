@@ -1,8 +1,10 @@
 """Executor tests — single-node and mocked-remote map/reduce
 (parity tier for executor_test.go)."""
 
+import os
 from datetime import datetime
 
+import jax
 import pytest
 
 from pilosa_tpu.cluster.topology import new_cluster
@@ -981,16 +983,84 @@ def test_warmup_prewarm_compiles_standard_shapes():
 def test_enable_compile_cache_idempotent():
     from pilosa_tpu.exec import warmup
 
-    # A stable dir, NOT tmp_path: the cache dir is process-global in
-    # JAX, so it must outlive this test or later compiles in the same
-    # pytest process would warn on every cache write.
-    d = "/tmp/pilosa-tpu-test-compile-cache"
-    ok1 = warmup.enable_compile_cache(d)
-    # Second call (any dir) is a no-op that still reports active.
-    ok2 = warmup.enable_compile_cache(d + "-other")
-    assert ok1 and ok2
-    # First caller in the PROCESS wins (an earlier test may have won).
-    assert warmup.enabled_cache_dir() is not None
+    # The fixed default dir, NOT tmp_path: the cache dir is
+    # process-global in JAX, so it must outlive this test or later
+    # compiles in the same pytest process would warn on every write.
+    d1 = warmup.enable_compile_cache("")
+    # Second call (any dir) is a no-op that still reports the active one.
+    d2 = warmup.enable_compile_cache("/tmp/pilosa-tpu-other-compile-cache")
+    assert d1 is not None and d1 == d2 == warmup.enabled_cache_dir()
+
+
+class TestCompileCacheDir:
+    """Where the persistent cache lives (exec/warmup.py): the directory
+    is part of the cache key, so it may never move between runs."""
+
+    @pytest.fixture
+    def fresh(self, monkeypatch):
+        """An un-enabled warmup module plus a record of every
+        ``jax.config.update`` it makes (none reach JAX)."""
+        from pilosa_tpu.exec import warmup
+
+        monkeypatch.setattr(warmup, "_enabled_dir", None)
+        monkeypatch.delenv(warmup.ENV_CACHE_DIR, raising=False)
+        updates = {}
+        monkeypatch.setattr(
+            jax.config, "update", lambda k, v: updates.__setitem__(k, v)
+        )
+        return warmup, updates
+
+    def test_env_set_means_no_dir_set_in_code(self, fresh, monkeypatch, tmp_path):
+        warmup, updates = fresh
+        monkeypatch.setenv(warmup.ENV_CACHE_DIR, str(tmp_path / "from-env"))
+        got = warmup.enable_compile_cache(str(tmp_path / "configured"))
+        assert got == str(tmp_path / "from-env")
+        assert "jax_compilation_cache_dir" not in updates
+        # thresholds only
+        assert set(updates) == {"jax_persistent_cache_min_compile_time_secs"}
+        assert not (tmp_path / "configured").exists()
+        # "off" in the config does not undo JAX's own variable either.
+        assert warmup.resolve_cache_dir("off") == str(tmp_path / "from-env")
+
+    def test_unset_env_uses_the_fixed_checkout_path(self, fresh, monkeypatch):
+        import pilosa_tpu
+
+        warmup, updates = fresh
+        monkeypatch.setattr(os, "makedirs", lambda *a, **k: None)
+        repo = os.path.dirname(os.path.dirname(pilosa_tpu.__file__))
+        want = os.path.join(repo, ".jax-compile-cache")
+        assert warmup.DEFAULT_CACHE_DIR == want
+        assert warmup.enable_compile_cache("") == want
+        assert updates["jax_compilation_cache_dir"] == want
+
+    def test_explicit_config_wins_over_the_default(self, fresh, tmp_path):
+        warmup, updates = fresh
+        d = str(tmp_path / "explicit")
+        assert warmup.enable_compile_cache(d) == d
+        assert updates["jax_compilation_cache_dir"] == d
+        assert os.path.isdir(d)
+
+    def test_off_disables(self, fresh):
+        warmup, updates = fresh
+        assert warmup.enable_compile_cache("off") is None
+        assert updates == {}
+
+    def test_server_config_no_longer_keys_the_cache_on_the_data_dir(
+        self, tmp_path
+    ):
+        from pilosa_tpu import config as config_mod
+        from pilosa_tpu.cli import ctl
+        from pilosa_tpu.exec import warmup
+
+        for sub in ("a", "b"):
+            cfg = config_mod.load(
+                None, environ={}, overrides={"data_dir": str(tmp_path / sub)}
+            )
+            srv = ctl.build_server(cfg)
+            assert (
+                warmup.resolve_cache_dir(srv.compilation_cache_dir)
+                == warmup.DEFAULT_CACHE_DIR
+            )
 
 
 # ---------------------------------------------------------------------------

@@ -370,6 +370,57 @@ def test_fuse_oversized_tree_falls_back_to_coalesce(rng):
         co.close()
 
 
+def test_fuse_scratch_budget_splits_then_falls_back(rng, monkeypatch):
+    """MAX_FUSE_BYTES bounds the interpreter's register file (batch rows
+    x (leaf bucket + op bucket) slice-rows): a program set past it
+    splits into further fused launches, and a pair that still does not
+    fit rides the concat path — same answers either way."""
+    from pilosa_tpu.exec import coalesce as coalesce_mod
+
+    words, rows = 16, 4
+    exprs = [
+        ("Intersect", ("leaf", 0), ("leaf", 1)),
+        ("Union", ("leaf", 0), ("leaf", 1)),
+        ("Difference", ("leaf", 0), ("leaf", 1)),
+        ("Xor", ("leaf", 0), ("leaf", 1)),
+    ]
+    np_ops = [np.bitwise_and, np.bitwise_or, lambda a, b: a & ~b, np.bitwise_xor]
+
+    def storm(budget):
+        monkeypatch.setattr(coalesce_mod, "MAX_FUSE_BYTES", budget)
+        co = CoalesceScheduler(max_wait_us=WAIT_US)
+        try:
+            batches = [
+                jnp.asarray(rng.integers(
+                    0, 2**32, size=(rows, 2, words), dtype=np.uint32
+                ))
+                for _ in exprs
+            ]
+            futs = [co.submit(e, "count", b) for e, b in zip(exprs, batches)]
+            for fut, b, op in zip(futs, batches, np_ops):
+                got, _info = fut.result(timeout=60)
+                h = np.asarray(b)
+                np.testing.assert_array_equal(
+                    got, np.bitwise_count(op(h[:, 0], h[:, 1])).sum(axis=-1)
+                )
+            return co.snapshot()
+        finally:
+            co.close()
+
+    # Four programs over 8 distinct leaves: one launch holds
+    # rows x (8 + 8) slice-rows of registers; two programs over 4
+    # leaves hold rows x (4 + 8).
+    per_device = rows // len(jnp.zeros(1).devices())
+    whole = per_device * (8 + 8) * words * 4
+    halved = per_device * (4 + 8) * words * 4
+    snap = storm(whole)
+    assert snap["fused_launches"] == 1 and snap["fused_queries"] == 4
+    snap = storm(halved)
+    assert snap["fused_launches"] == 2 and snap["fused_queries"] == 4
+    snap = storm(halved - 1)
+    assert snap["fused_launches"] == 0 and snap["fuse_fallbacks"] == 4
+
+
 def test_fuse_disabled_keeps_concat_semantics(rng):
     co = CoalesceScheduler(max_wait_us=WAIT_US, fuse=False)
     try:
