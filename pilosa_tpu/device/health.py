@@ -45,6 +45,8 @@ degraded replicas (executor._slices_by_node).
 
 from __future__ import annotations
 
+import contextvars
+import functools
 import queue
 import threading
 import time
@@ -230,7 +232,9 @@ class _WatchdogRunner:
         with self._mu:
             q = self._ensure_worker_locked()
             gen = self._gen
-        q.put((gen, fn, box))
+        # The body runs under the caller's context: its trace span (a
+        # compile inside the launch records under it) and its deadline.
+        q.put((gen, functools.partial(contextvars.copy_context().run, fn), box))
         if not box["done"].wait(timeout=timeout_s):
             with self._mu:
                 # Stale-mark the in-flight call and retire this runner:

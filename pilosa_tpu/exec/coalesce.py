@@ -67,7 +67,9 @@ Observability: ``exec.coalesce.launches`` / ``coalescedQueries`` /
 ``padWaste`` counters and an ``exec.coalesce.batchOccupancy`` histogram;
 the executor's per-query ``coalesce`` trace span carries the launch's
 occupancy and row stats (and through it the slow-query log's batch
-stats).
+stats).  The dispatcher times each launch once as a ``launch`` span
+(trace.SharedSpan) and records it under every waiter's ``coalesce``
+span, so that span's self time is the queue wait alone.
 """
 
 from __future__ import annotations
@@ -82,6 +84,7 @@ import numpy as np
 
 from pilosa_tpu import device as device_mod
 from pilosa_tpu.obs import perf as perf_mod
+from pilosa_tpu.obs import trace
 from pilosa_tpu.obs.stats import NopStatsClient
 
 DEFAULT_MAX_BATCH = 64
@@ -162,10 +165,12 @@ class _Item:
     # pass streams each distinct plane row once however many queries
     # reference it.  None = no identities known (columns stay unique).
     leaf_keys: "tuple | None" = None
-    # Submitting query's trace id, captured at submit time: the
-    # dispatcher thread has no trace contextvar, so the launch
-    # telemetry's slowest-launch attribution rides the item.
+    # Submitting query's trace id and current span (its ``coalesce``),
+    # captured at submit time: the dispatcher thread has no trace
+    # contextvar, so the launch telemetry's slowest-launch attribution
+    # and the ``launch`` span's parent ride the item.
     trace_id: str = ""
+    span: "trace.Span | None" = None
 
 
 def _placement(batch) -> tuple:
@@ -270,6 +275,7 @@ class CoalesceScheduler:
             pin_keys=tuple(k for k in pin_keys if k is not None),
             leaf_keys=leaf_keys,
             trace_id=perf_mod.current_trace_id(),
+            span=trace.current_span(),
         )
         with self._cv:
             if self._closed:
@@ -294,6 +300,7 @@ class CoalesceScheduler:
             future=fut,
             pin_keys=(),
             trace_id=perf_mod.current_trace_id(),
+            span=trace.current_span(),
         )
         with self._cv:
             if self._closed:
@@ -463,6 +470,25 @@ class CoalesceScheduler:
         # slice axis groups later, per launch).
         return (reduce, tail[-1], placement)
 
+    @staticmethod
+    def _launch_span(site: str, items: list, rows: int) -> trace.SharedSpan:
+        """The ``launch`` span of one dispatch+fetch, open from just
+        before the compiled call to the end of ``device_get``."""
+        return trace.SharedSpan(
+            "launch", items[0].trace_id,
+            site=site, queries=len(items), rows=rows,
+        )
+
+    @staticmethod
+    def _publish_launch(ls, items: list, dispatch_ms: float) -> None:
+        """Record the finished launch under every waiter's span —
+        BEFORE the futures resolve: a waiter that has its result may
+        finish its trace, and a span for a final trace is dropped."""
+        ls.annotate(
+            dispatch_ms=round(dispatch_ms, 3), first_call=bool(ls.children)
+        )
+        ls.publish(it.span for it in items)
+
     def _launch(self, key, items: list, extra=()) -> None:
         if key == _FETCH_KEY:
             self._launch_fetch(items)
@@ -530,9 +556,12 @@ class CoalesceScheduler:
         except Exception:  # noqa: BLE001 — non-jax stand-ins, old arrays
             mesh = None
         pins = {k for it in items for k in it.pin_keys}
+        site = "collective" if mesh is not None else "total"
         t0 = time.monotonic()
         t_disp = [t0]  # set when the async dispatch returns (pre-fetch)
-        with device_mod.pool().pinned(*pins):
+        with self._launch_span(
+            site, items, int(batch.shape[0])
+        ) as ls, device_mod.pool().pinned(*pins):
             if mesh is not None:
                 # The program psums over the mesh: serialize with every
                 # other collective launch in the process (see
@@ -554,9 +583,10 @@ class CoalesceScheduler:
                 res = np.asarray(jax.device_get(out))
         t1 = time.monotonic()
         launch_ms = (t1 - t0) * 1e3
+        self._publish_launch(ls, items, (t_disp[0] - t0) * 1e3)
         if perf_mod.enabled():
             perf_mod.record_launch(
-                "collective" if mesh is not None else "total",
+                site,
                 reduce="total",
                 queries=len(items),
                 rows=int(batch.shape[0]),
@@ -645,12 +675,15 @@ class CoalesceScheduler:
             dev_in = jnp.concatenate(parts, axis=0)
         pins = {k for it in items for k in it.pin_keys}
         t0 = time.monotonic()
-        with device_mod.pool().pinned(*pins):
+        with self._launch_span(
+            "coalesce", items, total
+        ) as ls, device_mod.pool().pinned(*pins):
             out = plan.compiled_batched(expr, reduce)(dev_in)
             t_disp = time.monotonic()
             res = np.asarray(jax.device_get(out))
         t1 = time.monotonic()
         launch_ms = (t1 - t0) * 1e3
+        self._publish_launch(ls, items, (t_disp - t0) * 1e3)
         # Logical bytes are the PRE-pad rows: pad rows are bucketing
         # overhead, not useful plane traffic.
         if perf_mod.enabled():
@@ -890,9 +923,13 @@ class CoalesceScheduler:
                 sharded = len(combined.devices()) > 1
             except Exception:  # noqa: BLE001 — unit-test stand-ins
                 sharded = False
+            site = "collective" if (reduce == "total" and sharded) else "interp"
+            fused_items = [it for it, _reg in fused]
             t0 = time.monotonic()
             t_disp = [t0]
-            with device_mod.pool().pinned(*pins):
+            with self._launch_span(
+                site, fused_items, n_rows * l_union
+            ) as ls, device_mod.pool().pinned(*pins):
                 if reduce == "total" and sharded:
                     # The slice-axis limb sums psum over the mesh —
                     # serialize with other collective launches (and,
@@ -912,11 +949,12 @@ class CoalesceScheduler:
                     res = np.asarray(jax.device_get(out))
             t1 = time.monotonic()
             launch_ms = (t1 - t0) * 1e3
+            self._publish_launch(ls, fused_items, (t_disp[0] - t0) * 1e3)
             # Logical bytes: the deduped union leaf set (streamed once
             # per pass), pad leaves excluded.
             if perf_mod.enabled():
                 perf_mod.record_launch(
-                    "collective" if (reduce == "total" and sharded) else "interp",
+                    site,
                     reduce=reduce,
                     queries=len(fused),
                     rows=n_rows * l_union,
@@ -1008,8 +1046,10 @@ class CoalesceScheduler:
             spans.append((len(arrays), len(arrs)))
             arrays.extend(arrs)
         t0 = time.monotonic()
-        fetched = jax.device_get(arrays)
+        with self._launch_span("fetch", items, 0) as ls:
+            fetched = jax.device_get(arrays)
         fetch_ms = (time.monotonic() - t0) * 1e3
+        self._publish_launch(ls, items, 0.0)
         if perf_mod.enabled():
             perf_mod.record_launch(
                 "fetch",

@@ -56,7 +56,6 @@ from pilosa_tpu.core.view import VIEW_INVERSE, VIEW_STANDARD
 from pilosa_tpu.exec import coalesce as coalesce_mod
 from pilosa_tpu.exec import hosteval as hosteval_mod
 from pilosa_tpu.exec import plan
-from pilosa_tpu.exec import warmup
 from pilosa_tpu.net import resilience
 from pilosa_tpu.obs import perf as perf_mod
 from pilosa_tpu.obs import trace
@@ -363,10 +362,6 @@ class Executor:
         # the degraded-mode data plane a quarantined device falls back
         # to, byte-identical by construction (exec/hosteval.py).
         self.hosteval = hosteval_mod.HostEvaluator(self)
-        # (expr, reduce, batch shape) programs this executor has already
-        # dispatched — distinguishes compile-bearing first calls from
-        # pure execution in the device span annotations.
-        self._seen_programs: set = set()
         self._pool = _DaemonPool(
             max_workers=16, stats=getattr(holder, "stats", None)
         )
@@ -955,39 +950,44 @@ class Executor:
         authoritative, so this is always coherent.  Returns (batch,
         kept, empties)."""
         n_leaves = len(leaves)
-        rows_buf = np.zeros(
-            (len(slices), n_leaves, bp.WORDS_PER_SLICE), dtype=np.uint32
-        )
         kept: list[int] = []
         empties: list[int] = []
-        i = 0
-        for s in slices:
-            any_set = False
-            for j, leaf in enumerate(leaves):
-                w = self._leaf_row_host(index, leaf, s)
-                if w is not None:
-                    rows_buf[i, j] = w
-                    if leaf.name not in plan.NEUTRAL_LEAVES:
-                        any_set = True
-            if not leaves or not any_set:
-                # an empty slice writes nothing, so position i stays
-                # zero-initialized for the next kept slice
-                empties.append(s)
-            else:
-                kept.append(s)
-                i += 1
+        with self.tracer.span("plan.leaves", path="host_fill") as sp:
+            rows_buf = np.zeros(
+                (len(slices), n_leaves, bp.WORDS_PER_SLICE), dtype=np.uint32
+            )
+            i = n_rows = 0
+            for s in slices:
+                any_set = False
+                for j, leaf in enumerate(leaves):
+                    w = self._leaf_row_host(index, leaf, s)
+                    if w is not None:
+                        rows_buf[i, j] = w
+                        n_rows += 1
+                        if leaf.name not in plan.NEUTRAL_LEAVES:
+                            any_set = True
+                if not leaves or not any_set:
+                    # an empty slice writes nothing, so position i stays
+                    # zero-initialized for the next kept slice
+                    empties.append(s)
+                else:
+                    kept.append(s)
+                    i += 1
+            sp.annotate(rows=n_rows, device_copies=0)
         if not kept:
             return None, kept, empties
-        bucket = plan.slice_bucket(len(kept))
-        if bucket <= rows_buf.shape[0]:
-            # positions past the last kept slice were never written
-            batch_np = rows_buf[:bucket]
-        else:
-            batch_np = np.zeros(
-                (bucket, n_leaves, bp.WORDS_PER_SLICE), dtype=np.uint32
-            )
-            batch_np[: len(kept)] = rows_buf[: len(kept)]
-        return jnp.asarray(batch_np), kept, empties
+        with self.tracer.span("plan.transfer", devices=1) as sp:
+            bucket = plan.slice_bucket(len(kept))
+            if bucket <= rows_buf.shape[0]:
+                # positions past the last kept slice were never written
+                batch_np = rows_buf[:bucket]
+            else:
+                batch_np = np.zeros(
+                    (bucket, n_leaves, bp.WORDS_PER_SLICE), dtype=np.uint32
+                )
+                batch_np[: len(kept)] = rows_buf[: len(kept)]
+            sp.annotate(bytes=int(batch_np.nbytes))
+            return jnp.asarray(batch_np), kept, empties
 
     def _gather_leaf_stacks(self, index: str, c: Call, slices: list[int]):
         """Fetch every slice's leaf rows onto its home device.
@@ -1000,23 +1000,26 @@ class Executor:
         stacks: list[object] = []
         kept_slices: list[int] = []
         empties: list[int] = []
-        for s in slices:
-            rows = []
-            any_set = False
-            for leaf in leaves:
-                r = self._leaf_row_device(index, leaf, s)
-                if r is None:
-                    r = self._zero_row(s)
-                elif leaf.name not in plan.NEUTRAL_LEAVES:
-                    any_set = True
-                rows.append(r)
-            if not leaves or not any_set:
-                empties.append(s)
-                continue
-            # All of a slice's leaves live on its home device, so this
-            # stack stays device-local.
-            stacks.append(jnp.stack(rows))
-            kept_slices.append(s)
+        with self.tracer.span("plan.leaves", path="device_gather") as sp:
+            for s in slices:
+                rows = []
+                any_set = False
+                for leaf in leaves:
+                    r = self._leaf_row_device(index, leaf, s)
+                    if r is None:
+                        r = self._zero_row(s)
+                    elif leaf.name not in plan.NEUTRAL_LEAVES:
+                        any_set = True
+                    rows.append(r)
+                if not leaves or not any_set:
+                    empties.append(s)
+                    continue
+                # All of a slice's leaves live on its home device, so this
+                # stack stays device-local.
+                stacks.append(jnp.stack(rows))
+                kept_slices.append(s)
+            n_rows = len(slices) * len(leaves)
+            sp.annotate(rows=n_rows, device_copies=n_rows + len(stacks))
         return expr, stacks, kept_slices, empties
 
     # Assembled leaf batches kept per (index, canonical call, slice set):
@@ -1050,37 +1053,39 @@ class Executor:
         quantum and every time-view fragment's version (the view set
         depends on the quantum; set_time_quantum bumps the write epoch
         so the O(1) fast path stays sound)."""
-        c = self._rewrite_bsi(index, c)
-        expr, leaves = plan.decompose(c)
-        cacheable = all(leaf.name in plan.LEAF_CALLS for leaf in leaves)
-        key = (index, str(c), tuple(slices))
-        if cacheable:
-            with self._batch_mu:
-                ent = self._batch_cache.get(key)
-            if ent is not None:
-                epoch = fragment_mod.write_epoch()
-                if ent["epoch"] == epoch or ent[
-                    "versions"
-                ] == self._leaf_versions(index, leaves, slices):
-                    ent["epoch"] = epoch
-                    with self._batch_mu:
-                        if key in self._batch_cache:
-                            self._batch_cache.move_to_end(key)
-                    device_mod.pool().touch(self._batch_pool_key(key))
-                    sp.annotate(batch_cache="hit")
-                    return ent
+        with self.tracer.span("plan.resolve") as rs:
+            c = self._rewrite_bsi(index, c)
+            expr, leaves = plan.decompose(c)
+            cacheable = all(leaf.name in plan.LEAF_CALLS for leaf in leaves)
+            key = (index, str(c), tuple(slices))
+            if cacheable:
+                with self._batch_mu:
+                    ent = self._batch_cache.get(key)
+                if ent is not None:
+                    epoch = fragment_mod.write_epoch()
+                    if ent["epoch"] == epoch or ent[
+                        "versions"
+                    ] == self._leaf_versions(index, leaves, slices):
+                        ent["epoch"] = epoch
+                        with self._batch_mu:
+                            if key in self._batch_cache:
+                                self._batch_cache.move_to_end(key)
+                        device_mod.pool().touch(self._batch_pool_key(key))
+                        sp.annotate(batch_cache="hit")
+                        return ent
 
-        sp.annotate(batch_cache="miss")
-        # Capture validity BEFORE building: a concurrent write during
-        # assembly leaves the entry conservatively stale.  The same
-        # sweep counts mirror-less fragments for the cold-path choice.
-        epoch = fragment_mod.write_epoch()
-        versions = None
-        n_frag = n_cold = 0
-        if cacheable:
-            versions, n_frag, n_cold = self._leaf_versions(
-                index, leaves, slices, with_cold=True
-            )
+            sp.annotate(batch_cache="miss")
+            # Capture validity BEFORE building: a concurrent write during
+            # assembly leaves the entry conservatively stale.  The same
+            # sweep counts mirror-less fragments for the cold-path choice.
+            epoch = fragment_mod.write_epoch()
+            versions = None
+            n_frag = n_cold = 0
+            if cacheable:
+                versions, n_frag, n_cold = self._leaf_versions(
+                    index, leaves, slices, with_cold=True
+                )
+            rs.annotate(fragments=n_frag, cold=n_cold)
         mesh = pmesh.default_slices_mesh()
         ent = {
             "batch": None,
@@ -1130,13 +1135,20 @@ class Executor:
             )
             ent.update(expr=expr, empties=empties, kept=kept_slices)
             if len(kept_slices) > 1:
-                batch, pos_of = self._assemble_mesh_batch(
-                    stacks, kept_slices, mesh
-                )
+                with self.tracer.span(
+                    "plan.transfer", devices=int(mesh.devices.size)
+                ) as ts:
+                    batch, pos_of = self._assemble_mesh_batch(
+                        stacks, kept_slices, mesh
+                    )
+                    ts.annotate(bytes=int(batch.nbytes))
                 ent.update(batch=batch, pos_of=pos_of, mesh=mesh)
             elif kept_slices:
+                with self.tracer.span("plan.transfer", devices=1) as ts:
+                    batch = jnp.stack(stacks)
+                    ts.annotate(bytes=int(batch.nbytes))
                 ent.update(
-                    batch=jnp.stack(stacks),
+                    batch=batch,
                     pos_of={s: i for i, s in enumerate(kept_slices)},
                 )
         # Per-column leaf identity keys for union-leaf fusion
@@ -1156,21 +1168,26 @@ class Executor:
         )
         if cacheable:
             displaced = []
-            with self._batch_mu:
-                self._batch_cache[key] = ent
-                while len(self._batch_cache) > self._BATCH_CACHE_CAP:
-                    displaced.append(self._batch_cache.popitem(last=False)[0])
-            # Pool tenancy OUTSIDE _batch_mu: admission may evict other
-            # tenants, whose callbacks take _batch_mu non-blocking.
-            pool = device_mod.pool()
-            for k in displaced:
-                pool.remove(self._batch_pool_key(k))
-            ent["pool_key"] = self._register_cache_entry(
-                self._batch_pool_key(key),
-                [ent["batch"]],
-                {"cache": "batch", "index": index, "query": str(c)},
-                functools.partial(self._evict_batch_key, key),
-            )
+            with self.tracer.span("plan.register") as gs:
+                with self._batch_mu:
+                    self._batch_cache[key] = ent
+                    while len(self._batch_cache) > self._BATCH_CACHE_CAP:
+                        displaced.append(
+                            self._batch_cache.popitem(last=False)[0]
+                        )
+                # Pool tenancy OUTSIDE _batch_mu: admission may evict
+                # other tenants, whose callbacks take _batch_mu
+                # non-blocking.
+                pool = device_mod.pool()
+                for k in displaced:
+                    pool.remove(self._batch_pool_key(k))
+                ent["pool_key"] = self._register_cache_entry(
+                    self._batch_pool_key(key),
+                    [ent["batch"]],
+                    {"cache": "batch", "index": index, "query": str(c)},
+                    functools.partial(self._evict_batch_key, key),
+                )
+                gs.annotate(displaced=len(displaced))
         return ent
 
     def _assemble_mesh_batch_host(self, index: str, leaves, slices, mesh):
@@ -1185,47 +1202,54 @@ class Executor:
         rows_of: dict[int, np.ndarray] = {}
         kept: list[int] = []
         empties: list[int] = []
-        for s in slices:
-            buf = None
-            any_set = False
-            for j, leaf in enumerate(leaves):
-                w = self._leaf_row_host(index, leaf, s)
-                if w is not None:
-                    if buf is None:
-                        buf = np.zeros(
-                            (n_leaves, bp.WORDS_PER_SLICE), dtype=np.uint32
-                        )
-                    buf[j] = w
-                    if leaf.name not in plan.NEUTRAL_LEAVES:
-                        any_set = True
-            if not any_set:
-                empties.append(s)
-            else:
-                kept.append(s)
-                rows_of[s] = buf
+        with self.tracer.span("plan.leaves", path="mesh_host_fill") as sp:
+            n_rows = 0
+            for s in slices:
+                buf = None
+                any_set = False
+                for j, leaf in enumerate(leaves):
+                    w = self._leaf_row_host(index, leaf, s)
+                    if w is not None:
+                        if buf is None:
+                            buf = np.zeros(
+                                (n_leaves, bp.WORDS_PER_SLICE), dtype=np.uint32
+                            )
+                        buf[j] = w
+                        n_rows += 1
+                        if leaf.name not in plan.NEUTRAL_LEAVES:
+                            any_set = True
+                if not any_set:
+                    empties.append(s)
+                else:
+                    kept.append(s)
+                    rows_of[s] = buf
+            sp.annotate(rows=n_rows, device_copies=0)
         if not kept:
             return None, {}, kept, empties
         if len(kept) == 1:
-            return (
-                jnp.asarray(rows_of[kept[0]][None]),
-                {kept[0]: 0},
-                kept,
-                empties,
-            )
+            with self.tracer.span("plan.transfer", devices=1) as sp:
+                one = rows_of[kept[0]][None]
+                sp.annotate(bytes=int(one.nbytes))
+                return jnp.asarray(one), {kept[0]: 0}, kept, empties
 
         n_dev = int(mesh.devices.size)
-        groups, chunk = self._mesh_placement(kept, n_dev)
-        blocks = []
-        pos_of: dict[int, int] = {}
-        for d in range(n_dev):
-            block = np.zeros(
-                (chunk, n_leaves, bp.WORDS_PER_SLICE), dtype=np.uint32
-            )
-            for i, s in enumerate(groups[d]):
-                block[i] = rows_of[s]
-                pos_of[s] = d * chunk + i
-            blocks.append(jax.device_put(block, mesh.devices.flat[d]))
-        return pmesh.assemble_sharded_batch(blocks, mesh), pos_of, kept, empties
+        with self.tracer.span("plan.transfer", devices=n_dev) as sp:
+            groups, chunk = self._mesh_placement(kept, n_dev)
+            blocks = []
+            pos_of: dict[int, int] = {}
+            n_bytes = 0
+            for d in range(n_dev):
+                block = np.zeros(
+                    (chunk, n_leaves, bp.WORDS_PER_SLICE), dtype=np.uint32
+                )
+                for i, s in enumerate(groups[d]):
+                    block[i] = rows_of[s]
+                    pos_of[s] = d * chunk + i
+                n_bytes += block.nbytes
+                blocks.append(jax.device_put(block, mesh.devices.flat[d]))
+            sp.annotate(bytes=int(n_bytes))
+            batch = pmesh.assemble_sharded_batch(blocks, mesh)
+        return batch, pos_of, kept, empties
 
     @staticmethod
     def _mesh_placement(kept: list[int], n_dev: int):
@@ -1388,24 +1412,6 @@ class Executor:
         health.success(paths, probe=probe)
         return res
 
-    def _device_span(self, ent: dict, reduce: str):
-        """Span for one fused device program dispatch+fetch, annotated
-        with compile-vs-execute visibility: ``warm`` is whether this
-        executor already dispatched the same (tree shape, reduce, batch
-        shape) program — a cold call bears XLA compilation unless the
-        persistent compile cache (exec/warmup.py) serves it, which
-        ``persistent_cache`` records."""
-        shape = None if ent["batch"] is None else tuple(ent["batch"].shape)
-        key = (ent["expr"], reduce, shape)
-        warm = key in self._seen_programs
-        self._seen_programs.add(key)
-        return self.tracer.span(
-            "exec.device",
-            reduce=reduce,
-            warm=warm,
-            persistent_cache=bool(warmup.enabled_cache_dir()),
-        )
-
     def _record_direct_launch(
         self, ent: dict, reduce: str, t0, t_disp, t1, site: str = "direct"
     ) -> None:
@@ -1445,18 +1451,15 @@ class Executor:
         The per-query ``coalesce`` span covers queue wait + the shared
         launch and carries the launch's batch stats (occupancy, rows,
         padding) — the trace-level evidence that N queries rode one
-        dispatch.  Compile-warmth bookkeeping matches _device_span so a
-        coalesced first launch is as visible as a direct one."""
+        dispatch.  The dispatcher records the launch itself as a
+        ``launch`` child (with a ``compile`` under it on a first call
+        per shape), so this span's self time is the queue wait."""
         # Chaos hook: an injected fault here surfaces exactly like a
         # coalesced launch error — the waiter's health guard classifies
         # it and fails over PER WAITER, never poisoning the shared
         # batch.
         self._fault_check_launch("coalesce")
-        shape = tuple(ent["batch"].shape)
-        pkey = (ent["expr"], reduce, shape)
-        warm = pkey in self._seen_programs
-        self._seen_programs.add(pkey)
-        with self.tracer.span("coalesce", reduce=reduce, warm=warm) as sp:
+        with self.tracer.span("coalesce", reduce=reduce) as sp:
             try:
                 fut = self.coalescer.submit(
                     ent["expr"],
@@ -1555,7 +1558,7 @@ class Executor:
             # may not evict the batch out from under the dispatch+fetch.
             with device_mod.pool().pinned(
                 ent.get("pool_key")
-            ), self._device_span(ent, reduce):
+            ), self.tracer.span("exec.device", reduce=reduce):
                 self._fault_check_launch("direct")
                 t0 = time.monotonic()
                 if ent["mesh"] is not None:
@@ -1662,7 +1665,9 @@ class Executor:
             return Executor._anchor_candidates(expr[1])
         return set()
 
-    def _try_anchored_count(self, index: str, c: Call, slices: list[int]):
+    def _try_anchored_count(
+        self, index: str, c: Call, slices: list[int], sp
+    ):
         """Compressed-plane Count: when the tree is fold-only over
         Bitmap leaves and some AND-dominating leaf is sparse, evaluate
         the expression POINTWISE over that anchor leaf's positions
@@ -1671,7 +1676,14 @@ class Executor:
         128 KiB.  Returns the exact total, or None to decline (the
         caller falls through to the batched word-domain path; any
         failure here also declines, so the guarded path retains its
-        retry/host-fallback semantics)."""
+        retry/host-fallback semantics).
+
+        ``sp`` is the caller's ``anchored.prepass`` span: it leaves with
+        an ``outcome`` (``not_eligible`` before the slice loop, else
+        ``declined_too_dense`` / ``declined_dense`` / ``answered`` /
+        ``error``) and how far the loop got — slices walked, anchor
+        rows scanned for their positions."""
+        sp.annotate(outcome="not_eligible", slices_walked=0, anchors_scanned=0)
         if bp.PLANE_FORMAT == "dense":
             return None
         try:
@@ -1685,13 +1697,15 @@ class Executor:
         cands = self._anchor_candidates(expr)
         if not cands:
             return None
+        outcome = "error"
+        walked = scanned = 0
         try:
             # Per-slice leaf resolution + anchor pick, grouped by the
             # per-leaf container-format signature (formats may differ
             # per slice; each signature is its own compiled wrapper).
             groups: dict[tuple, list] = {}
             any_compressed = False
-            for s in slices:
+            for walked, s in enumerate(slices, 1):
                 resolved = [
                     self._resolve_bitmap_leaf(index, leaf, s)
                     for leaf in leaves
@@ -1706,8 +1720,11 @@ class Executor:
                 if card == 0:
                     continue  # empty anchor bounds the slice count at 0
                 if card > self.ANCHORED_MAX_POSITIONS:
-                    return None  # too dense: whole query keeps one path
+                    # too dense: whole query keeps one path
+                    outcome = "declined_too_dense"
+                    return None
                 afrag, arid = resolved[ai]
+                scanned += 1
                 anchor = afrag.row_positions(arid)
                 if anchor is None or len(anchor) == 0:
                     continue
@@ -1739,13 +1756,19 @@ class Executor:
                 # gathers save no bytes, and the batched word-domain
                 # path keeps its cache/coalesce behavior.  (Dense-tier
                 # corpora — the default budget — always land here.)
+                outcome = "declined_dense"
                 return None
             total = 0
             for fmts, items in groups.items():
                 total += self._anchored_launch(expr, fmts, items)
+            outcome = "answered"
             return int(total)
         except Exception:  # noqa: BLE001 — decline, main path decides
             return None
+        finally:
+            sp.annotate(
+                outcome=outcome, slices_walked=walked, anchors_scanned=scanned
+            )
 
     def _anchored_launch(
         self, expr: tuple, fmts: tuple, items: list
@@ -1827,7 +1850,8 @@ class Executor:
             # Declines (None) fall through to the batched word-domain
             # path unchanged.  Healthy devices only: a granted probe
             # must resolve through the guarded launch below.
-            anchored = self._try_anchored_count(index, c, slices)
+            with self.tracer.span("anchored.prepass") as sp:
+                anchored = self._try_anchored_count(index, c, slices, sp)
             if anchored is not None:
                 return anchored
         ent = self._cached_batch(index, c, slices)
@@ -1882,7 +1906,7 @@ class Executor:
         def direct():
             with device_mod.pool().pinned(
                 ent.get("pool_key")
-            ), self._device_span(ent, "count"):
+            ), self.tracer.span("exec.device", reduce="count"):
                 self._fault_check_launch("direct")
                 if ent["mesh"] is not None:
                     # Zero pad slices contribute nothing, so the budget
@@ -2164,7 +2188,7 @@ class Executor:
         def direct():
             with device_mod.pool().pinned(
                 ent.get("pool_key")
-            ), self._device_span(ent, "agg"):
+            ), self.tracer.span("exec.device", reduce="agg"):
                 self._fault_check_launch("direct")
                 return np.asarray(
                     jax.device_get(

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from pilosa_tpu.bsi import ripple
+from pilosa_tpu.obs import trace
 from pilosa_tpu.pql.parser import WRITE_CALLS, Call
 
 # Calls that fetch rows (leaves of a bitmap expression).  The Bsi*
@@ -708,12 +709,21 @@ class _Program:
         )
         if shapes in self._seen_shapes:
             return self.fn(batch, *args)
-        t0 = time.monotonic()
+        start, t0 = time.time(), time.monotonic()
         out = self.fn(batch, *args)
+        ms = (time.monotonic() - t0) * 1e3
         # Unlocked set add + dict accumulate: a racing duplicate first
         # call double-counts a few ms of telemetry, never corrupts.
         self._seen_shapes.add(shapes)
-        _note_compile_ms(self.family, (time.monotonic() - t0) * 1e3)
+        _note_compile_ms(self.family, ms)
+        # The same interval as a ``compile`` span in the trace of the
+        # request that waited on it: under the dispatcher's ``launch``
+        # on a coalesced launch, else under the caller's current span.
+        sp = trace.current_span()
+        if sp is not None:
+            sp.add_child(
+                "compile", start, ms, family=self.family, shape=str(shapes)
+            )
         return out
 
     def lower(self, *args, **kwargs):

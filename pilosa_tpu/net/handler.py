@@ -1946,6 +1946,7 @@ class Handler:
             except Exception:  # noqa: BLE001 — stats must not fail the scrape
                 snap = {}
         self._inject_program_cache_gauges(snap)
+        self._inject_device_memory_gauges(snap)
         if self.admission is not None:
             # Scrape-time admission gauges (active/queued/concurrency/
             # EWMA per class) — like the program-cache gauges, they
@@ -2023,6 +2024,26 @@ class Handler:
         except Exception:  # noqa: BLE001 — stats must not fail the scrape
             pass
 
+    @staticmethod
+    def _inject_device_memory_gauges(snap: dict) -> None:
+        """Scrape-time allocator gauges per local device, from
+        ``memory_stats()``: ``device.<i>.hbm_bytes_in_use`` and
+        ``device.<i>.hbm_peak_bytes_in_use``, the allocator's high-water
+        mark since the process started.  Read at the scrape, so a peak
+        between two scrapes is not lost; a backend that reports no
+        memory statistics (CPU) renders neither."""
+        try:
+            import jax
+
+            gauges = snap.setdefault("gauges", {})
+            for i, dev in enumerate(jax.local_devices()):
+                mem = dev.memory_stats() or {}
+                for key in ("bytes_in_use", "peak_bytes_in_use"):
+                    if key in mem:
+                        gauges[f"device.{i}.hbm_{key}"] = mem[key]
+        except Exception:  # noqa: BLE001 — stats must not fail the scrape
+            pass
+
     def handle_get_perf(self, req: Request) -> Response:
         """The launch-telemetry roofline table (obs/perf.py): per-site
         launches, logical bytes streamed, achieved GB/s, % of the
@@ -2059,7 +2080,15 @@ class Handler:
         ``?seconds=N`` (clamped to 60), tars the trace directory under
         the data dir, and returns its path.  Single-flight — a second
         concurrent request answers 409; a runtime without the profiler
-        answers 501 (the capture is optional, the endpoint is not)."""
+        answers 501 (the capture is optional, the endpoint is not).
+
+        The Python tracer is OFF unless ``?python=1`` asks for it: it
+        hooks every call of a server whose bottleneck is Python and
+        stalls it for seconds at the stop, so a profile taken with it
+        measures the profiler.  The host tracer stays on, and while the
+        session is live every program span also enters a
+        ``TraceAnnotation`` (obs/trace.py), so the spans stand in the
+        profile's host plane beside the device ops."""
         try:
             seconds = max(0.05, min(float(req.query.get("seconds", "3")), 60.0))
         except ValueError:
@@ -2079,8 +2108,14 @@ class Handler:
             )
             os.makedirs(trace_dir, exist_ok=True)
             try:
-                with profiler.trace(trace_dir):
-                    time.sleep(seconds)
+                opts = profiler.ProfileOptions()
+                opts.python_tracer_level = int(req.query.get("python") == "1")
+                with profiler.trace(trace_dir, profiler_options=opts):
+                    trace.set_profiling(profiler.TraceAnnotation)
+                    try:
+                        time.sleep(seconds)
+                    finally:
+                        trace.set_profiling(None)
             except Exception as e:  # noqa: BLE001 — backend without xprof
                 shutil.rmtree(trace_dir, ignore_errors=True)
                 return Response.error(f"jax profiler unavailable: {e}", 501)

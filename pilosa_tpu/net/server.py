@@ -881,18 +881,6 @@ class Server:
             scatter_mod.publish_stats(self.stats)
         except Exception:  # noqa: BLE001 — stats are best-effort
             pass
-        try:
-            import jax
-
-            for i, dev in enumerate(jax.local_devices()):
-                ms = getattr(dev, "memory_stats", None)
-                mem = ms() if callable(ms) else None
-                if mem and "bytes_in_use" in mem:
-                    self.stats.gauge(
-                        f"device.{i}.hbm_bytes_in_use", mem["bytes_in_use"]
-                    )
-        except Exception:  # noqa: BLE001 — device stats are best-effort
-            pass
 
     def _on_device_health_change(self, path: str, state: str) -> None:
         """Device-health transitions (quarantine/heal) from the health

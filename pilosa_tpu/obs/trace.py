@@ -5,7 +5,9 @@ query gets a trace; the last ``capacity`` finished traces are retained in
 a ring buffer served as JSON by ``GET /debug/traces``.
 
 A trace is a tree of :class:`Span` objects sharing one ``trace_id``.
-Spans time with ``time.monotonic()`` and link parent→child two ways:
+Spans time with ``time.monotonic()`` (and the opening thread's CPU time
+with ``time.thread_time()``: wall less CPU is time off the processor —
+the GIL, a lock, a blocking fetch) and link parent→child two ways:
 
 * in-process via a ``contextvars.ContextVar`` holding the active span —
   crossing threads works because the executor's pool captures the
@@ -17,6 +19,15 @@ Spans time with ``time.monotonic()`` and link parent→child two ways:
   client absorbs back into the coordinator's open trace — so ONE trace
   on the coordinator covers parse, plan, local slice execution, and
   every remote node's leg.
+
+Work done once for several traces on a thread that has no current span
+(a coalesced launch on the dispatcher) times as a :class:`SharedSpan`
+and lands in each waiter's trace through :meth:`Tracer.add_span`.
+
+While a ``/debug/profile`` session is live (:func:`set_profiling`) every
+span entered also stands in the profiler's host plane as a
+``jax.profiler.TraceAnnotation`` of its name, on the profiler's own
+clock beside the device ops.
 
 ``NOP_TRACER`` is the disabled implementation: components constructed
 without a tracer (unit tests, embedders) pay one no-op method call per
@@ -46,6 +57,18 @@ MAX_EXPORT_SPANS = 128
 _current_span: "contextvars.ContextVar[Span | None]" = contextvars.ContextVar(
     "pilosa_current_span", default=None
 )
+
+# ``jax.profiler.TraceAnnotation`` while a /debug/profile session is
+# live, else None: outside a session a span pays one global read.
+_annotation = None
+
+
+def set_profiling(annotation) -> None:
+    """The /debug/profile handler brackets its session with this, under
+    its single-flight lock: the profiler's ``TraceAnnotation`` class at
+    the start, None at the stop."""
+    global _annotation
+    _annotation = annotation
 
 
 def current_span() -> "Span | None":
@@ -79,9 +102,13 @@ class Span:
         "parent_id",
         "start",
         "_t0",
+        "_cpu0",
+        "_thread",
         "duration_ms",
+        "cpu_ms",
         "tags",
         "_token",
+        "_anno",
     )
 
     def __init__(self, tracer, name: str, trace_id: str, parent_id: str | None,
@@ -93,9 +120,15 @@ class Span:
         self.parent_id = parent_id
         self.start = time.time()
         self._t0 = time.monotonic()
+        self._cpu0 = time.thread_time()
+        self._thread = threading.get_ident()
         self.duration_ms: float | None = None
+        # CPU time of the opening thread over the span; None when the
+        # span finished on (or was recorded for) another thread.
+        self.cpu_ms: float | None = None
         self.tags = dict(tags) if tags else {}
         self._token = None
+        self._anno = None
 
     def annotate(self, **tags) -> "Span":
         self.tags.update(tags)
@@ -108,16 +141,32 @@ class Span:
     def deactivate(self, token) -> None:
         _current_span.reset(token)
 
+    def _stop_clocks(self) -> None:
+        self.duration_ms = (time.monotonic() - self._t0) * 1000.0
+        if threading.get_ident() == self._thread:
+            self.cpu_ms = (time.thread_time() - self._cpu0) * 1000.0
+
     def finish(self) -> None:
         if self.duration_ms is None:
-            self.duration_ms = (time.monotonic() - self._t0) * 1000.0
+            self._stop_clocks()
             self.tracer._record(self)
+
+    def add_child(self, name: str, start: float, duration_ms: float, **tags):
+        """Record an already finished child (see Tracer.add_span)."""
+        return self.tracer.add_span(self, name, start, duration_ms, **tags)
 
     def __enter__(self) -> "Span":
         self._token = self.activate()
+        anno = _annotation
+        if anno is not None:
+            self._anno = anno(self.name, trace_id=self.trace_id)
+            self._anno.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._anno is not None:
+            self._anno.__exit__(exc_type, exc, tb)
+            self._anno = None
         if self._token is not None:
             self.deactivate(self._token)
             self._token = None
@@ -134,8 +183,45 @@ class Span:
             "duration_ms": round(self.duration_ms, 3)
             if self.duration_ms is not None
             else None,
+            "cpu_ms": round(self.cpu_ms, 3)
+            if self.cpu_ms is not None
+            else None,
             "tags": self.tags,
         }
+
+
+class SharedSpan(Span):
+    """Work done once for several traces, on a thread that has no
+    current span: a coalesced launch on the dispatcher.  While open it
+    is that thread's current span and belongs to no trace; children
+    recorded under it (a ``compile``) are kept, and :meth:`publish`
+    records it and them under each waiter's span, so every waiter's
+    trace shows the one launch at the same wall-clock ``start``."""
+
+    __slots__ = ("children",)
+
+    def __init__(self, name: str, trace_id: str = "", **tags):
+        # ``trace_id`` (the first waiter's) only names the profile's
+        # annotation; no tracer has that trace open for this span.
+        super().__init__(NOP_TRACER, name, trace_id, None, tags)
+        self.children: list[tuple] = []
+
+    def finish(self) -> None:
+        if self.duration_ms is None:
+            self._stop_clocks()
+
+    def add_child(self, name: str, start: float, duration_ms: float, **tags):
+        self.children.append((name, start, duration_ms, tags))
+
+    def publish(self, parents) -> None:
+        for parent in parents:
+            if parent is None:
+                continue
+            sp = parent.add_child(
+                self.name, self.start, self.duration_ms, **self.tags
+            )
+            for name, start, ms, tags in self.children:
+                sp.add_child(name, start, ms, **tags)
 
 
 class Tracer:
@@ -147,8 +233,6 @@ class Tracer:
         # trace_id -> {"root": Span, "spans": [span dicts], "started": t}
         self._open: dict[str, dict] = {}
         self._ring: "deque[dict]" = deque(maxlen=self.capacity)
-        # Spans that finished after their trace was finalized (debug aid).
-        self.late_spans = 0
 
     # -- span creation --------------------------------------------------
 
@@ -179,14 +263,28 @@ class Tracer:
             return Span(self, name, new_trace_id(), None, tags)
         return Span(self, name, parent.trace_id, parent.span_id, tags)
 
+    def add_span(
+        self, parent: Span, name: str, start: float, duration_ms: float,
+        **tags,
+    ) -> Span:
+        """Record a FINISHED child of ``parent`` with an explicit
+        wall-clock ``start`` and duration — work another thread did for
+        this trace (the coalescer's dispatcher has no current span).
+        It carries no ``cpu_ms``; once the trace is final it is dropped
+        like any late span."""
+        span = Span(self, name, parent.trace_id, parent.span_id, tags)
+        span.start = start
+        span.duration_ms = duration_ms
+        self._record(span)
+        return span
+
     # -- recording ------------------------------------------------------
 
     def _record(self, span: Span) -> None:
         with self._mu:
             ent = self._open.get(span.trace_id)
             if ent is None:
-                self.late_spans += 1
-                return
+                return  # the trace is already final
             if ent["root"] is span:
                 return  # the root records at finish_root
             if len(ent["spans"]) < MAX_SPANS_PER_TRACE:
@@ -205,7 +303,6 @@ class Tracer:
         with self._mu:
             ent = self._open.get(trace_id)
             if ent is None:
-                self.late_spans += 1
                 return
             room = MAX_SPANS_PER_TRACE - len(ent["spans"])
             ent["spans"].extend(
@@ -216,7 +313,7 @@ class Tracer:
         """Finish the trace root, finalize the trace, retain it in the
         ring, and return the trace record."""
         if root.duration_ms is None:
-            root.duration_ms = (time.monotonic() - root._t0) * 1000.0
+            root._stop_clocks()
         with self._mu:
             ent = self._open.pop(root.trace_id, None)
             if ent is None:
@@ -290,6 +387,9 @@ class _NopSpan(Span):
     def finish(self):
         pass
 
+    def add_child(self, name, start, duration_ms, **tags):
+        return self
+
     def __enter__(self):
         return self
 
@@ -310,6 +410,9 @@ class NopTracer(Tracer):
         return NOP_SPAN
 
     def span(self, name, parent=None, **tags):
+        return NOP_SPAN
+
+    def add_span(self, parent, name, start, duration_ms, **tags):
         return NOP_SPAN
 
     def absorb(self, payload):

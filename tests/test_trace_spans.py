@@ -1,0 +1,370 @@
+"""The spans where the host's seconds go: what a span records (wall and
+CPU time, spans recorded for another thread), the stages of a served
+Count (the anchored pre-pass, the children of ``plan``, the dispatcher's
+``launch`` and its ``compile``), and the profile ``/debug/profile``
+takes of them."""
+
+import glob
+import json
+import tarfile
+import threading
+import time
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pilosa_tpu.exec import plan
+from pilosa_tpu.exec.coalesce import CoalesceScheduler
+from pilosa_tpu.net import handler as handler_mod
+from pilosa_tpu.net.client import InternalClient
+from pilosa_tpu.net.server import Server
+from pilosa_tpu.obs import stats as stats_mod
+from pilosa_tpu.obs import trace
+from pilosa_tpu.ops.bitplane import SLICE_WIDTH
+
+PLAN_STAGES = ("plan.resolve", "plan.leaves", "plan.transfer", "plan.register")
+
+
+# ---------------------------------------------------------------------------
+# what a span records
+# ---------------------------------------------------------------------------
+
+
+def _one_span(tr, body):
+    root = tr.start_trace("query")
+    token = root.activate()
+    with tr.span("work"):
+        body()
+    root.deactivate(token)
+    rec = tr.finish_root(root)
+    return next(s for s in rec["spans"] if s["name"] == "work"), rec
+
+
+def _spin():
+    end = time.monotonic() + 0.05
+    while time.monotonic() < end:
+        pass
+
+
+@pytest.mark.parametrize("body,busy", [(_spin, True),
+                                       (lambda: time.sleep(0.05), False)])
+def test_cpu_ms_is_the_threads_time_on_the_processor(body, busy):
+    span, rec = _one_span(trace.Tracer(), body)
+    assert span["duration_ms"] >= 45
+    assert span["cpu_ms"] is not None
+    assert span["cpu_ms"] <= span["duration_ms"] + 1
+    if busy:
+        assert span["cpu_ms"] > 0.5 * span["duration_ms"]
+    else:
+        assert span["cpu_ms"] < 10
+    # the root carries it too, and the export header with it
+    assert rec["spans"][0]["cpu_ms"] is not None
+    assert '"cpu_ms"' in trace.Tracer.export_payload(rec)
+
+
+def test_a_span_finished_on_another_thread_has_no_cpu_ms():
+    tr = trace.Tracer()
+    root = tr.start_trace("query")
+    sp = tr.span("handed_over", parent=root)
+    t = threading.Thread(target=sp.finish)
+    t.start()
+    t.join(timeout=10)
+    rec = tr.finish_root(root)
+    got = next(s for s in rec["spans"] if s["name"] == "handed_over")
+    assert got["duration_ms"] is not None and got["cpu_ms"] is None
+
+
+def test_add_span_lands_under_its_parent_and_is_dropped_once_final():
+    tr = trace.Tracer()
+    root = tr.start_trace("query")
+    parent = tr.span("coalesce", parent=root)
+    child = tr.add_span(parent, "launch", 1234.5, 7.25, site="total")
+    tr.add_span(child, "compile", 1234.6, 3.0, family="plan.batched")
+    parent.finish()
+    rec = tr.finish_root(root)
+    by_name = {s["name"]: s for s in rec["spans"]}
+    launch = by_name["launch"]
+    assert launch["parent_id"] == parent.span_id
+    assert (launch["start"], launch["duration_ms"]) == (1234.5, 7.25)
+    assert launch["cpu_ms"] is None and launch["tags"] == {"site": "total"}
+    assert by_name["compile"]["parent_id"] == launch["span_id"]
+    # the trace is final: a launch that ends now is not recorded
+    tr.add_span(parent, "launch", 1.0, 1.0)
+    assert [s["name"] for s in tr.traces()[-1]["spans"]].count("launch") == 1
+    # tracing disabled: a no-op that still hands back a parent
+    nop = trace.NOP_TRACER.add_span(trace.NOP_SPAN, "launch", 1.0, 1.0)
+    assert nop.add_child("compile", 1.0, 1.0) is nop
+    assert not hasattr(trace.Tracer(), "late_spans")
+
+
+# ---------------------------------------------------------------------------
+# the dispatcher's launch, once per waiter
+# ---------------------------------------------------------------------------
+
+
+def _waiter(tr, co, expr, batch, out):
+    root = tr.start_trace("query")
+    token = root.activate()
+    with tr.span("coalesce"):
+        co.submit(expr, "count", batch).result(timeout=60)
+    root.deactivate(token)
+    out.append(tr.finish_root(root))
+
+
+def test_two_queries_on_one_launch_each_get_the_same_launch_span(rng):
+    tr = trace.Tracer()
+    co = CoalesceScheduler(max_wait_us=300_000)
+    try:
+        # a shape no other test has called, so this launch is a first call
+        batch = jnp.asarray(
+            rng.integers(0, 2**32, size=(4, 2, 48), dtype=np.uint32))
+        expr = ("Xor", ("leaf", 0), ("leaf", 1))
+        recs: list = []
+        threads = [threading.Thread(target=_waiter,
+                                    args=(tr, co, expr, batch, recs))
+                   for _ in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert len(recs) == 2
+        launches = []
+        for rec in recs:
+            by_name = {s["name"]: s for s in rec["spans"]}
+            launch = by_name["launch"]
+            assert launch["parent_id"] == by_name["coalesce"]["span_id"]
+            assert launch["tags"]["site"] == "coalesce"
+            assert launch["tags"]["queries"] == 2
+            assert launch["tags"]["first_call"] is True
+            assert launch["tags"]["dispatch_ms"] <= launch["duration_ms"]
+            assert launch["cpu_ms"] is None
+            # the launch lies inside the wait for it
+            assert launch["duration_ms"] <= by_name["coalesce"]["duration_ms"]
+            compile_ = by_name["compile"]
+            assert compile_["parent_id"] == launch["span_id"]
+            assert compile_["tags"]["family"] == "plan.batched"
+            assert "(4, 2, 48)" in compile_["tags"]["shape"]
+            launches.append(launch)
+        assert launches[0]["start"] == launches[1]["start"]
+        assert launches[0]["duration_ms"] == launches[1]["duration_ms"]
+        assert launches[0]["span_id"] != launches[1]["span_id"]
+        # the same shape again: a launch, and no compile
+        again: list = []
+        _waiter(tr, co, expr, batch, again)
+        names = [s["name"] for s in again[0]["spans"]]
+        assert names.count("launch") == 1 and "compile" not in names
+        assert next(s for s in again[0]["spans"]
+                    if s["name"] == "launch")["tags"]["first_call"] is False
+    finally:
+        co.close()
+
+
+def test_a_direct_first_call_compiles_under_the_current_span(rng):
+    tr = trace.Tracer()
+    batch = jnp.asarray(rng.integers(0, 2**32, size=(2, 2, 80), dtype=np.uint32))
+    prog = plan.compiled_batched(("Union", ("leaf", 0), ("leaf", 1)), "count")
+    root = tr.start_trace("query")
+    token = root.activate()
+    with tr.span("exec.device") as dev:
+        prog(batch)
+        prog(batch)
+    root.deactivate(token)
+    rec = tr.finish_root(root)
+    compiles = [s for s in rec["spans"] if s["name"] == "compile"]
+    assert len(compiles) == 1 and compiles[0]["parent_id"] == dev.span_id
+    # outside any trace the first call records nothing and does not raise
+    plan.compiled_batched(("Xor", ("leaf", 0), ("leaf", 1)), "count")(batch)
+
+
+# ---------------------------------------------------------------------------
+# a served Count
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def server(tmp_path):
+    s = Server(
+        data_dir=str(tmp_path / "data"),
+        stats=stats_mod.ExpvarStatsClient(),
+        anti_entropy_interval=3600,
+        polling_interval=3600,
+        cache_flush_interval=3600,
+    )
+    s.open()
+    yield s
+    s.close()
+
+
+def _populate(s, slices):
+    s.holder.create_index_if_not_exists("i")
+    f = s.holder.index("i").create_frame_if_not_exists("f")
+    for sl in range(slices):
+        for r in (1, 2):
+            f.set_bit("standard", r, sl * SLICE_WIDTH + 5)
+        f.set_bit("standard", 1, sl * SLICE_WIDTH + 9)
+
+
+def _last_trace(c):
+    _status, data = c._request("GET", "/debug/traces")
+    return json.loads(data)["traces"][-1]
+
+
+def _children(t, name):
+    parent = next(s for s in t["spans"] if s["name"] == name)
+    return [s for s in t["spans"] if s["parent_id"] == parent["span_id"]]
+
+
+INTERSECT = 'Count(Intersect(Bitmap(frame="f", rowID=1), Bitmap(frame="f", rowID=2)))'
+UNION = 'Count(Union(Bitmap(frame="f", rowID=1), Bitmap(frame="f", rowID=2)))'
+
+
+def test_the_stages_of_a_served_count(server):
+    _populate(server, 3)
+    c = InternalClient(server.host, timeout=60.0)
+    assert c.execute_pql("i", INTERSECT) == 3
+    miss = _last_trace(c)
+    pre = [s for s in miss["spans"] if s["name"] == "anchored.prepass"]
+    assert len(pre) == 1
+    # the test corpus is dense-tier: walked to the end, then declined
+    assert pre[0]["tags"] == {"outcome": "declined_dense", "slices_walked": 3,
+                              "anchors_scanned": 3}
+    assert pre[0] in _children(miss, "map.local")
+    plan_span = next(s for s in miss["spans"] if s["name"] == "plan")
+    assert plan_span["tags"]["batch_cache"] == "miss"
+    stages = _children(miss, "plan")
+    assert [s["name"] for s in stages] == list(PLAN_STAGES)
+    assert sum(s["duration_ms"] for s in stages) <= plan_span["duration_ms"]
+    by_name = {s["name"]: s for s in stages}
+    assert by_name["plan.resolve"]["tags"]["fragments"] == 6
+    leaves = by_name["plan.leaves"]["tags"]
+    assert leaves["path"] in ("host_fill", "mesh_host_fill", "device_gather")
+    assert leaves["rows"] == 6
+    assert (leaves["device_copies"] > 0) == (leaves["path"] == "device_gather")
+    assert by_name["plan.transfer"]["tags"]["bytes"] > 0
+    assert by_name["plan.transfer"]["tags"]["devices"] >= 1
+    assert by_name["plan.register"]["tags"]["displaced"] == 0
+    launch = [s for s in miss["spans"] if s["name"] == "launch"]
+    assert len(launch) == 1 and launch[0] in _children(miss, "coalesce")
+    # the tags that guessed at compiles are gone; the compile is a span
+    for s in miss["spans"]:
+        assert "warm" not in s["tags"] and "persistent_cache" not in s["tags"]
+
+    assert c.execute_pql("i", INTERSECT) == 3
+    hit = _last_trace(c)
+    assert next(s for s in hit["spans"]
+                if s["name"] == "plan")["tags"]["batch_cache"] == "hit"
+    assert [s["name"] for s in _children(hit, "plan")] == ["plan.resolve"]
+    assert "compile" not in {s["name"] for s in hit["spans"]}
+
+    # a Union has no leaf that bounds it: the pre-pass ends before its loop
+    assert c.execute_pql("i", UNION) == 6
+    pre = next(s for s in _last_trace(c)["spans"]
+               if s["name"] == "anchored.prepass")
+    assert pre["tags"] == {"outcome": "not_eligible", "slices_walked": 0,
+                           "anchors_scanned": 0}
+
+
+@pytest.mark.parametrize("slices", [2, 40])
+def test_no_span_sits_in_a_loop_over_slices(server, slices):
+    _populate(server, slices)
+    c = InternalClient(server.host, timeout=60.0)
+    assert c.execute_pql("i", INTERSECT) == slices
+    assert len(_last_trace(c)["spans"]) <= 24
+    assert c.execute_pql("i", INTERSECT) == slices
+    assert len(_last_trace(c)["spans"]) <= 24
+
+
+# ---------------------------------------------------------------------------
+# /debug/profile
+# ---------------------------------------------------------------------------
+
+
+class _FakeProfiler:
+    """Stands in for ``jax.profiler``: keeps what ``trace`` was given."""
+
+    class ProfileOptions:
+        python_tracer_level = 1
+        host_tracer_level = 2
+
+    class TraceAnnotation:
+        def __init__(self, name, **kw):
+            pass
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    def __init__(self):
+        self.sessions = []
+
+    def trace(self, log_dir, profiler_options=None):
+        fake = self
+
+        class _Session:
+            def __enter__(self):
+                fake.sessions.append(profiler_options)
+
+            def __exit__(self, *exc):
+                with open(f"{log_dir}/fake.xplane.pb", "wb") as f:
+                    f.write(b"x")
+                return False
+
+        return _Session()
+
+
+@pytest.mark.parametrize("query,level", [("", 0), ("&python=1", 1),
+                                         ("&python=0", 0)])
+def test_profile_leaves_the_python_tracer_off_unless_asked(
+        server, monkeypatch, query, level):
+    fake = _FakeProfiler()
+    monkeypatch.setattr(handler_mod, "_jax_profiler", lambda: fake)
+    c = InternalClient(server.host, timeout=30.0)
+    status, data, _ = c._request_meta("GET", f"/debug/profile?seconds=0.05{query}")
+    assert status == 200 and json.loads(data)["bytes"] > 0
+    (opts,) = fake.sessions
+    assert opts.python_tracer_level == level
+    assert opts.host_tracer_level == 2  # the host tracer stays as it is
+    assert trace._annotation is None  # the session's flag is cleared
+
+
+def test_a_profile_taken_while_a_query_runs_holds_the_programs_spans(
+        server, tmp_path):
+    from jax.profiler import ProfileData
+
+    _populate(server, 3)
+    c = InternalClient(server.host, timeout=60.0)
+    assert c.execute_pql("i", INTERSECT) == 3  # compile outside the profile
+    reply: dict = {}
+
+    def profile():
+        status, data, _ = InternalClient(server.host, timeout=120.0)._request_meta(
+            "GET", "/debug/profile?seconds=1")
+        reply.update(status=status, doc=json.loads(data))
+
+    t = threading.Thread(target=profile)
+    t.start()
+    deadline = time.monotonic() + 30
+    while t.is_alive() and time.monotonic() < deadline:
+        assert c.execute_pql("i", UNION) == 6
+        assert c.execute_pql("i", INTERSECT) == 3
+    t.join(timeout=120)
+    assert not t.is_alive()
+    if reply["status"] == 501:
+        pytest.skip("this runtime has no profiler")
+    assert reply["status"] == 200
+    with tarfile.open(reply["doc"]["trace"]) as tf:
+        tf.extractall(tmp_path / "prof", filter="data")
+    (pb,) = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    data = ProfileData.from_file(pb)
+    planes = {p.name: p for p in data.planes}
+    # what benchmarks/xplane.py needs of a profile without the Python tracer
+    stats = dict(planes["Task Environment"].stats)
+    assert stats["profile_stop_time"] > stats["profile_start_time"]
+    names = {ev.name for n, p in planes.items() if n.startswith("/host:")
+             for ln in p.lines for ev in ln.events}
+    assert {"plan", "map.local", "anchored.prepass", "launch"} <= names
+    assert trace._annotation is None
