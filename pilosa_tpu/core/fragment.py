@@ -1506,11 +1506,38 @@ class Fragment:
             fmt, payload, nbytes = self._host_payload_locked(row_id, offs)
             return (fmt, payload, nbytes, len(offs))
 
+    def holds_sparse_tier_rows(self) -> bool:
+        """Whether any row lives in the sparse tier.  While none does,
+        every present row is FMT_DENSE by placement (View.dense_tier_only
+        sums this over a view)."""
+        with self._mu:
+            return bool(self._sparse)
+
+    def row_meta(self, row_id: int) -> tuple:
+        """``(cardinality, fmt)`` of one row — what ``host_payload``
+        reports as its last and first fields, without building the
+        payload or touching a plane (the cardinality is the cached
+        popcount); ``(0, None)`` when the row is absent.  A row with a
+        dense-tier slot is FMT_DENSE by placement; a sparse-tier row
+        answers from its memoized encoding (O(cardinality) once per
+        mutation).  The anchored count's metadata walk decides its
+        route from this alone."""
+        with self._mu:
+            if row_id in self._slot_of:
+                return self._count_of.get(row_id, 0), bp.FMT_DENSE
+            offs = self._sparse.get(row_id)
+            if offs is None:
+                return 0, None
+            fmt = self._host_payload_locked(row_id, offs)[0]
+            return self._count_of.get(row_id, 0), fmt
+
     def row_positions(self, row_id: int):
         """Sorted uint32 in-slice positions of one present row (the
         anchored count's anchor vector), or None.  O(cardinality) for
         sparse-tier rows; dense-tier rows pay one 128 KiB plane-row
-        scan."""
+        scan (unpacked to 1 MiB of bytes), so the anchored count calls
+        this only in its second pass, once row_meta has said the route
+        answers — never before a decline."""
         with self._mu:
             slot = self._slot_of.get(row_id)
             if slot is not None:
@@ -1521,12 +1548,6 @@ class Fragment:
             if offs is None:
                 return None
             return np.asarray(offs, dtype=np.uint32)
-
-    def row_count(self, row_id: int) -> int:
-        """Cached popcount of one row (0 when absent) — the anchored
-        count's anchor-selection key, no plane scan."""
-        with self._mu:
-            return self._count_of.get(row_id, 0)
 
     # ------------------------------------------------------------------
     # writes (reference: fragment.go:379-473)

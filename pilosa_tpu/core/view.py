@@ -25,7 +25,11 @@ import threading
 from collections.abc import Callable
 
 from pilosa_tpu.core import cache as cache_mod
-from pilosa_tpu.core.fragment import Fragment, FragmentRetiredError
+from pilosa_tpu.core.fragment import (
+    Fragment,
+    FragmentRetiredError,
+    write_epoch,
+)
 from pilosa_tpu.obs.stats import NopStatsClient
 from pilosa_tpu.ops.bitplane import SLICE_WIDTH
 
@@ -75,6 +79,9 @@ class View:
         # paths pay one falsy check.
         self._cold: dict[int, object] = {}
         self.hydrator = None  # TierManager, attached with cold entries
+        # dense_tier_only()'s last answer and the write epoch it was
+        # read at; any fragment content change anywhere retires it.
+        self._dense_tier_memo: tuple[int, bool] | None = None
 
     # --- lifecycle (reference: view.go:97-154) ---
 
@@ -128,6 +135,50 @@ class View:
         # Cold: hydrate OUTSIDE the view lock (store I/O must not hold
         # a core data lock); the hydrator serializes per fragment.
         return self.hydrator.hydrate(self, slice_i)
+
+    def fragments_at(self, slices: list[int]) -> list[Fragment | None]:
+        """``[self.fragment(s) for s in slices]`` under ONE acquisition
+        of the view lock.  A walk over every slice of an index that
+        took the lock per slice made eight concurrent queries queue on
+        it 954 times each; once one of them was descheduled inside it
+        the queue never cleared (a lock convoy: PERF.md, PR 27)."""
+        hydrator = self.hydrator
+        with self._mu:
+            out = [self._fragments.get(s) for s in slices]
+            if hydrator is None:
+                return out
+            cold = []
+            for i, s in enumerate(slices):
+                if out[i] is not None:
+                    hydrator.touch(self, s)
+                elif s in self._cold:
+                    cold.append(i)
+        for i in cold:  # store I/O outside the view lock, as fragment()
+            out[i] = hydrator.hydrate(self, slices[i])
+        return out
+
+    def dense_tier_only(self) -> bool:
+        """True when no fragment of this view holds a sparse-tier row,
+        so every present row is a dense plane (FMT_DENSE) by placement
+        — the default budget's whole corpus.  Memoized against the
+        process-wide write epoch: a read-mostly load pays one compare,
+        and after a write anywhere the next caller asks each hot
+        fragment again (no plane is touched).  A cold fragment's tiers
+        are unknown until it hydrates, so a view with one answers
+        False.  Advisory: callers pick a route from it, never an
+        answer."""
+        epoch = write_epoch()
+        memo = self._dense_tier_memo
+        if memo is not None and memo[0] == epoch:
+            return memo[1]
+        with self._mu:
+            frags = list(self._fragments.values())
+            unknown = bool(self._cold)
+        dense = not unknown and not any(
+            f.holds_sparse_tier_rows() for f in frags
+        )
+        self._dense_tier_memo = (epoch, dense)
+        return dense
 
     def fragments(self) -> list[Fragment]:
         """The HOT (locally materialized) fragments only — cold

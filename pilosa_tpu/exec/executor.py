@@ -69,6 +69,8 @@ from pilosa_tpu.pql.parser import Call, Query
 _EMPTY_SPARSE_PAYLOAD = np.full(
     bp.PAYLOAD_BUCKET_FLOOR, bp.FMT_SENTINEL, dtype=np.uint32
 )
+# Fragment.row_meta's answer for a leaf whose slice has no fragment.
+_ABSENT_ROW_META = (0, None)
 
 # reference: executor.go:33-40
 DEFAULT_FRAME = "general"
@@ -578,27 +580,23 @@ class Executor:
         fragments collect, so the all-warm steady state costs one dict
         lookup + two attribute compares per existing fragment."""
         frags: list = []
-        seen: set[int] = set()
+        walked: set = set()
 
         def add_view(frame_name: str, view_name: str) -> None:
+            if (frame_name, view_name) in walked:
+                return  # the leaves of one query mostly share a view
+            walked.add((frame_name, view_name))
             v = self.holder.view(index, frame_name, view_name)
             if v is None:
                 return
-            have = v.fragment_slices()
-            for s in slices:
-                if s not in have:
-                    continue
-                frag = v.fragment(s)
-                if frag is None or id(frag) in seen:
-                    continue
+            for frag in v.fragments_at(slices):
                 # Advisory cold check (no lock): a racing writer only
                 # flips a mirror cold; the worker re-checks under the
                 # fragment lock.
-                if (
+                if frag is not None and (
                     frag._device is None
                     or frag._device_version != frag._version
                 ):
-                    seen.add(id(frag))
                     frags.append(frag)
 
         try:
@@ -713,6 +711,13 @@ class Executor:
     def _resolve_bitmap_leaf(self, index: str, c: Call, slice_i: int):
         """Frame/row/orientation resolution for a Bitmap() leaf
         (reference: executor.go:438-484 executeBitmapSlice)."""
+        view, id_ = self._resolve_bitmap_view(index, c)
+        return (view.fragment(slice_i) if view is not None else None), id_
+
+    def _resolve_bitmap_view(self, index: str, c: Call):
+        """The slice-independent half of a Bitmap() leaf: ``(view or
+        None, row/column id)``.  A walk over many slices resolves this
+        once and asks the view for each slice's fragment."""
         idx = self.holder.index(index)
         if idx is None:
             raise IndexNotFoundError()
@@ -740,8 +745,7 @@ class Executor:
                 raise ExecutorError(
                     "Bitmap() cannot retrieve columns unless inverse storage enabled"
                 )
-        frag = self.holder.fragment(index, frame, view, slice_i)
-        return frag, id_
+        return f.view(view), id_
 
     def _resolve_range(self, idx, f, c: Call):
         """Shared Range() argument resolution for the device and host
@@ -1678,11 +1682,25 @@ class Executor:
         failure here also declines, so the guarded path retains its
         retry/host-fallback semantics).
 
+        Two passes.  The METADATA pass reads only what the views and
+        fragments already hold and settles the route.  Where no leaf's
+        view holds a sparse-tier row (View.dense_tier_only: one compare
+        on a read-mostly load) it declines at once.  Otherwise it walks
+        the slices over each leaf's cached cardinality and container
+        format (Fragment.row_meta) for the per-slice anchor, and stops
+        at the first anchor too dense or finds no compressed leaf.  It
+        touches no plane, so a decline scans, expands and copies
+        nothing.  Only when the route answers does the second pass
+        read the anchors' positions and the leaves' payloads and
+        launch.
+
         ``sp`` is the caller's ``anchored.prepass`` span: it leaves with
-        an ``outcome`` (``not_eligible`` before the slice loop, else
-        ``declined_too_dense`` / ``declined_dense`` / ``answered`` /
-        ``error``) and how far the loop got — slices walked, anchor
-        rows scanned for their positions."""
+        an ``outcome`` (``not_eligible`` before any view is looked at,
+        else ``declined_too_dense`` / ``declined_dense`` / ``answered``
+        / ``error``), ``slices_walked`` (how far the metadata pass's
+        walk got: 0 where the views alone decided) and
+        ``anchors_scanned`` (Fragment.row_positions calls: 0 on every
+        decline)."""
         sp.annotate(outcome="not_eligible", slices_walked=0, anchors_scanned=0)
         if bp.PLANE_FORMAT == "dense":
             return None
@@ -1694,44 +1712,79 @@ class Executor:
             return None
         if not self._expr_fold_only(expr):
             return None
-        cands = self._anchor_candidates(expr)
+        cands = sorted(self._anchor_candidates(expr))
         if not cands:
             return None
         outcome = "error"
         walked = scanned = 0
         try:
-            # Per-slice leaf resolution + anchor pick, grouped by the
-            # per-leaf container-format signature (formats may differ
-            # per slice; each signature is its own compiled wrapper).
-            groups: dict[tuple, list] = {}
+            # Metadata pass.  Frame / view / row id resolve once per
+            # leaf; a view shared by several leaves is looked at once.
+            views: list = []
+            leaf_keys: list[tuple[int, int]] = []  # (index into views, row id)
+            for leaf in leaves:
+                view, rid = self._resolve_bitmap_view(index, leaf)
+                if view not in views:
+                    views.append(view)
+                leaf_keys.append((views.index(view), rid))
+            if all(v is None or v.dense_tier_only() for v in views):
+                # No fragment of any leaf's view holds a sparse-tier
+                # row, so every leaf of every slice is a full dense
+                # plane: the position-domain gathers save no bytes, and
+                # the batched word-domain path keeps its cache/coalesce
+                # behavior.  Dense-tier corpora (the default budget)
+                # always leave here, having walked no slice.
+                outcome = "declined_dense"
+                return None
+            # Each view's fragments in one lookup, then per slice one
+            # row_meta per leaf.
+            frags_of = [
+                v.fragments_at(slices) if v is not None else [None] * len(slices)
+                for v in views
+            ]
+            picked: list[tuple[int, int]] = []  # (position in slices, anchor)
             any_compressed = False
-            for walked, s in enumerate(slices, 1):
-                resolved = [
-                    self._resolve_bitmap_leaf(index, leaf, s)
-                    for leaf in leaves
+            for at in range(len(slices)):
+                walked = at + 1
+                metas = [
+                    frags_of[vi][at].row_meta(rid)
+                    if frags_of[vi][at] is not None
+                    else _ABSENT_ROW_META
+                    for vi, rid in leaf_keys
                 ]
-                best = None
-                for i in sorted(cands):
-                    frag, rid = resolved[i]
-                    card = frag.row_count(rid) if frag is not None else 0
-                    if best is None or card < best[0]:
-                        best = (card, i)
-                card, ai = best
+                # smallest candidate, the first of equals
+                card, ai = min((metas[i][0], i) for i in cands)
                 if card == 0:
                     continue  # empty anchor bounds the slice count at 0
                 if card > self.ANCHORED_MAX_POSITIONS:
                     # too dense: whole query keeps one path
                     outcome = "declined_too_dense"
                     return None
-                afrag, arid = resolved[ai]
+                picked.append((at, ai))
+                any_compressed = any_compressed or any(
+                    fmt not in (None, bp.FMT_DENSE) for _, fmt in metas
+                )
+            if not any_compressed:
+                # Sparse-tier rows exist, but none of them compressed
+                # under a non-empty anchor: dense planes all the same.
+                outcome = "declined_dense"
+                return None
+            # The route answers: anchor positions + leaf payloads,
+            # grouped by the per-leaf container-format signature
+            # (formats may differ per slice; each signature is its own
+            # compiled wrapper).
+            groups: dict[tuple, list] = {}
+            for at, ai in picked:
                 scanned += 1
-                anchor = afrag.row_positions(arid)
+                vi, rid = leaf_keys[ai]
+                anchor = frags_of[vi][at].row_positions(rid)
                 if anchor is None or len(anchor) == 0:
                     continue
                 fmts: list[int] = []
                 payloads: list = []
                 eff = 4 * len(anchor)
-                for frag, rid in resolved:
+                for vi, rid in leaf_keys:
+                    frag = frags_of[vi][at]
                     hp = (
                         frag.host_payload(rid) if frag is not None else None
                     )
@@ -1746,18 +1799,9 @@ class Executor:
                         fmts.append(fmt)
                         payloads.append(payload)
                         eff += nbytes
-                        if fmt != bp.FMT_DENSE:
-                            any_compressed = True
                 groups.setdefault(tuple(fmts), []).append(
                     (anchor, payloads, eff)
                 )
-            if not any_compressed:
-                # Every leaf is a full dense plane: the position-domain
-                # gathers save no bytes, and the batched word-domain
-                # path keeps its cache/coalesce behavior.  (Dense-tier
-                # corpora — the default budget — always land here.)
-                outcome = "declined_dense"
-                return None
             total = 0
             for fmts, items in groups.items():
                 total += self._anchored_launch(expr, fmts, items)

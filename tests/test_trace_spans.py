@@ -226,9 +226,12 @@ def test_the_stages_of_a_served_count(server):
     miss = _last_trace(c)
     pre = [s for s in miss["spans"] if s["name"] == "anchored.prepass"]
     assert len(pre) == 1
-    # the test corpus is dense-tier: walked to the end, then declined
-    assert pre[0]["tags"] == {"outcome": "declined_dense", "slices_walked": 3,
-                              "anchors_scanned": 3}
+    # the test corpus is dense-tier: the view holds no sparse-tier row, so
+    # the metadata pass declined before walking a slice, and no anchor's
+    # positions were read (anchors_scanned counts row_positions calls:
+    # 0 on every decline; both read 3 while the scan came before the test)
+    assert pre[0]["tags"] == {"outcome": "declined_dense", "slices_walked": 0,
+                              "anchors_scanned": 0}
     assert pre[0] in _children(miss, "map.local")
     plan_span = next(s for s in miss["spans"] if s["name"] == "plan")
     assert plan_span["tags"]["batch_cache"] == "miss"
