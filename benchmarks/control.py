@@ -6,13 +6,13 @@ server's place with one stated guarantee given up.
 
 No server runs.  The cell's data is made from the seed at the cell's own
 size, the mix's texts are drawn as a window draws them, and each is
-answered by the reference with the guarantee "bit-exact over every
-column of every slice" broken in one of the ways of
-``reference.CONTROLS`` (the last slice not counted; the even slices
-counted twice as an estimate).  Those answers go through the comparison
-a run makes (``run.compare_answers`` and ``run.is_correct``) as a
-window's answers do, and have to come out not correct.  One line of
-JSON per control.
+answered by the reference of the configuration's kind with one stated
+guarantee broken in one of the ways the kind names in ``CONTROLS`` (for
+``two-row-count``, "bit-exact over every column of every slice": the
+last slice not counted; the even slices counted twice as an estimate).
+Those answers go through the comparison a run makes
+(``run.compare_answers`` and ``run.is_correct``) as a window's answers
+do, and have to come out not correct.  One line of JSON per control.
 """
 
 from __future__ import annotations
@@ -25,11 +25,10 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
-from reference import CONTROLS, Reference  # noqa: E402
-from traffic import Record, Traffic  # noqa: E402
+from traffic import Mix, Record  # noqa: E402
 
 
-def answers(ref: Reference, traffic: Traffic, n: int, broken: str | None) -> list[Record]:
+def answers(ref, traffic: Mix, n: int, broken: str | None) -> list[Record]:
     """The window's first ``n`` requests, answered by the reference with
     ``broken`` given up, as the records a window keeps."""
     out = []
@@ -37,19 +36,21 @@ def answers(ref: Reference, traffic: Traffic, n: int, broken: str | None) -> lis
         rec = Record()
         rec.client = i % traffic.clients
         rec.req = traffic.read(i // traffic.clients if traffic.fixed else i, rec.client)
-        rec.status, rec.answer = 200, ref.count(*rec.req.key, broken=broken)
+        rec.status, rec.answer = 200, ref.answer(rec.req.key, broken=broken)
         rec.sent = rec.done = rec.latency_s = rec.late_s = 0.0
         rec.trace_id = ""
         out.append(rec)
     return out
 
 
-def judge(ref: Reference, traffic: Traffic, n: int, broken: str | None) -> dict:
+def judge(ref, traffic: Mix, n: int, broken: str | None) -> dict:
     """The run's own comparison of those answers: the numbers beside
-    their limits, and ``correct``."""
+    their limits, and ``correct``.  The answers come from a reference, in
+    the form it answers in, so nothing is left to normalise."""
     from run import compare_answers, is_correct
 
-    _records, compared = compare_answers(answers(ref, traffic, n, broken), ref)
+    _records, compared = compare_answers(answers(ref, traffic, n, broken), ref,
+                                         lambda answer: answer)
     return {"correct": is_correct(compared), "compared": compared}
 
 
@@ -62,14 +63,12 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--answers", type=int, default=100)
     args = ap.parse_args(argv)
     cell = Cell(read_json(os.path.join(ROOT, "BENCHMARK.json")), args.workload)
-    cfg = cell.config
-    ref = Reference(args.seed, cfg["slices"], cfg["rows"], cfg["slice_width"],
-                    cfg["density"])
-    for s in range(cfg["slices"]):
-        ref.make_slice(s)
+    ref = cell.kind.Reference(cell.config, args.seed)
+    for unit in ref.units():
+        ref.make(unit)
     ref.seal()
-    traffic = Traffic(cell.mix, cfg, args.seed)
-    for broken in (None, *CONTROLS):
+    traffic = cell.kind.Traffic(cell.mix, cell.config, args.seed)
+    for broken in (None, *cell.kind.CONTROLS):
         print(json.dumps({
             "workload": args.workload, "seed": args.seed, "control": broken,
             **judge(ref, traffic, args.answers, broken),

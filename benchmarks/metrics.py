@@ -16,7 +16,8 @@ import os
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-# Launch sites of obs/perf.py on which a Count can ride.
+# Launch sites of obs/perf.py on which a Count can ride: the default of
+# ``reducers/perf_ratio.py`` and the ``SITES`` of the kind ``two-row-count``.
 COUNT_SITES = ("direct", "coalesce", "interp", "total", "collective")
 
 

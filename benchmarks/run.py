@@ -5,13 +5,16 @@
 
 The cell comes from ``BENCHMARK.json``; its configuration, its traffic
 mix and each per-layer metric are files found by name under
-``benchmarks/`` (``README.md`` there says how to add one).  This process
-never initialises a JAX backend.  It starts one child
-``python -m pilosa_tpu.cli server`` on the default configuration, loads
-the configuration's data from ``--seed`` through ``POST /import``, warms
-the mix's own query shapes, drives ``POST /index/<i>/query`` for
-``--seconds``, stops the server, compares every answer the window got
-with the numpy reference, and prints one JSON line.  With no TPU, or
+``benchmarks/`` (``README.md`` there says how to add one), and so is the
+configuration's deployment kind (``deployments/<kind>.py``), which owns
+the schema, the data, the requests, the plain reference and the control.
+This process never initialises a JAX backend.  It starts one child
+``python -m pilosa_tpu.cli server`` on the default configuration, creates
+the kind's schema, loads its data from ``--seed`` through ``POST /import``
+or ``/import-value``, warms the mix's own query shapes, drives
+``POST /index/<i>/query`` for ``--seconds``, stops the server, compares
+every answer the window got with the kind's numpy reference, and prints
+one JSON line.  With no TPU, or
 another number of chips than the cell asks for, it exits 2 and prints no
 result.
 """
@@ -20,8 +23,10 @@ from __future__ import annotations
 
 import argparse
 import glob
+import importlib.util
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -42,9 +47,8 @@ sys.path.insert(1, ROOT)  # pilosa_tpu.net.client, for the load alone
 
 import metrics as metrics_mod  # noqa: E402
 import xplane  # noqa: E402
-from reference import Reference  # noqa: E402
 from server import DEADLINE_MS, LOG_MUST_NOT_HAVE, HarnessError, Server  # noqa: E402
-from traffic import Load, Record, Traffic  # noqa: E402
+from traffic import Load, Record  # noqa: E402
 
 LOAD_THREADS = 4
 # A traced run keeps every trace of its window: the server's ring holds
@@ -54,6 +58,10 @@ TRACE_RING = 200_000
 # 60 s), from a tenth of the way in.
 PROFILE_SHARE = 0.25
 PROGRAMS = "pilosa_exec_programCache_entries"
+# The kind of a configuration whose file names none, and what a kind's
+# module has to hold (``README.md``, "A deployment kind").
+DEFAULT_KIND = "two-row-count"
+KIND_PARTS = ("schema", "Reference", "Traffic", "normalise", "CONTROLS", "SITES")
 
 
 def say(msg: str) -> None:
@@ -76,6 +84,23 @@ class Rig:
     extra_env: dict = field(default_factory=dict)
     root: str = ROOT
     mix_dir: str = os.path.join(HERE, "traffic")
+    kind_dir: str = os.path.join(HERE, "deployments")
+
+
+def load_kind(name: str, kind_dir: str = Rig.kind_dir):
+    """The module ``<kind_dir>/<name>.py``, found by name as
+    ``metrics.load_reducer`` finds a reducer."""
+    path = os.path.join(kind_dir, name + ".py")
+    if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}", name) or not os.path.exists(path):
+        raise HarnessError(f"no deployment kind {name!r}: no file {path}")
+    spec = importlib.util.spec_from_file_location(
+        "deployment_" + re.sub(r"\W", "_", name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [part for part in KIND_PARTS if not hasattr(mod, part)]
+    if missing:
+        raise HarnessError(f"deployment kind {name!r} lacks {', '.join(missing)}")
+    return mod
 
 
 class Cell:
@@ -92,6 +117,7 @@ class Cell:
         self.chips = int(self.spec["chips"])
         cfg = next(c for c in bench["configs"] if c["name"] == self.spec["config"])
         self.config = read_json(os.path.join(rig.root, cfg["file"]))
+        self.kind = load_kind(self.config.get("kind", DEFAULT_KIND), rig.kind_dir)
         mix = os.path.join(rig.mix_dir, self.spec["traffic"] + ".json")
         if not os.path.exists(mix):
             raise HarnessError(f"no traffic mix {mix}")
@@ -113,11 +139,9 @@ class Run:
                  rig: Rig):
         self.cell, self.seed, self.seconds, self.traced = cell, seed, seconds, traced
         self.platform = rig.platform
-        cfg = cell.config
-        self.index, self.frame = cfg["index"], cfg["frame"]
-        self.ref = Reference(seed, cfg["slices"], cfg["rows"], cfg["slice_width"],
-                             cfg["density"])
-        self.traffic = Traffic(cell.mix, cfg, seed)
+        self.kind = cell.kind
+        self.ref = cell.kind.Reference(cell.config, seed)
+        self.traffic = cell.kind.Traffic(cell.mix, cell.config, seed)
         self.work = tempfile.mkdtemp(prefix="pilosa-bench-")
         env = dict(rig.extra_env)
         if traced:
@@ -150,20 +174,33 @@ class Run:
         t0 = time.monotonic()
         host = f"127.0.0.1:{self.server.port}"
         client = InternalClient(host, timeout=120.0)
-        client.create_index(self.index)
-        client.create_frame(self.index, self.frame)
+        for index in self.kind.schema(self.cell.config):
+            client.create_index(index["name"], index.get("options"))
+            for frame in index["frames"]:
+                client.create_frame(index["name"], frame["name"], frame.get("options"))
+                for fld in frame.get("fields", ()):
+                    client.create_field(index["name"], frame["name"], fld["name"],
+                                        fld["min"], fld["max"])
 
-        def one(s: int) -> None:
-            rows, cols = self.ref.make_slice(s)
-            InternalClient(host, timeout=120.0).import_bits(
-                self.index, self.frame, s, (rows, cols)
-            )
+        def one(unit) -> None:
+            # Generation runs here, inside the load's threads.
+            u = self.ref.make(unit)
+            to = InternalClient(host, timeout=120.0)
+            if u["route"] == "import":
+                to.import_bits(u["index"], u["frame"], u["slice"],
+                               (u["rows"], u["cols"]))
+            elif u["route"] == "import-value":
+                to.import_value(u["index"], u["frame"], u["field"], u["slice"],
+                                u["columns"], u["values"])
+            else:
+                raise HarnessError(f"no load route {u['route']!r}")
 
         with ThreadPoolExecutor(LOAD_THREADS) as pool:
-            list(pool.map(one, range(self.cell.config["slices"])))
+            list(pool.map(one, self.ref.units()))
         self.ref.seal()
         self.setup["load_s"] = time.monotonic() - t0
-        say(f"loaded {self.ref.n_bits} bits in {self.setup['load_s']:.1f} s")
+        say(f"loaded {self.ref.n_loaded} bits and values in "
+            f"{self.setup['load_s']:.1f} s")
 
     def warm(self) -> None:
         t0 = time.monotonic()
@@ -178,7 +215,7 @@ class Run:
         if pw and pw.get("error"):
             raise HarnessError(f"prewarm failed: {pw['error']}")
         self.setup["prewarm_wait_s"] = time.monotonic() - t0
-        load = Load(self.server, self.index, traced=False)
+        load = Load(self.server, self.traffic.index, traced=False)
 
         for reqs in self.traffic.warmup_rounds():
             for rec in load.round(reqs):
@@ -212,7 +249,7 @@ class Run:
     def window(self) -> dict:
         before = self.counters()
         self.setup["setup_s"] = time.monotonic() - T_START
-        load = Load(self.server, self.index, self.traced)
+        load = Load(self.server, self.traffic.index, self.traced)
         prof = threading.Thread(target=self._profile) if self.traced else None
         if prof:
             prof.start()
@@ -282,13 +319,14 @@ class Run:
         """Every answer the window got against the reference
         (:func:`compare_answers`), and the server's own word on whether
         the device produced them.  Each number sits beside its limit."""
-        ev["records"], compared = compare_answers(ev.pop("raw_records"), self.ref)
+        ev["records"], compared = compare_answers(ev.pop("raw_records"), self.ref,
+                                                  self.kind.normalise)
 
         def site(when, name, key="launches"):
             return ev["perf"][when].get(name, {}).get(key, 0)
 
         hosteval = site("after", "hosteval") - site("before", "hosteval")
-        launches = sum(site("after", s) - site("before", s) for s in metrics_mod.COUNT_SITES)
+        launches = sum(site("after", s) - site("before", s) for s in self.kind.SITES)
         h = ev["health"]["after"]
         paths = h.get("paths", {}).values()
         faults = (
@@ -310,19 +348,17 @@ class Run:
         }
 
     def readback(self, ev: dict, compared: dict) -> None:
-        """Every acknowledged write, read back before the server stops."""
-        rows = sorted({r.req.key[0] for r in ev["raw_records"]
-                       if r.req.kind == "write" and r.status == 200})
-        wrong = 0
+        """Every acknowledged write, applied to the reference and read
+        back through the kind's own texts before the server stops."""
         for r in ev["raw_records"]:
             if r.req.kind == "write" and r.status == 200:
-                self.ref.set_bit(*r.req.key)
-        for row in rows:
-            text = f"Count(Bitmap(frame={self.frame}, rowID={row}))"
+                self.ref.apply(r.req.key)
+        wrong = 0
+        for req in self.ref.readback():
             status, data = self.server.request(
-                "POST", f"/index/{self.index}/query", text.encode())
+                "POST", f"/index/{self.traffic.index}/query", req.text.encode())
             got = json.loads(data).get("results", [None])[0] if status == 200 else None
-            wrong += got != self.ref.count("Bitmap", row)
+            wrong += got is None or self.kind.normalise(got) != self.ref.answer(req.key)
         compared["writes_not_read_back"] = {"value": wrong, "limit": 0}
 
     def log_tail(self) -> str:
@@ -343,14 +379,16 @@ def _count(failures) -> int:
     return int(bool(failures))
 
 
-def compare_answers(raw: list[Record], ref) -> tuple[list[dict], dict]:
+def compare_answers(raw: list[Record], ref, normalise) -> tuple[list[dict], dict]:
     """The comparison that decides whether the answers are right: every
-    read the window got, bit-exact against ``ref.count``.  Returns the
-    records as the reducers read them and the numbers compared, each
-    beside its limit.  The control (``control.py``) puts a broken
-    reference's answers through this same function."""
+    read the window got, put by the kind's ``normalise`` into the form
+    its reference answers in and held with ``==`` to ``ref.answer``.  A
+    kind has no tolerance to set, and the limits are the same for all.
+    Returns the records as the reducers read them and the numbers
+    compared, each beside its limit.  The control (``control.py``) puts
+    a broken reference's answers through this same function."""
     records, wrong, unanswered = [], 0, 0
-    cache: dict[tuple, int] = {}
+    cache: dict[tuple, object] = {}
     for r in raw:
         ok = r.status == 200 and r.answer is not None
         correct = False
@@ -358,8 +396,8 @@ def compare_answers(raw: list[Record], ref) -> tuple[list[dict], dict]:
             unanswered += 1
         elif r.req.kind == "read":
             if r.req.key not in cache:
-                cache[r.req.key] = ref.count(*r.req.key)
-            correct = r.answer == cache[r.req.key]
+                cache[r.req.key] = ref.answer(r.req.key)
+            correct = normalise(r.answer) == cache[r.req.key]
             wrong += not correct
         else:
             correct = True  # an acknowledged write; readback() judges it

@@ -3,12 +3,14 @@ must see ``correct`` come out false.
 
     python benchmarks/tests/broken_server.py <fault> server --data-dir ... --bind ...
 
-``answer_altered``     every third Count the executor produces is one too high
+``answer_altered``     every third answer the executor produces to a Count,
+                       a TopN or a Sum is one too high (a TopN's first pair)
 ``half_left_out``      a Count over all slices is answered from every second slice
 ``exchange_left_out``  on a mesh, the total is one chip's partial: the
                        all-reduce between the chips is skipped
 """
 
+import dataclasses
 import os
 import sys
 
@@ -24,12 +26,22 @@ if fault == "answer_altered":
     orig_execute = executor_mod.Executor.execute
     calls = [0]
 
+    def one_too_high(answer):
+        if type(answer) is int:
+            return answer + 1
+        if isinstance(answer, list) and answer:  # TopN's pairs
+            first = answer[0]
+            return [dataclasses.replace(first, count=first.count + 1)] + answer[1:]
+        if hasattr(answer, "value"):  # a Sum's ValCount
+            return dataclasses.replace(answer, value=answer.value + 1)
+        return answer
+
     def execute(self, index, q, slices=None, opt=None):
         out = orig_execute(self, index, q, slices, opt)
-        if out and type(out[0]) is int and "Count" in str(q):
+        if out and any(call in str(q) for call in ("Count", "TopN", "Sum")):
             calls[0] += 1
             if calls[0] % 3 == 0:
-                out = [out[0] + 1] + list(out[1:])
+                out = [one_too_high(out[0])] + list(out[1:])
         return out
 
     executor_mod.Executor.execute = execute

@@ -4,6 +4,7 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
+FIXTURES = os.path.join(HERE, "fixtures")
 for p in (ROOT, BENCH):
     if p not in sys.path:
         sys.path.insert(0, p)

@@ -1,13 +1,16 @@
 """``BENCHMARK.json`` against the limits a driver refuses it over before
 any run: key sets, names, units, lengths, files under ``paths``."""
 
+import ast
+import glob
 import json
 import os
 import re
 
 import pytest
 
-from conftest import BENCH, ROOT
+import run
+from conftest import BENCH, FIXTURES, ROOT
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -105,3 +108,56 @@ def test_files_under_paths_are_named_from_a_names_characters():
         for f in files:
             rel = os.path.relpath(os.path.join(d, f), ROOT)
             assert ok.match(rel) and len(rel) <= 200, rel
+
+
+# -- deployment kinds ---------------------------------------------------------
+
+KIND_DIRS = {"shipped": os.path.join(BENCH, "deployments"),
+             "fixture": os.path.join(FIXTURES, "deployments")}
+KINDS = sorted((where, os.path.basename(path)[:-3])
+               for where, d in KIND_DIRS.items()
+               for path in glob.glob(os.path.join(d, "*.py")))
+
+
+def test_a_configurations_kind_names_a_file_under_deployments(bench):
+    assert (("shipped", run.DEFAULT_KIND) in KINDS
+            and any(where == "fixture" for where, _ in KINDS))
+    for c in bench["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            kind = json.load(f).get("kind", run.DEFAULT_KIND)
+        assert NAME.match(kind)
+        assert os.path.isfile(os.path.join(KIND_DIRS["shipped"], kind + ".py")), kind
+
+
+@pytest.mark.parametrize("where,name", KINDS)
+def test_a_kind_exposes_the_seven_parts(where, name):
+    kind = run.load_kind(name, KIND_DIRS[where])  # schema .. SITES: load_kind checks
+    assert callable(kind.schema) and callable(kind.normalise)
+    # data and load, the reference, the control
+    for method in ("units", "make", "seal", "answer", "apply", "readback"):
+        assert callable(getattr(kind.Reference, method)), method
+    # the requests: what Load drives
+    for method in ("warmup_rounds", "read", "schedule"):
+        assert callable(getattr(kind.Traffic, method)), method
+    assert kind.CONTROLS and all(NAME.match(c) for c in kind.CONTROLS)
+    assert kind.SITES and all(isinstance(s, str) for s in kind.SITES)
+    assert "hosteval" not in kind.SITES
+
+
+@pytest.mark.parametrize("where,name", KINDS)
+def test_a_kind_imports_nothing_of_the_program(where, name):
+    with open(os.path.join(KIND_DIRS[where], name + ".py")) as f:
+        tree = ast.parse(f.read())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported |= {a.name.split(".")[0] for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            imported.add((node.module or "").split(".")[0])
+        elif isinstance(node, ast.Call) and getattr(node.func, "id", "") == "__import__":
+            imported.add("__import__")
+    # Not the program, and no way round the look: no import by a computed
+    # name, no child process, no word with the server.
+    assert not imported & {"pilosa_tpu", "__import__", "importlib", "runpy",
+                           "subprocess", "socket", "http", "urllib"}, imported
+    assert "numpy" in imported

@@ -9,17 +9,19 @@ import numpy as np
 import pytest
 
 import control
+import run
 from conftest import BENCH, HERE
-from reference import CONTROLS, OPS, Reference
-from traffic import Traffic
+
+KIND = run.load_kind(run.DEFAULT_KIND)
+CONTROLS, OPS, Traffic = KIND.CONTROLS, KIND.OPS, KIND.Traffic
 
 
 def tiny(seed=7):
     with open(os.path.join(HERE, "fixtures", "tiny.json")) as f:
         cfg = json.load(f)
-    ref = Reference(seed, cfg["slices"], cfg["rows"], cfg["slice_width"], cfg["density"])
-    for s in range(cfg["slices"]):
-        ref.make_slice(s)
+    ref = KIND.Reference(cfg, seed)
+    for unit in ref.units():
+        ref.make(unit)
     ref.seal()
     return cfg, ref
 
@@ -54,7 +56,7 @@ def test_the_reference_agrees_with_python_sets():
             "Difference": len(x - y), "Xor": len(x ^ y)}
     assert {op: ref.count(op, a, b) for op in OPS} == want
     assert ref.count("Bitmap", a) == len(x)
-    assert ref.n_bits == sum(r.size for r in ref._rows)
+    assert ref.n_loaded == sum(r.size for r in ref._rows)
     # every row is sorted and unique, which the set algebra assumes
     assert all(np.all(np.diff(r.astype(np.int64)) > 0) for r in ref._rows)
 
@@ -84,6 +86,16 @@ def test_a_set_bit_is_read_back():
     assert ref.set_bit(15, col) is True
     assert ref.set_bit(15, col) is False
     assert ref.count("Bitmap", 15) == before + 1
+    # through the parts a run uses: apply, then the texts that read it back
+    assert ref.readback() == []
+    ref.apply((17, col))
+    ref.apply((15, col))
+    back = ref.readback()
+    assert [(r.kind, r.text, r.key) for r in back] == [
+        ("read", "Count(Bitmap(frame=f, rowID=15))", ("Bitmap", 15)),
+        ("read", "Count(Bitmap(frame=f, rowID=17))", ("Bitmap", 17)),
+    ]
+    assert ref.answer(back[0].key) == before + 1
 
 
 def test_the_distinct_warm_up_covers_every_operator():

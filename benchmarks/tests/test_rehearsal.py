@@ -12,7 +12,7 @@ import sys
 import pytest
 
 import run
-from conftest import BENCH, HERE, ROOT
+from conftest import BENCH, FIXTURES, HERE, ROOT
 
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 DEVICE_METRICS = {"device.count_roofline", "device.idle_share"}
@@ -27,10 +27,11 @@ def tiny_bench() -> dict:
         "tiny.rw-mix": ("tiny", "rw-mix-tiny", 1),
         "tiny-wide.count-distinct": ("tiny-wide", "count-distinct", 4),
         "tiny-wide.count-repeat": ("tiny-wide", "count-repeat", 4),
+        "ranked-bsi-tiny.topn-sum": ("ranked-bsi-tiny", "topn-sum-tiny", 1),
     }
     bench["configs"] = [
         {"name": n, "file": f"benchmarks/tests/fixtures/{n}.json"}
-        for n in ("tiny", "tiny-wide")
+        for n in ("tiny", "tiny-wide", "ranked-bsi-tiny")
     ]
     bench["workloads"] = [
         {"name": k, "config": c, "traffic": t, "chips": n}
@@ -52,10 +53,20 @@ def cpu_env(devices: int) -> dict:
 
 
 def rehearse(cell, traced, devices, seed=2_147_483_700, seconds=1.0,
-             server_argv=None, env=None, mix_dir=run.Rig.mix_dir):
+             server_argv=None, env=None, mix_dir=run.Rig.mix_dir,
+             kind_dir=run.Rig.kind_dir):
     rig = run.Rig(platform="cpu", server_argv=server_argv,
-                  extra_env={**cpu_env(devices), **(env or {})}, mix_dir=mix_dir)
+                  extra_env={**cpu_env(devices), **(env or {})}, mix_dir=mix_dir,
+                  kind_dir=kind_dir)
     return run.run_cell(tiny_bench(), cell, seed, seconds, traced, rig)
+
+
+def rehearse_the_fixture_kind(traced=False, server_argv=None):
+    """The kind that lives under ``fixtures/`` alone: its module, its
+    configuration and its mix are all found there."""
+    return rehearse("ranked-bsi-tiny.topn-sum", traced, 1, server_argv=server_argv,
+                    mix_dir=os.path.join(FIXTURES, "traffic"),
+                    kind_dir=os.path.join(FIXTURES, "deployments"))
 
 
 @pytest.mark.parametrize("cell,traced,devices", [
@@ -90,9 +101,40 @@ def test_a_good_run(cell, traced, devices):
         assert all(m["value"] > 0 for m in line["metrics"].values())
 
 
+@pytest.mark.parametrize("traced", [False, True])
+def test_a_kind_the_harness_has_never_seen_runs_with_no_edit(traced):
+    """A ranked-cache frame and a BSI field through ``/import-value``,
+    TopN and Sum(Range) reads: a list-valued and a dict-valued answer."""
+    rc, line = rehearse_the_fixture_kind(traced)
+    assert rc == 0
+    line = json.loads(json.dumps(line))
+    assert set(line) - {"breakdown", "compared"} == CONTRACT_KEYS
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] >= 4  # each of the four texts at least once
+    c = line["compared"]
+    assert c["answers_compared"]["value"] == line["attempted"]
+    assert c["wrong_answers"] == {"value": 0, "limit": 0}
+    assert c["hosteval_launches"] == {"value": 0, "limit": 0}
+    # the kind's own sites: TopN's scorer and the BSI aggregate both rode one
+    assert c["device_launches"]["value"] >= 1
+    if traced:
+        assert {"client.latency_p99_ms", "setup.load_s"} <= set(line["metrics"])
+    else:
+        assert set(line["metrics"]) == {"answers_per_s", "setup_s"}
+
+
+def test_the_fixture_kinds_altered_answer_is_not_correct():
+    argv = [sys.executable, os.path.join(HERE, "broken_server.py"),
+            "answer_altered", "server"]
+    rc, line = rehearse_the_fixture_kind(server_argv=argv)
+    assert rc == 0
+    assert line["correct"] is False
+    assert line["compared"]["wrong_answers"]["value"] > 0
+
+
 def test_open_loop_with_writes_reads_every_acked_write_back():
     rc, line = rehearse("tiny.rw-mix", False, 1,
-                        mix_dir=os.path.join(HERE, "fixtures", "traffic"))
+                        mix_dir=os.path.join(FIXTURES, "traffic"))
     assert rc == 0 and line["correct"] is True
     assert line["compared"]["writes_not_read_back"] == {"value": 0, "limit": 0}
     assert line["attempted"] == 40  # 40/s for 1 s, whatever the replies did

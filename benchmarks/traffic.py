@@ -1,33 +1,28 @@
 """The one general traffic generator.
 
-A mix is a data file, ``traffic/<mix>.json``; this module reads it and
-drives ``POST /index/<i>/query`` with what it says.  Everything a run
-sends is drawn from ``--seed`` before the window opens: every seed gets
-the same number of each operator (a shuffled deck, not a coin), so a
-seed changes the order of the work and not the work.
+A mix is a data file, ``traffic/<mix>.json``; this module reads what is
+the same for every deployment (the loop, the clients, the warm-up's
+rounds, the open loop's schedule) and drives ``POST /index/<i>/query``.
+What a request says is the deployment kind's (``deployments/<kind>.py``):
+its ``Traffic`` is a :class:`Mix` that fills in the reads, the warm-up's
+texts and, where the mix writes, :meth:`Mix.write_request`.  Everything a
+run sends is drawn from ``--seed`` before the window opens, and a seed
+changes the order of the work and not the work.
 
-Keys of a mix (see ``README.md``):
+Keys of a mix that every kind shares (a kind's docstring lists its own,
+under ``read`` and ``write``):
 
 ``loop``         ``closed`` (``clients`` connections, each waits for its
                  reply) or ``open`` (``rate_per_s`` requests a second on
                  a fixed schedule, at most ``clients`` in flight).
-``read``         ``template`` (PQL with ``{op} {frame} {a} {b}``) and
-                 ``texts``: a list of ``[op, a, b]`` (fixed texts, named
-                 here and cycled; ``sources`` beside them says where each
-                 comes from), or ``"distinct"`` with an ``op_deck``
-                 (operator -> cards in the deck): no text is sent twice
-                 in a run, the row pairs drawn without replacement over
-                 every row that takes no write.
-``write_share``  share of requests that are writes (open loop only);
-                 ``write`` gives their ``template`` (``{frame} {row}
-                 {col}``), ``rows`` (how many rows take writes: the last
-                 rows of the configuration, which no read text names)
-                 and ``column_zipf`` (the skew of the written column).
+``read.texts``   a list (fixed texts, named in the file and cycled) or
+                 ``"distinct"`` (no text is sent twice in a run).
+``write_share``  share of requests that are writes (open loop only).
 ``warmup``       ``rounds`` of ``clients`` concurrent requests before the
                  window.  A fixed mix cycles its texts.  A distinct mix
                  has ``fresh_texts`` texts of its own for them (none is
                  sent in the window) and then ``repeat_rounds`` /
-                 ``repeat_last``: see :meth:`Traffic.warmup_rounds`.
+                 ``repeat_last``: see :meth:`Mix.warmup_rounds`.
 """
 
 from __future__ import annotations
@@ -49,7 +44,7 @@ class Request:
     def __init__(self, kind: str, text: str, key: tuple, due: float | None = None):
         self.kind = kind  # "read" | "write"
         self.text = text
-        self.key = key  # read: (op, a, b); write: (row, col)
+        self.key = key  # what the kind's reference answers or applies
         self.due = due  # open loop: seconds after the window opens
 
 
@@ -62,61 +57,48 @@ class Record:
                  "status", "answer", "trace_id")
 
 
-def _deck(cards: dict[str, int], n: int, rng) -> list[str]:
+def deck(cards: dict[str, int], n: int, rng) -> list[str]:
     """``n`` draws, every whole deck holding each card its stated number
     of times."""
-    deck = [name for name, k in cards.items() for _ in range(int(k))]
+    pile = [name for name, k in cards.items() for _ in range(int(k))]
     out: list[str] = []
     while len(out) < n:
-        out.extend(rng.permutation(deck).tolist())
+        out.extend(rng.permutation(pile).tolist())
     return out[:n]
 
 
-class Traffic:
+def zipf_rank(u: float, n: int, theta: float) -> int:
+    """The rank in ``[0, n)`` that a uniform draw ``u`` falls on under a
+    Zipf law of skew ``theta`` (0: uniform)."""
+    if theta <= 0.0:
+        rank = int(u * n)
+    elif abs(theta - 1.0) < 1e-9:
+        rank = int(n ** u) - 1
+    else:
+        rank = int(((n ** (1 - theta) - 1) * u + 1) ** (1 / (1 - theta))) - 1
+    return min(max(rank, 0), n - 1)
+
+
+class Mix:
+    """What :class:`Load` drives, and what every kind's requests share.
+    A kind's ``Traffic(mix, config, seed)`` calls this initialiser, then
+    fills ``_reads`` (the window's reads in order) and ``_warm`` (the
+    warm-up's texts) with draws from ``rng``, and overrides
+    :meth:`write_request` where its mixes write."""
+
     def __init__(self, mix: dict, config: dict, seed: int):
         self.mix = mix
-        self.frame = config["frame"]
+        self.index = config["index"]
         self.clients = int(mix["clients"])
         self.loop = mix["loop"]
         if self.loop not in ("closed", "open"):
             raise ValueError(f"loop must be closed or open, not {self.loop!r}")
-        rng = np.random.default_rng([int(seed), 7_000_003])
-        write = mix.get("write") or {}
+        self.rng = np.random.default_rng([int(seed), 7_000_003])
         self.write_share = float(mix.get("write_share", 0.0))
-        n_rows = int(config["rows"])
-        self.write_rows = list(range(n_rows - int(write.get("rows", 0)), n_rows))
-        read_rows = n_rows - len(self.write_rows)
-        read = mix["read"]
-        self.fixed = read["texts"] != "distinct"
-        warm = mix.get("warmup", {})
-        self.warm_rounds = int(warm.get("rounds", 1))
-
-        request = self._request
-        if self.fixed:
-            self._reads = [request(*t) for t in read["texts"]]
-            self._warm = self._reads
-        else:
-            pairs = [(a, b) for a in range(read_rows) for b in range(read_rows)
-                     if a != b]
-            pairs = [pairs[i] for i in rng.permutation(len(pairs))]
-            ops = _deck(read["op_deck"], len(pairs), rng)
-            # The warm-up's own texts come off the far end: the window
-            # starts at the near one and never gets there (it raises).
-            # Their operators go round the deck's kinds, so that every
-            # operator's program is compiled before the window.
-            n = int(warm.get("fresh_texts", self.clients))
-            kinds = itertools.cycle(read["op_deck"])
-            self._warm = [request(op, a, b)
-                          for op, (a, b) in zip(kinds, pairs[len(pairs) - n:])]
-            self._reads = [request(op, a, b)
-                           for op, (a, b) in zip(ops, pairs[: len(pairs) - n])]
-        self._rng = rng
-        self._write = write
-        self._n_columns = int(config["slices"]) * int(config["slice_width"])
-
-    def _request(self, op: str, a: int, b: int) -> Request:
-        text = self.mix["read"]["template"].format(op=op, frame=self.frame, a=a, b=b)
-        return Request("read", text, (op, a, b))
+        self.fixed = mix["read"]["texts"] != "distinct"
+        self.warm_rounds = int(mix.get("warmup", {}).get("rounds", 1))
+        self._reads: list[Request] = []
+        self._warm: list[Request] = []
 
     # -- what is sent -------------------------------------------------------
 
@@ -150,21 +132,8 @@ class Traffic:
             )
         return self._reads[i]
 
-    def _write_request(self) -> Request:
-        theta = float(self._write.get("column_zipf", 0.0))
-        u = float(self._rng.random())
-        n = self._n_columns
-        if theta <= 0.0:
-            rank = int(u * n)
-        elif abs(theta - 1.0) < 1e-9:
-            rank = int(n ** u) - 1
-        else:
-            rank = int(((n ** (1 - theta) - 1) * u + 1) ** (1 / (1 - theta))) - 1
-        # Spread the hot ranks over the column space.
-        col = (min(max(rank, 0), n - 1) * 2_654_435_761) % n
-        row = self.write_rows[int(self._rng.integers(len(self.write_rows)))]
-        text = self._write["template"].format(frame=self.frame, row=row, col=col)
-        return Request("write", text, (row, col))
+    def write_request(self) -> Request:
+        raise ValueError(f"this kind sends no writes: write_share = {self.write_share}")
 
     def schedule(self, seconds: float) -> list[Request]:
         """Open loop: every request of the window with its due time."""
@@ -172,11 +141,11 @@ class Traffic:
         n = int(rate * seconds)
         n_writes = int(round(n * self.write_share))
         kinds = ["write"] * n_writes + ["read"] * (n - n_writes)
-        kinds = [kinds[i] for i in self._rng.permutation(n)]
+        kinds = [kinds[i] for i in self.rng.permutation(n)]
         out, reads = [], 0
         for i, kind in enumerate(kinds):
             if kind == "write":
-                req = self._write_request()
+                req = self.write_request()
             else:
                 base = self.read(reads)
                 req = Request("read", base.text, base.key)
@@ -293,7 +262,7 @@ class Load:
         self._join(threads)
         return w0, max([r.done for r in self.records], default=w0)
 
-    def closed(self, traffic: Traffic, seconds: float) -> tuple[float, float]:
+    def closed(self, traffic: Mix, seconds: float) -> tuple[float, float]:
         """``clients`` closed loops for ``seconds``; a request in flight
         at the close is waited for."""
         if traffic.write_share > 0:
@@ -309,7 +278,7 @@ class Load:
 
         return self._clients(traffic.clients, body)
 
-    def open(self, traffic: Traffic, seconds: float) -> tuple[float, float]:
+    def open(self, traffic: Mix, seconds: float) -> tuple[float, float]:
         """Requests on a fixed schedule whatever the replies do; latency
         runs from the time a request was due."""
         plan = traffic.schedule(seconds)
@@ -325,5 +294,5 @@ class Load:
 
         return self._clients(traffic.clients, body, lead_s=0.05)
 
-    def run(self, traffic: Traffic, seconds: float) -> tuple[float, float]:
+    def run(self, traffic: Mix, seconds: float) -> tuple[float, float]:
         return (self.open if traffic.loop == "open" else self.closed)(traffic, seconds)
