@@ -1397,6 +1397,19 @@ class Fragment:
                 pool.touch(self._pool_key)
             return self._device
 
+    def plane_rows(self) -> int:
+        """Rows of the dense plane as allocated (a pow2 class, floor
+        ROW_BLOCK): the row dimension of every program that reads the
+        plane's device mirror."""
+        return int(self._plane.shape[0])
+
+    def mirror_is(self, plane) -> bool:
+        """Whether ``plane`` is this fragment's CURRENT device mirror,
+        the array the residency pool accounts for under the fragment's
+        own key (a snapshot taken before a later write's refresh, or
+        before an eviction, is not).  One attribute read, no lock."""
+        return self._device is plane
+
     def has_row(self, row_id: int) -> bool:
         """Whether either tier holds the row (no device work)."""
         with self._mu:

@@ -2356,12 +2356,14 @@ class Executor:
         ``src_spec`` from ``_attach_dev_src`` (None when the src tree
         is not a plain Bitmap leaf), ``fragment`` for the host scoring
         fallback.  Entries with a SubRef group by program shape
-        (sub shape, plane rows, home device); each group runs ONE fused
-        program (bp.score_planes) that reads candidate AND src rows
-        straight from the fragments' resident HBM mirrors — no stacked
-        copy, no src upload — and is fetched as ONE array, where a
-        per-fragment path would pay a dispatch + a 128 KiB src upload +
-        a fetch PER SLICE.
+        (sub shape, plane rows, home device); each group is scored by
+        ONE compiled program (bp.score_planes) that reads candidate AND
+        src rows straight from the fragments' resident HBM mirrors — no
+        stacked copy, no src upload — launched once per bp.SCORE_GROUP
+        members without waiting, and every launch of every group is
+        fetched in ONE round trip, where a per-fragment path would pay a
+        dispatch + a 128 KiB src upload + a fetch PER SLICE.  The
+        program's operands are bounded whatever the slice count.
 
         Rides the device-health gate: a quarantined device (or a
         finally-failed scorer launch) fills the count vectors from the
@@ -2370,10 +2372,11 @@ class Executor:
         vectors.
 
         The ``topn.dispatch`` / ``topn.fetch`` spans split the device
-        cost: dispatch covers gather prep + the async program launches,
-        fetch the blocking device->host transfer — with ``topn.select``
-        in the callers, the per-stage TopN(src) breakdown ROADMAP 5
-        needs before attacking the 5-7 ms residual."""
+        cost: dispatch covers gather prep + the async program launches
+        (a program shape's first call leaves a ``compile`` span under
+        it), fetch the blocking device->host transfer — with
+        ``topn.select`` in the callers, the per-stage TopN(src)
+        breakdown."""
         live = [e for e in parts if e[1] is not None]
         if not live:
             return
@@ -2390,57 +2393,61 @@ class Executor:
                 groups.setdefault(
                     (ref.shape, ref.plane_rows, ref.device), []
                 ).append(entry)
-            dev_outs = []  # (device array, [states]) fetched in one pass
+            # Scorer roofline accounting: each live member's fused
+            # scoring pass streams its whole plane snapshot (the last
+            # launch's pad repeats are bucketing, not counted).
+            rows = sum(int(e[1].plane_rows) for e in live)
+            n_bytes = sum(
+                perf_mod.plane_bytes(int(e[1].plane_rows), bp.WORDS_PER_SLICE)
+                for e in live
+            )
+            dev_outs = []  # ([device arrays], [states]) fetched in one pass
             t0 = time.monotonic()
-            with self.tracer.span("topn.dispatch", groups=len(groups)):
+            with self.tracer.span(
+                "topn.dispatch", groups=len(groups), rows=rows, bytes=n_bytes
+            ) as sp:
                 self._fault_check_launch("topn")
-                for _gkey, members in groups.items():
-                    # Pad the group to a power-of-two bucket by repeating
-                    # the last member (the row dimension is already
-                    # pad_rows-bucketed): an unpadded group size would
-                    # compile a fresh XLA program per distinct slice count.
-                    # Surplus rows are simply not consumed when the fetched
-                    # scores distribute.
-                    n_pad = 1 << (len(members) - 1).bit_length()
-                    padded = members + [members[-1]] * (n_pad - len(members))
-                    planes = tuple(m[1].plane for m in padded)
-                    slots = np.stack([m[1].slots for m in padded])
+                for members in groups.values():
+                    planes = [m[1].plane for m in members]
+                    slots = np.stack([m[1].slots for m in members])
                     # Same-plane src slot for every member -> zero src bytes
                     # cross the host boundary (and no extra leaf shapes in
                     # the jit key); otherwise one stacked host-snapshot
-                    # transfer for the group.
-                    if all(m[3] is not None for m in padded):
-                        src_slots = np.asarray(
-                            [m[3] for m in padded], dtype=np.int32
-                        )
-                        out = bp.score_planes(
-                            planes, slots, src_slots=src_slots
+                    # transfer per launch.
+                    if all(m[3] is not None for m in members):
+                        outs = bp.score_planes(
+                            planes,
+                            slots,
+                            src_slots=np.asarray(
+                                [m[3] for m in members], dtype=np.int32
+                            ),
+                            first_call=plan.note_scorer_first_call,
                         )
                     else:
-                        srcs = np.stack([m[2] for m in padded])
-                        out = bp.score_planes(planes, slots, srcs=srcs)
-                    dev_outs.append((out, [m[0] for m in members]))
+                        outs = bp.score_planes(
+                            planes,
+                            slots,
+                            srcs=np.stack([m[2] for m in members]),
+                            first_call=plan.note_scorer_first_call,
+                        )
+                    dev_outs.append((outs, [m[0] for m in members]))
+                sp.annotate(launches=sum(len(o) for o, _ in dev_outs))
             t_disp = time.monotonic()
-            with self.tracer.span("topn.fetch", arrays=len(dev_outs)) as sp:
-                fetched = self._shared_fetch([o for o, _ in dev_outs], sp)
-            for arr, (_, sts) in zip(fetched, dev_outs):
-                arr = np.asarray(arr)
+            flat = [o for outs, _ in dev_outs for o in outs]
+            with self.tracer.span("topn.fetch", arrays=len(flat)) as sp:
+                fetched = iter(self._shared_fetch(flat, sp))
+            for outs, sts in dev_outs:
+                arr = np.concatenate(
+                    [np.asarray(next(fetched)) for _ in outs]
+                )
                 for i, st in enumerate(sts):
                     st.counts = arr[i]
-            # Scorer roofline accounting: each live member's fused
-            # scoring pass streams its whole plane snapshot (group pad
-            # repeats are bucketing, not counted).
             if perf_mod.enabled():
                 perf_mod.record_launch(
                     "topn",
                     reduce="topn",
-                    rows=sum(int(e[1].plane_rows) for e in live),
-                    n_bytes=sum(
-                        perf_mod.plane_bytes(
-                            int(e[1].plane_rows), bp.WORDS_PER_SLICE
-                        )
-                        for e in live
-                    ),
+                    rows=rows,
+                    n_bytes=n_bytes,
                     dispatch_ms=(t_disp - t0) * 1e3,
                     total_ms=(time.monotonic() - t0) * 1e3,
                     trace_id=perf_mod.current_trace_id(),
@@ -2579,14 +2586,17 @@ class Executor:
             out.append(tuple(self._leaf_versions(index, leaves, slices)))
         return tuple(out)
 
-    def _topn_folded_entry(self, index: str, c: Call, slices: list[int]) -> dict:
+    def _topn_folded_entry(
+        self, index: str, c: Call, slices: list[int]
+    ) -> tuple[dict, str]:
         """The folded path's prep — candidate walks, union assembly,
         foreign-count resolution, src evaluation, and gather prep —
         CACHED per (index, query, slice set) and validated exactly like
         _cached_batch entries (O(1) against the global write epoch, then
         against the version vector).  At 64 slices the prep is ~50 ms of
         host-side numpy per query; repeated queries skip all of it and
-        pay only dispatch + fetch + winner selection.
+        pay only dispatch + fetch + winner selection.  Returns the
+        entry and how it was come by, ``"hit"`` or ``"built"``.
 
         Attr-filtered queries (filterField) are NOT cached: the attr
         store has no version vector, so a SetRowAttrs would serve stale
@@ -2630,7 +2640,7 @@ class Executor:
                         if key in self._topn_cache:
                             self._topn_cache.move_to_end(key)
                     device_mod.pool().touch(self._topn_pool_key(key))
-                    return ent
+                    return ent, "hit"
                 # Version validation failed: the entry can never serve
                 # again (a deleted or rewritten fragment), yet its
                 # SubRefs pin HBM plane snapshots — drop it NOW, before
@@ -2664,16 +2674,26 @@ class Executor:
             pool = device_mod.pool()
             for k in displaced:
                 pool.remove(self._topn_pool_key(k))
-            # Byte-account the entry's HBM plane snapshots (SubRefs):
-            # the pool, not the entry-count cap, now bounds how much
-            # device memory TopN prep keeps alive.
+            # Byte-account the HBM plane snapshots that this entry ALONE
+            # keeps alive.  A SubRef's plane is the fragment's mirror as
+            # it stood at prepare time; while it still IS the mirror the
+            # pool already holds it under the fragment's own key, and
+            # charging it again would make 8.0 GB of resident planes
+            # read as 16 and evict the very mirrors the scorer reads.
+            # Only a snapshot that a later write has replaced is this
+            # entry's to account for (it dies with the entry, within
+            # RECALCULATE_INTERVAL_S).
             self._register_cache_entry(
                 self._topn_pool_key(key),
-                [p[5].plane for p in ent.get("parts", ()) if p[5] is not None],
+                [
+                    p[5].plane
+                    for p in ent.get("parts", ())
+                    if p[5] is not None and not p[0].mirror_is(p[5].plane)
+                ],
                 {"cache": "topn", "index": index, "query": str(c)},
                 functools.partial(self._evict_topn_key, key),
             )
-        return ent
+        return ent, "built"
 
     def _topn_folded_build(self, index: str, c: Call, slices: list[int]) -> dict:
         """Build a folded-TopN prep entry (see _topn_folded_entry for
@@ -2777,7 +2797,7 @@ class Executor:
         # so a 32-query storm of one TopN shape pays ONE
         # dispatch+fetch, not 32 — the topn.fetch residual ROADMAP 5
         # names.
-        return {"parts": parts}
+        return {"parts": parts, "union": len(union)}
 
     def _execute_topn_folded(
         self, index: str, c: Call, slices: list[int], opt: ExecOptions
@@ -2801,8 +2821,13 @@ class Executor:
         # Intersect(B,A)) each paid their own dispatch+fetch.  AND/OR/
         # XOR commute bit for bit, so results stay byte-identical.
         c = plan.canonicalize_call(c)
-        with self.tracer.span("topn.prep", slices=len(slices)):
-            ent = self._topn_folded_entry(index, c, slices)
+        with self.tracer.span("topn.prep", slices=len(slices)) as sp:
+            ent, how = self._topn_folded_entry(index, c, slices)
+            # ``union``: rows scored in every slice
+            sp.annotate(
+                prep_cache="two_phase" if ent.get("two_phase") else how,
+                union=ent.get("union", 0),
+            )
         if ent.get("empty"):
             return []
         if ent.get("two_phase"):

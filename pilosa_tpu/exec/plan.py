@@ -23,6 +23,7 @@ executor.go:418-434,486-505,621-637).
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from collections import OrderedDict, namedtuple
@@ -715,15 +716,7 @@ class _Program:
         # Unlocked set add + dict accumulate: a racing duplicate first
         # call double-counts a few ms of telemetry, never corrupts.
         self._seen_shapes.add(shapes)
-        _note_compile_ms(self.family, ms)
-        # The same interval as a ``compile`` span in the trace of the
-        # request that waited on it: under the dispatcher's ``launch``
-        # on a coalesced launch, else under the caller's current span.
-        sp = trace.current_span()
-        if sp is not None:
-            sp.add_child(
-                "compile", start, ms, family=self.family, shape=str(shapes)
-            )
+        note_first_call(self.family, str(shapes), start, ms)
         return out
 
     def lower(self, *args, **kwargs):
@@ -962,6 +955,23 @@ def _note_compile_ms(family: str, ms: float) -> None:
     _COMPILE_MS[family] = _COMPILE_MS.get(family, 0.0) + ms
 
 
+def note_first_call(family: str, shape: str, start: float, ms: float) -> None:
+    """A program shape's first call, the one that compiles: its wall
+    time accrues to the family's ``compileMs`` gauge and is recorded as
+    a ``compile`` span in the trace of the request that waited on it —
+    under the dispatcher's ``launch`` on a coalesced launch, else under
+    the caller's current span (``topn.dispatch`` for the TopN scorer)."""
+    _note_compile_ms(family, ms)
+    sp = trace.current_span()
+    if sp is not None:
+        sp.add_child("compile", start, ms, family=family, shape=shape)
+
+
+# The TopN scorer's (``bp.score_planes``) ``first_call`` hook: its
+# programs compile outside ``_Program``.
+note_scorer_first_call = functools.partial(note_first_call, "topn.score")
+
+
 def program_cache_compile_ms() -> dict[str, float]:
     """Cumulative compile-bearing first-call wall ms per jit family —
     the ``exec.programCache.compileMs[cache:*]`` gauges on /metrics and
@@ -1079,8 +1089,10 @@ def program_cache_bounds() -> dict[str, int]:
                 max(_BUCKET_HIGHWATER.get("plan.scatter.rows", rb), rb), rb
             )
         ),
-        # (self-src + host-src) x fragment-group classes x plane-row
-        # classes x candidate-slot classes
+        # (self-src + host-src) x fragment-group classes (at most
+        # log2(bp.SCORE_GROUP) + 1 whatever the slice count: larger
+        # groups relaunch the SCORE_GROUP program) x plane-row classes
+        # x candidate-slot classes
         "bitplane.scorePlanes": (
             2
             * bp.bucket_classes(max(hw.get("score_frags", 1), 1))
@@ -1147,6 +1159,7 @@ def clear_program_caches() -> None:
     _ANCHORED_HIGHWATER.clear()
     _COMPILE_MS.clear()
     bp._SHAPE_HIGHWATER.clear()
+    bp._SCORE_SEEN.clear()
     for fn in (
         bp._score_planes_self_src,
         bp._score_planes_host_src,

@@ -23,6 +23,7 @@ import threading
 
 import numpy as np
 
+from pilosa_tpu.core.view import VIEW_INVERSE, VIEW_STANDARD
 from pilosa_tpu.exec import plan
 from pilosa_tpu.ops import bitplane as bp
 
@@ -172,33 +173,80 @@ def prewarm_fuse(
     return warmed
 
 
-def prewarm_topn(
-    row_buckets=(bp.ROW_BLOCK, 2 * bp.ROW_BLOCK), group_buckets=(1,)
-) -> int:
-    """Compile the fused TopN scorer's smallest bucket shapes — the
-    self-src variant of ``bp.score_planes`` (the common
-    ``TopN(Bitmap(frame=f), frame=f)`` shape) at the first plane-row /
-    candidate-slot classes.  Every dimension of the scorer's jit key is
-    pow2-bucketed (ops/bitplane.py), so this warms the exact programs a
-    fresh node's first TopN queries hit."""
+# Scorer programs every node warms: one fragment at the first two
+# plane-row classes (a new index's first TopN).  A node that boots on
+# loaded data also warms the programs ITS indexes will use
+# (:func:`topn_shapes`, taken by the server before it listens).
+_TOPN_SHAPES = ((1, bp.ROW_BLOCK), (1, 2 * bp.ROW_BLOCK))
+# At most this many scorer programs are warmed from the holder's own
+# shapes, the views with the most fragments first: each compiles for
+# seconds, and a churny schema has dozens of (members, rows) classes.
+_TOPN_SHAPES_MAX = 8
+
+
+def topn_shapes(holder) -> list[tuple[int, int]]:
+    """The ``(members, plane rows)`` of the scorer programs the holder's
+    indexes will use, the views with the most fragments first: the n
+    fragments of a view that share a home device are scored
+    ``bp.score_group_bucket(n)`` members a launch whatever n is, at the
+    pow2 row class of their planes."""
+    n_dev = len(bp.participating_devices())
+    weight: dict[tuple[int, int], int] = {}
+    for idx in holder.indexes().values():
+        for frame in idx.frames().values():
+            for name, view in frame.views().items():
+                # TopN ranks the rows of a standard or inverse view (time
+                # views among them), never the bit planes of a BSI field.
+                if not name.startswith((VIEW_STANDARD, VIEW_INVERSE)):
+                    continue
+                frags = view.fragments()
+                if not frags:
+                    continue
+                members = bp.score_group_bucket(-(-len(frags) // n_dev))
+                for rows in {f.plane_rows() for f in frags}:
+                    key = (members, rows)
+                    weight[key] = max(weight.get(key, 0), len(frags))
+    return sorted(weight, key=lambda k: -weight[k])[:_TOPN_SHAPES_MAX]
+
+
+def prewarm_topn(shapes=_TOPN_SHAPES) -> int:
+    """Compile the fused TopN scorer — the self-src variant of
+    ``bp.score_planes`` (the common ``TopN(Bitmap(frame=f), frame=f)``
+    shape) — at each ``(members, plane rows)`` of ``shapes``, with as
+    many candidate slots as plane rows (every row a candidate).  Every
+    dimension of the scorer's jit key is pow2-bucketed and the member
+    count is bounded by ``bp.SCORE_GROUP`` (ops/bitplane.py), so these
+    are exactly the programs the first TopN queries hit, however many
+    slices the index has.  On a multi-device host this warms the first
+    device's programs; the others load theirs from the persistent
+    cache on first use."""
+    import jax
+
     warmed = 0
-    for rows in row_buckets:
-        for n in group_buckets:
-            planes = tuple(
-                np.zeros((rows, bp.WORDS_PER_SLICE), dtype=np.uint32)
-                for _ in range(n)
-            )
-            slots = np.zeros((n, rows), dtype=np.int32)
-            src_slots = np.zeros(n, dtype=np.int32)
-            bp.score_planes(
-                planes, slots, src_slots=src_slots
-            ).block_until_ready()
-            warmed += 1
+    for members, rows in shapes:
+        # Placed as a fragment's mirror is (the jit key holds the
+        # placement): slice 0's home device.
+        zero = jax.device_put(
+            np.zeros((rows, bp.WORDS_PER_SLICE), dtype=np.uint32),
+            bp.home_device(0),
+        )
+        planes = [zero] * members
+        slots = np.zeros((members, rows), dtype=np.int32)
+        src_slots = np.zeros(members, dtype=np.int32)
+        for out in bp.score_planes(
+            planes, slots, src_slots=src_slots, first_call=plan.note_scorer_first_call
+        ):
+            out.block_until_ready()
+        warmed += 1
     return warmed
 
 
-def prewarm(buckets=(1, 2, 4, 8), exprs=_STANDARD_EXPRS, coalesce=False) -> int:
-    """Compile the standard (tree shape x slice bucket) programs.
+def prewarm(
+    buckets=(1, 2, 4, 8), exprs=_STANDARD_EXPRS, coalesce=False, topn=()
+) -> int:
+    """Compile the standard (tree shape x slice bucket) programs, and
+    the TopN scorer at its standard shapes and at ``topn`` (the
+    ``(members, plane rows)`` of :func:`topn_shapes`).
 
     Triggers real compilations by calling each program on a zero batch
     of the bucketed shape — with the persistent cache enabled this both
@@ -245,14 +293,16 @@ def prewarm(buckets=(1, 2, 4, 8), exprs=_STANDARD_EXPRS, coalesce=False) -> int:
                 plan.compiled_total_count(expr, mesh)(batch).block_until_ready()
                 plan.compiled_batched(expr, "row")(batch).block_until_ready()
                 warmed += 2
-    warmed += prewarm_topn()
+    warmed += prewarm_topn(
+        list(_TOPN_SHAPES) + [k for k in topn if k not in _TOPN_SHAPES]
+    )
     if coalesce:
         warmed += prewarm_coalesce()
         warmed += prewarm_fuse()
     return warmed
 
 
-def prewarm_async(logger=None, coalesce=False) -> threading.Thread:
+def prewarm_async(logger=None, coalesce=False, topn=()) -> threading.Thread:
     """Run :func:`prewarm` on a daemon thread (server open must not
     block on compiles) and return the thread, which carries the
     outcome once it ends: ``programs`` (the count compiled) or
@@ -261,7 +311,7 @@ def prewarm_async(logger=None, coalesce=False) -> threading.Thread:
 
     def run():
         try:
-            t.programs = prewarm(coalesce=coalesce)
+            t.programs = prewarm(coalesce=coalesce, topn=topn)
         except Exception as e:  # noqa: BLE001 — recorded, not swallowed
             t.error = e
             if logger is not None:

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import functools
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -689,38 +690,96 @@ def _score_planes_host_src(planes, slots, srcs):
     return jnp.stack(outs)
 
 
-def score_planes(planes, slots, src_slots=None, srcs=None):
+# Fragments one compiled scorer program takes.  The program is unrolled
+# once per member (each plane mirror is an operand of its own: stacking
+# them would copy the planes), so its compile time grows with the
+# member count: 65.7 s at the 1024 members one 954-slice index once
+# passed (my chip run, PR 21).  Larger groups run as ceil(n / SCORE_GROUP)
+# launches of the SAME program, so neither the jit key nor the compile
+# time depends on how many slices an index has.  The value is from
+# tools/topn_scorer_sweep.py on the chip (PERF.md, PR 29; 954 planes of
+# 64 rows): the first call costs 65 ms a member (2.2 s at 32, 4.2 s at
+# 64, 7.4 s at 128), a launch 0.46 ms of dispatch whatever its size, and
+# a whole answer 16.5 / 13.3 / 18.0 ms at 32 / 64 / 128: at 64 the 15
+# dispatches hide behind the 13 ms of streaming.
+SCORE_GROUP = 64
+
+
+def score_group_bucket(n_frags: int) -> int:
+    """Members of the program that scores ``n_frags`` fragments: the
+    pow2 class of a small group, never more than SCORE_GROUP."""
+    return min(pow2_bucket(n_frags), SCORE_GROUP)
+
+
+# Program shapes the scorer has been called with: a shape's first call
+# traces and compiles (or loads) its program, and ``score_planes`` tells
+# its caller so.  Plain set writes (no lock): a racing duplicate first
+# call reports a few ms twice, never corrupts.
+_SCORE_SEEN: set = set()
+
+
+def score_planes(planes, slots, src_slots=None, srcs=None, first_call=None) -> list:
     """Cross-fragment TopN scorer that reads STRAIGHT from the
     fragments' HBM-resident plane mirrors — no stacked candidate copy
     ever materializes (a stacked batch doubled the candidate rows'
     device footprint and tripped OOM at 100 slices x 256 candidates).
 
-    ``planes``: tuple of uint32[plane_rows, words] device mirror
-    SNAPSHOTS; ``slots``: int32[n_frag, rows] candidate slot indices
-    (one small transfer); the src is either ``src_slots`` int32[n_frag]
-    — the src row's slot in the SAME plane (the common
-    TopN(Bitmap(frame=f), frame=f) shape; zero src bytes host->device,
-    and no extra leaf shapes enter the jit key) — or ``srcs``
-    uint32[n_frag, words] host-snapshot rows.  Gathers fuse into the
-    popcount reduce, so each candidate row is read once.  Returns
-    int32[n_frag, rows].  One dispatch + one fetch per query where the
-    per-fragment path paid a dispatch, a src transfer, and a fetch PER
-    SLICE.
+    ``planes``: sequence of uint32[plane_rows, words] device mirror
+    SNAPSHOTS, one per fragment; ``slots``: int32[n_frag, rows]
+    candidate slot indices (one small transfer a launch); the src is
+    either ``src_slots`` int32[n_frag] — the src row's slot in the SAME
+    plane (the common TopN(Bitmap(frame=f), frame=f) shape; zero src
+    bytes host->device, and no extra leaf shapes enter the jit key) — or
+    ``srcs`` uint32[n_frag, words] host-snapshot rows.  Gathers fuse
+    into the popcount reduce, so each candidate row is read once.
 
-    Every dimension of the jit key is pow2-bucketed by the callers —
-    fragment count (executor group padding), plane rows (pad_rows at
-    plane allocation), candidate slots (pad_rows at prepare) — so the
-    compiled-program count is bounded by the product of the classes,
-    not by how many distinct fragment shapes the schema churns through.
+    The fragments are scored ``score_group_bucket(n_frag)`` at a time:
+    every launch is dispatched without waiting and the device arrays
+    are returned as a list, int32[bucket, rows] each, in fragment order
+    (the last launch is padded by repeating its last member; surplus
+    rows are simply not read back).  The caller fetches them in ONE
+    device->host round trip.  ``first_call(shape, start, ms)`` is told
+    of a program shape's first call, the one that compiles: its shape
+    as text, its wall-clock start and how long it took.
+
+    Every dimension of the jit key is pow2-bucketed and bounded —
+    members (here), plane rows (pad_rows at plane allocation),
+    candidate slots (pad_rows at prepare) — so the compiled-program
+    count is the product of the classes, whatever the number of
+    fragments and however many distinct fragment shapes the schema
+    churns through.
     """
+    n = len(planes)
+    bucket = score_group_bucket(n)
     _note_shape(
-        score_frags=len(planes),
+        score_frags=bucket,
         score_rows=max(int(p.shape[0]) for p in planes),
         score_slots=int(slots.shape[-1]),
     )
-    if srcs is None:
-        return _score_planes_self_src(planes, slots, src_slots)
-    return _score_planes_host_src(planes, slots, srcs)
+    fn, src = (
+        (_score_planes_self_src, src_slots)
+        if srcs is None
+        else (_score_planes_host_src, srcs)
+    )
+    devs = getattr(planes[0], "devices", None)
+    shape = (
+        "self" if srcs is None else "host",
+        bucket,
+        tuple(planes[0].shape),
+        int(slots.shape[-1]),
+        str(sorted(map(str, devs()))) if callable(devs) else "",
+    )
+    outs = []
+    for lo in range(0, n, bucket):
+        idx = np.minimum(np.arange(lo, lo + bucket), n - 1)
+        group = tuple(planes[i] for i in idx)
+        start, t0 = time.time(), time.monotonic()
+        outs.append(fn(group, slots[idx], src[idx]))
+        if shape not in _SCORE_SEEN:
+            _SCORE_SEEN.add(shape)
+            if first_call is not None:
+                first_call(str(shape), start, (time.monotonic() - t0) * 1e3)
+    return outs
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

@@ -85,3 +85,14 @@ def free_udp_port() -> int:
     port = s.getsockname()[1]
     s.close()
     return port
+
+
+@pytest.fixture
+def one_chip():
+    """Every slice's home is one device, as on a one-chip machine (the
+    tests' CPU has eight, and fragments are grouped by their home)."""
+    from pilosa_tpu.ops import bitplane as bp
+
+    bp.configure_mesh_devices(1)
+    yield
+    bp.configure_mesh_devices(0)
