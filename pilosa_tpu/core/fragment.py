@@ -1415,6 +1415,28 @@ class Fragment:
         with self._mu:
             return row_id in self._slot_of or row_id in self._sparse
 
+    def gather_slots(self, row_ids) -> tuple | None:
+        """``(mirror snapshot, [slot, ...])`` for the device gather of a
+        leaf batch (exec/executor.py, ``bp.gather_planes``): the slot of
+        each row in the plane, -1 for a row the fragment does not hold,
+        and the mirror those slots index, both read under one hold of
+        the lock (``device_plane()`` applies a queued point-write scatter
+        first, so an acknowledged write is in the snapshot).  The mirror
+        is None, and nothing is uploaded, when no row is held.  None when
+        a row lives in the sparse tier: no plane holds it."""
+        with self._mu:
+            slots = []
+            for row_id in row_ids:
+                slot = self._slot_of.get(row_id)
+                if slot is None:
+                    if row_id in self._sparse:
+                        return None
+                    slot = -1
+                slots.append(slot)
+            if max(slots, default=-1) < 0:
+                return None, slots
+            return self.device_plane(), slots
+
     def device_row(self, row_id: int):
         """One row as a device leaf for query plans (exec/plan.py).
 

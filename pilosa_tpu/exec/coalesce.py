@@ -102,12 +102,20 @@ MAX_FUSE_LEAVES = 64
 # holds a register file of (leaf bucket + op bucket) slice-rows for
 # EVERY batch row at once, beside the combined leaf array, so its
 # footprint grows with slices x programs where MAX_FUSE_LEAVES only
-# counts leaves: at 1024 batch rows, 8 leaves and 16 programs compile
-# to 6.8 GiB of HLO temp on a v5e, and 32 leaves do not compile at all
-# (PERF.md, PR 21).  Program sets past the budget split into further
-# launches; like the leaf budget this bounds device memory, not
-# correctness.
+# counts leaves.  What XLA allots it is FUSE_SCRATCH_FACTOR register
+# files, not one: compiled for a v5e at 1024 batch rows the program's
+# temp is 5.0 GiB at 4 leaves + 8 ops (a register file of 1.5 GiB),
+# 5.4 at 8 + 8, 7.8 at 8 + 16 and at 16 + 8, 10.7 at 16 + 16
+# (``memory_analysis`` of the chip's own compiler; tests/
+# test_topn_deployment.py holds the factor).  Counted as one register
+# file, launches of 5-8 GiB passed the budget on a chip whose planes
+# take 8 of its 16 GB, and every batch assembled meanwhile failed for
+# memory (PERF.md, PR 31).  Program sets past the budget split into
+# further launches, and a pair that still does not fit launches each
+# tree's own program; like the leaf budget this bounds device memory,
+# not correctness.
 MAX_FUSE_BYTES = 2 << 30
+FUSE_SCRATCH_FACTOR = 3.4
 # Reduce kinds the interpreter can evaluate; "agg" trees reduce inside
 # the expression (BSI aggregates) and stay on the per-compile-key path.
 # "total" is the ICI-reduced count: per-register limb pairs summed
@@ -123,6 +131,10 @@ _FETCH_KEY = ("__fetch__",)
 # this bounds transient device memory (concatenation materializes a
 # copy), not correctness.
 MAX_CONCAT_ROWS = 4096
+# Largest all-zero LEAF pad block the scheduler keeps between launches
+# (_leaf_pad_zeros); a larger one is a device-side fill of the launch
+# that needs it.
+ZERO_CACHE_MAX_BYTES = 32 << 20
 # Backstop bound on a waiter's Future wait: a wedged device call must
 # surface as a failed query, not a hung request thread.  Waiters with a
 # query deadline clamp this to their REMAINING budget and detach on
@@ -859,7 +871,8 @@ class CoalesceScheduler:
         rows_per_device = n_rows // len(segs[0].devices())
         row_bytes = int(segs[0].shape[-1]) * segs[0].dtype.itemsize
         over = (
-            rows_per_device * (l_bucket + p_bucket) * row_bytes
+            FUSE_SCRATCH_FACTOR
+            * rows_per_device * (l_bucket + p_bucket) * row_bytes
             > MAX_FUSE_BYTES
         )
         if over and len(out_of) > 2:
@@ -1009,11 +1022,10 @@ class CoalesceScheduler:
         self._fallback_by_key(reduce, fallback)
 
     def _leaf_pad_zeros(self, n_rows: int, pad: int, like):
-        """Cached all-zero LEAF-axis pad block matching ``like``'s
-        placement (single device, or the identical sharding for mesh
-        batches) — bucketing the combined leaf axis of a fused launch
-        (pow2 gaps, so the cache stays small like the row-pad one)."""
-        import jax
+        """All-zero LEAF-axis pad block matching ``like``'s placement
+        (single device, or the identical sharding for mesh batches) —
+        bucketing the combined leaf axis of a fused launch."""
+        import jax.numpy as jnp
 
         words = int(like.shape[-1])
         devs = list(like.devices())
@@ -1023,13 +1035,19 @@ class CoalesceScheduler:
         else:
             target = like.sharding
             token = repr(target)
+        # A pad past ZERO_CACHE_MAX_BYTES is made on the device for this
+        # launch and dropped with it: at 1,024 batch rows a leaf pad is
+        # 128 MiB a leaf, and the seven classes of a 16-leaf bucket,
+        # kept, were 3.5 GB of HBM that held nothing (PERF.md, PR 31).
+        shape = (n_rows, pad, words)
+        if int(np.prod(shape)) * 4 > ZERO_CACHE_MAX_BYTES:
+            return jnp.zeros(shape, dtype=jnp.uint32, device=target)
         zkey = ("leafpad", n_rows, pad, words, token)
         z = self._zeros.get(zkey)
         if z is None:
-            z = jax.device_put(
-                np.zeros((n_rows, pad, words), dtype=np.uint32), target
+            z = self._zeros[zkey] = jnp.zeros(
+                shape, dtype=jnp.uint32, device=target
             )
-            self._zeros[zkey] = z
         return z
 
     def _launch_fetch(self, items: list) -> None:

@@ -56,6 +56,7 @@ from pilosa_tpu.core.view import VIEW_INVERSE, VIEW_STANDARD
 from pilosa_tpu.exec import coalesce as coalesce_mod
 from pilosa_tpu.exec import hosteval as hosteval_mod
 from pilosa_tpu.exec import plan
+from pilosa_tpu.exec import warmup
 from pilosa_tpu.net import resilience
 from pilosa_tpu.obs import perf as perf_mod
 from pilosa_tpu.obs import trace
@@ -82,6 +83,25 @@ DEFAULT_MAX_WRITES_PER_REQUEST = 5000
 
 WRITE_CALLS = frozenset({"SetBit", "ClearBit", "SetRowAttrs", "SetColumnAttrs"})
 
+
+
+def _fitting_runs(lo: int, hi: int, rows: int):
+    """Split members ``[lo, hi)`` of a block of ``rows`` rows so that
+    every split's launches, padded to their members bucket
+    (``bp.gather_planes``), still end inside the block: an in-place
+    write that does not fit would be shifted.  One run wherever the
+    planes of a block share a shape (64 divides every larger bucket);
+    a run that ends a block of mixed shapes splits at powers of two,
+    which pad nothing."""
+    while lo < hi:
+        n = hi - lo
+        b = bp.score_group_bucket(n)
+        if lo + -(-n // b) * b <= rows:
+            yield lo, hi
+            return
+        m = 1 << (n.bit_length() - 1)
+        yield lo, lo + m
+        lo += m
 
 class ExecutorError(RuntimeError):
     pass
@@ -367,9 +387,10 @@ class Executor:
         self._pool = _DaemonPool(
             max_workers=16, stats=getattr(holder, "stats", None)
         )
-        self._zero_rows: dict = {}  # device -> cached all-zero leaf row
-        # (value, bucket, device) -> packed BSI predicate row on device.
-        self._pred_rows: dict = {}
+        # Shapes whose gather programs a mostly-cold miss has warmed,
+        # and the last thread that did (see _warm_gather_beside).
+        self._gather_warmed: set = set()
+        self._gather_warming: threading.Thread | None = None
         # Assembled leaf-batch LRU (see _cached_batch); executors serve
         # concurrent HTTP request threads, so access is lock-guarded.
         self._batch_cache: "OrderedDict[tuple, dict]" = OrderedDict()
@@ -667,46 +688,10 @@ class Executor:
     # bitmap call trees — fused device programs
     # ------------------------------------------------------------------
 
-    def _leaf_row_device(self, index: str, c: Call, slice_i: int):
-        """Fetch one leaf row as a device (or None=empty) uint32[32768]."""
-        if c.name == "Bitmap":
-            frag, row_id = self._resolve_bitmap_leaf(index, c, slice_i)
-            if frag is None:
-                return None
-            return frag.device_row(row_id)
-        if c.name == "Range":
-            return self._range_row_device(index, c, slice_i)
-        if c.name == "BsiPlane":
-            frag = self._bsi_plane_fragment(index, c, slice_i)
-            if frag is None:
-                return None
-            return frag.device_row(c.args["row"])
-        if c.name == "BsiPred":
-            return self._pred_row_device(c, slice_i)
-        if c.name == "BsiZero":
-            return None
-        raise plan.PlanError(f"unknown call: {c.name}")
-
     def _bsi_plane_fragment(self, index: str, c: Call, slice_i: int):
         return self.holder.fragment(
             index, c.args["frame"], bsi.field_view_name(c.args["field"]), slice_i
         )
-
-    def _pred_row_device(self, c: Call, slice_i: int):
-        """A packed predicate row on a slice's home device, cached per
-        (value, bucket, device) — predicates repeat across slices and
-        across queries, so the upload happens once."""
-        import jax
-
-        dev = bp.home_device(slice_i)
-        key = (c.args["v"], c.args["d"], dev)
-        row = self._pred_rows.get(key)
-        if row is None:
-            row = jax.device_put(bsi.pred_row(c.args["v"], c.args["d"]), dev)
-            if len(self._pred_rows) >= 256:
-                self._pred_rows.clear()
-            self._pred_rows[key] = row
-        return row
 
     def _resolve_bitmap_leaf(self, index: str, c: Call, slice_i: int):
         """Frame/row/orientation resolution for a Bitmap() leaf
@@ -764,30 +749,6 @@ class Executor:
             )
         view_name, id_ = (VIEW_INVERSE, col_id) if col_ok else (VIEW_STANDARD, row_id)
         return view_name, id_, _time_arg(c, "start"), _time_arg(c, "end"), f.time_quantum
-
-    def _range_row_device(self, index: str, c: Call, slice_i: int):
-        """Union of rows across time views (reference: executor.go:507-589)."""
-        frame = c.args.get("frame") or DEFAULT_FRAME
-        idx = self.holder.index(index)
-        if idx is None:
-            raise IndexNotFoundError()
-        f = idx.frame(frame)
-        if f is None:
-            raise FrameNotFoundError()
-        view_name, id_, start, end, quantum = self._resolve_range(idx, f, c)
-        if not quantum:
-            return None
-
-        acc = None
-        for view in tq.views_by_time_range(view_name, start, end, quantum):
-            frag = self.holder.fragment(index, frame, view, slice_i)
-            if frag is None:
-                continue
-            row = frag.device_row(id_)
-            if row is None:
-                continue
-            acc = row if acc is None else (acc | row)
-        return acc
 
     # ------------------------------------------------------------------
     # BSI rewrite — Range(field > x) / Sum / Min / Max expansion
@@ -904,8 +865,8 @@ class Executor:
         )
 
     def _leaf_row_host(self, index: str, c: Call, slice_i: int):
-        """Host-side (numpy) variant of _leaf_row_device: one leaf row's
-        words, or None when the row has no bits."""
+        """One leaf row's words on the host (numpy), or None when the
+        row has no bits."""
         if c.name == "Bitmap":
             frag, row_id = self._resolve_bitmap_leaf(index, c, slice_i)
             if frag is None:
@@ -993,39 +954,6 @@ class Executor:
             sp.annotate(bytes=int(batch_np.nbytes))
             return jnp.asarray(batch_np), kept, empties
 
-    def _gather_leaf_stacks(self, index: str, c: Call, slices: list[int]):
-        """Fetch every slice's leaf rows onto its home device.
-
-        Returns ``(expr, stacks, kept_slices, empties)``: ``stacks[i]``
-        is uint32[n_leaves, 32768] for ``kept_slices[i]`` (device-local);
-        ``empties`` are slices where no leaf has any bits (their result
-        is identically zero for every tree shape)."""
-        expr, leaves = plan.decompose(c)
-        stacks: list[object] = []
-        kept_slices: list[int] = []
-        empties: list[int] = []
-        with self.tracer.span("plan.leaves", path="device_gather") as sp:
-            for s in slices:
-                rows = []
-                any_set = False
-                for leaf in leaves:
-                    r = self._leaf_row_device(index, leaf, s)
-                    if r is None:
-                        r = self._zero_row(s)
-                    elif leaf.name not in plan.NEUTRAL_LEAVES:
-                        any_set = True
-                    rows.append(r)
-                if not leaves or not any_set:
-                    empties.append(s)
-                    continue
-                # All of a slice's leaves live on its home device, so this
-                # stack stays device-local.
-                stacks.append(jnp.stack(rows))
-                kept_slices.append(s)
-            n_rows = len(slices) * len(leaves)
-            sp.annotate(rows=n_rows, device_copies=n_rows + len(stacks))
-        return expr, stacks, kept_slices, empties
-
     # Assembled leaf batches kept per (index, canonical call, slice set):
     # the working set of a hot query is one entry.  Each entry holds
     # device memory comparable to the queried planes, so entries are
@@ -1045,12 +973,12 @@ class Executor:
         """The assembled device batch for a bitmap call tree over
         ``slices``, CACHED across queries.
 
-        At bench scale the per-slice Python loop in _gather_leaf_stacks
-        costs ~2 device dispatches per (slice, leaf) — thousands of
-        host-side operations before the fused program runs, where the
-        reference's goroutine-per-slice mapperLocal amortizes to ~zero
-        (reference: executor.go:1246-1282).  Repeated query shapes skip
-        it entirely: entries validate in O(1) against the global
+        Assembly costs a sweep of every (slice, leaf) fragment and a
+        few launches a device (or, from cold planes, a host fill and a
+        transfer), where the reference's goroutine-per-slice
+        mapperLocal amortizes to ~zero (reference:
+        executor.go:1246-1282).  Repeated query shapes skip it
+        entirely: entries validate in O(1) against the global
         fragment write epoch, then (only when some fragment changed
         anywhere) against the per-fragment version vector.  Range
         leaves' validity entries additionally carry the frame's time
@@ -1083,12 +1011,11 @@ class Executor:
             # assembly leaves the entry conservatively stale.  The same
             # sweep counts mirror-less fragments for the cold-path choice.
             epoch = fragment_mod.write_epoch()
-            versions = None
+            versions = sweep = None
             n_frag = n_cold = 0
             if cacheable:
-                versions, n_frag, n_cold = self._leaf_versions(
-                    index, leaves, slices, with_cold=True
-                )
+                sweep = self._leaf_sweep(index, leaves, slices)
+                versions, n_frag, n_cold = self._sweep_versions(sweep)
             rs.annotate(fragments=n_frag, cold=n_cold)
         mesh = pmesh.default_slices_mesh()
         ent = {
@@ -1097,64 +1024,41 @@ class Executor:
             "mesh": None,
             "epoch": epoch,
             "versions": versions,
+            "expr": expr,
         }
-        if mesh is None:
-            # Single device: assemble HOST-side (one numpy fill + one
-            # transfer; the slice axis pads to a power of two — one
-            # compiled program per (tree shape, bucket), SURVEY.md §7
-            # shape bucketing).
+        # Mostly-resident dense rows gather ON the device from the plane
+        # mirrors, on one chip and on a mesh alike (a fragment or two
+        # invalidated by a write re-upload on the way).  MOSTLY-cold
+        # fragments, a sparse-tier row or a time-quantum Range fill on
+        # the host from the authoritative planes: one transfer a device,
+        # and no full-plane uploads just to read two rows.
+        built = None
+        if sweep is not None and n_frag:
+            if n_cold * 2 <= n_frag:
+                built = self._assemble_gather_batch(leaves, slices, sweep, mesh)
+            else:
+                self._warm_gather_beside(leaves, sweep)
+        if built is not None:
+            batch, pos_of, kept_slices, empties = built
+        elif mesh is None:
+            # one numpy fill + one transfer; the slice axis pads to a
+            # power of two — one compiled program per (tree shape,
+            # bucket), SURVEY.md §7 shape bucketing
             batch, kept_slices, empties = self._assemble_host_batch(
                 index, leaves, slices
             )
-            ent.update(
-                expr=expr,
-                empties=empties,
-                kept=kept_slices,
-                batch=batch,
-                pos_of={s: i for i, s in enumerate(kept_slices)},
-            )
-        elif cacheable and n_cold * 2 > n_frag:
-            # MOSTLY-cold fragments: assemble per-device blocks HOST-
-            # side from the authoritative planes — one transfer per
-            # device instead of ~2 device dispatches per (slice, leaf),
-            # and no full-plane uploads just to gather two rows.  A
-            # mostly-WARM set (e.g. one fragment invalidated by a write)
-            # keeps the device-gather path, which re-uploads only the
-            # changed planes.
+            pos_of = {s: i for i, s in enumerate(kept_slices)}
+        else:
             batch, pos_of, kept_slices, empties = self._assemble_mesh_batch_host(
                 index, leaves, slices, mesh
             )
-            ent.update(expr=expr, empties=empties, kept=kept_slices)
-            if batch is not None:
-                ent.update(
-                    batch=batch,
-                    pos_of=pos_of,
-                    mesh=mesh if len(kept_slices) > 1 else None,
-                )
-        else:
-            # Warm device mirrors (or Range trees): gather rows straight
-            # from HBM-resident planes — nothing crosses host<->device.
-            expr, stacks, kept_slices, empties = self._gather_leaf_stacks(
-                index, c, slices
+        ent.update(empties=empties, kept=kept_slices)
+        if batch is not None:
+            ent.update(
+                batch=batch,
+                pos_of=pos_of,
+                mesh=mesh if len(kept_slices) > 1 else None,
             )
-            ent.update(expr=expr, empties=empties, kept=kept_slices)
-            if len(kept_slices) > 1:
-                with self.tracer.span(
-                    "plan.transfer", devices=int(mesh.devices.size)
-                ) as ts:
-                    batch, pos_of = self._assemble_mesh_batch(
-                        stacks, kept_slices, mesh
-                    )
-                    ts.annotate(bytes=int(batch.nbytes))
-                ent.update(batch=batch, pos_of=pos_of, mesh=mesh)
-            elif kept_slices:
-                with self.tracer.span("plan.transfer", devices=1) as ts:
-                    batch = jnp.stack(stacks)
-                    ts.annotate(bytes=int(batch.nbytes))
-                ent.update(
-                    batch=batch,
-                    pos_of={s: i for i, s in enumerate(kept_slices)},
-                )
         # Per-column leaf identity keys for union-leaf fusion
         # (coalesce._launch_interp): equal keys guarantee byte-identical
         # columns — same leaf call, same kept-slice geometry, and the
@@ -1194,10 +1098,277 @@ class Executor:
                 gs.annotate(displaced=len(displaced))
         return ent
 
+    @staticmethod
+    def _gather_columns(leaves, sweep):
+        """``(cols, consts)`` of a gatherable tree: ``cols`` the columns
+        that read one view, in runs — a member's rows of a run come from
+        ONE plane — as ``(first column, row ids, fragments)``; ``consts``
+        the slice-invariant predicate rows, ``(column, host row)``.  The
+        BsiZero pads that follow a field's planes ride in its run as row
+        id None (held nowhere: zeros), so the fields of one depth bucket
+        gather by one program, as they count by one.  None for a tree
+        the gather does not take: a time-quantum Range is a union over
+        views."""
+        if any(ent[0] == "range" for ent in sweep):
+            return None
+        cols: list[tuple[int, list[int], list]] = []
+        consts: list[tuple[int, np.ndarray]] = []
+        for j, (leaf, ent) in enumerate(zip(leaves, sweep)):
+            if ent[0] == "rows":
+                _, view, row_id, frags = ent
+                if view is None:
+                    continue
+                if cols and cols[-1][2] is frags and cols[-1][0] + len(cols[-1][1]) == j:
+                    cols[-1][1].append(row_id)
+                else:
+                    cols.append((j, [row_id], frags))
+            elif leaf.name == "BsiPred":
+                consts.append((j, bsi.pred_row(leaf.args["v"], leaf.args["d"])))
+            elif cols and cols[-1][0] + len(cols[-1][1]) == j:
+                cols[-1][1].append(None)
+        return cols, consts
+
+    def _warm_gather_beside(self, leaves, sweep) -> None:
+        """A miss over MOSTLY-cold fragments fills on the host, and the
+        prefetcher is already uploading their mirrors: the next miss
+        will gather.  Its programs, at this batch's own shapes, are
+        compiled now on a thread beside the fill (a fresh node's first
+        texts, a benchmark's warm-up) and nobody waits for them who does
+        not need them: a gather that comes before they are ready waits
+        out the rest of the compile (``bp._first_call``).  Once a
+        shape."""
+        gatherable = self._gather_columns(leaves, sweep)
+        if gatherable is None:
+            return
+        devices = bp.participating_devices()
+        shapes = set()
+        for _, row_ids, frags in gatherable[0]:
+            live = [f for f in frags if f is not None]
+            if live:
+                n = -(-len(live) // len(devices))
+                shapes.add(
+                    (
+                        bp.score_group_bucket(n),
+                        live[0].plane_rows(),
+                        plan.slice_bucket(n),
+                        len(row_ids),
+                        len(leaves),
+                    )
+                )
+        shapes -= self._gather_warmed
+        if not shapes:
+            return
+        self._gather_warmed |= shapes
+
+        def run():
+            try:
+                warmup.prewarm_gather(shapes, devices)
+            except Exception:  # noqa: BLE001 — a warm-up: the gather's own
+                pass  # first call compiles, and reports, what this could not
+
+        self._gather_warming = threading.Thread(
+            target=run, daemon=True, name="gather-warm"
+        )
+        self._gather_warming.start()
+
+    def _assemble_gather_batch(self, leaves, slices: list[int], sweep, mesh):
+        """Assemble the leaf batch ON the device from the fragments'
+        resident plane mirrors: per device, ceil(members / 64) launches
+        of one gather program (``bp.gather_planes``, the TopN scorer's
+        convention: the mirrors are operands, rows are picked by slot
+        inside the program) written in place into one zeroed block of
+        the slice bucket the consumers use.  No row is copied on the
+        host and nothing crosses host<->device but the slot indices.
+        One chip: the block is the batch.  A mesh: a block a home
+        device over ``_mesh_placement``'s groups (a spilled slice is
+        gathered where its plane lives and moved), glued by
+        ``pmesh.assemble_sharded_batch``.
+
+        Returns ``(batch, pos_of, kept, empties)`` exactly as the host
+        fills give them (``kept`` is decided from the fragments' slot
+        maps, no plane is read) — the three producers feed one batch
+        cache — or None where the gather does not apply and the caller
+        fills on the host: a time-quantum Range (a union over views), a
+        sparse-tier row (no plane holds it), or a launch that failed
+        for a device reason."""
+        gatherable = self._gather_columns(leaves, sweep)
+        if gatherable is None:
+            return None
+        cols, consts = gatherable
+        n = len(slices)
+        with self.tracer.span("plan.leaves") as sp:
+            planes = [[None] * n for _ in cols]
+            slots = [np.full((n, len(c[1])), -1, dtype=np.int32) for c in cols]
+            held = np.zeros(n, dtype=bool)
+            for g, (_, row_ids, frags) in enumerate(cols):
+                for i, frag in enumerate(frags):
+                    if frag is None:
+                        continue
+                    ref = frag.gather_slots(row_ids)
+                    if ref is None:
+                        sp.annotate(path="gather_declined", reason="sparse_tier")
+                        return None
+                    if ref[0] is not None:
+                        planes[g][i], slots[g][i] = ref
+                        held[i] = True
+            kept = [s for i, s in enumerate(slices) if held[i]]
+            empties = [s for i, s in enumerate(slices) if not held[i]]
+            n_rows = int(sum((sl >= 0).sum() for sl in slots))
+            if not kept:
+                sp.annotate(path="plane_gather", rows=0, launches=0, device_copies=0)
+                return None, {}, kept, empties
+            at = {s: i for i, s in enumerate(slices)}
+            if mesh is None or len(kept) == 1:
+                # (device, block rows, members); one kept slice of a mesh
+                # is a plain one-device batch, as the host fill gives it
+                layout = [
+                    (bp.home_device(kept[0]), plan.slice_bucket(len(kept)), kept)
+                ]
+                pos_of = {s: i for i, s in enumerate(kept)}
+            else:
+                n_dev = int(mesh.devices.size)
+                groups, chunk = self._mesh_placement(kept, n_dev)
+                layout = [
+                    (mesh.devices.flat[d], chunk, groups[d]) for d in range(n_dev)
+                ]
+                pos_of = {
+                    s: d * chunk + i
+                    for d in range(n_dev)
+                    for i, s in enumerate(groups[d])
+                }
+
+            def block_of(dev, rows, members):
+                return self._gather_block(
+                    dev,
+                    (rows, len(leaves), bp.WORDS_PER_SLICE),
+                    cols,
+                    planes,
+                    slots,
+                    [at[s] for s in members],
+                )
+
+            try:
+                self._fault_check_launch("gather")
+                # a device's own slices lead its group; what spilled in
+                # from a fuller device follows
+                homes = [
+                    [s for s in members if bp.home_device(s) == dev]
+                    for dev, _, members in layout
+                ]
+                blocks, launches = [], 0
+                for home, (dev, rows, _) in zip(homes, layout):
+                    block, n_launched = block_of(dev, rows, home)
+                    blocks.append(block)
+                    launches += n_launched
+                sp.annotate(
+                    path="plane_gather",
+                    rows=n_rows,
+                    launches=launches,
+                    device_copies=launches,
+                )
+            except Exception as e:
+                if health_mod.classify(e) is None:
+                    raise
+                sp.annotate(path="gather_declined", reason=type(e).__name__)
+                return None
+        with self.tracer.span("plan.transfer", devices=len(layout)) as sp:
+            try:
+                spilled = 0
+                for d, ((dev, _, members), home) in enumerate(zip(layout, homes)):
+                    block = blocks[d]
+                    for i in range(len(home), len(members)):
+                        s = members[i]
+                        one, _ = block_of(bp.home_device(s), 1, [s])
+                        block = bp.place_rows(
+                            block,
+                            jax.device_put(one, dev),
+                            i,
+                            first_call=plan.note_gather_first_call,
+                        )
+                        spilled += 1
+                    for col, row in consts:
+                        block = bp.place_const(
+                            block,
+                            row,
+                            len(members),
+                            col,
+                            first_call=plan.note_gather_first_call,
+                        )
+                    blocks[d] = block
+                batch = (
+                    blocks[0]
+                    if len(blocks) == 1
+                    else pmesh.assemble_sharded_batch(blocks, mesh)
+                )
+            except Exception as e:
+                if health_mod.classify(e) is None:
+                    raise
+                sp.annotate(declined=type(e).__name__)
+                return None
+            sp.annotate(bytes=int(batch.nbytes), spilled=spilled)
+        return batch, pos_of, kept, empties
+
+    @staticmethod
+    def _gather_block(dev, shape: tuple, cols, planes, slots, members: list[int]):
+        """The block of ``shape`` on ``dev`` whose leading rows are
+        ``members`` (positions in the swept slice list, all homed on
+        ``dev``), in order: every launch of the gather is dispatched
+        without waiting and its output written into the zeroed block in
+        place before the next is dispatched, so one launch's output (16
+        MiB at 64 members x 2 rows) is all that lives beside the block.
+        A padded launch's surplus rows are zeros and the next launch,
+        in row order, writes over them.  A single launch that fills the
+        block IS the block.  Members launch together while their planes
+        share a shape (the jit key holds it); one that holds none of a
+        run's rows rides with its neighbours and gathers zeros.
+        Returns ``(block, launches)``."""
+        block, launches = None, 0
+        for g, (col0, _row_ids, _frags) in enumerate(cols):
+            pl = [planes[g][i] for i in members]
+            sl = slots[g][members]
+            lo, plane_shape = 0, None
+            runs = []
+            for m, p in enumerate(pl):
+                if p is None:
+                    continue
+                if plane_shape is not None and p.shape != plane_shape:
+                    runs.append((lo, m))
+                    lo = m
+                plane_shape = p.shape
+            if plane_shape is not None:
+                runs.append((lo, len(pl)))
+            for lo, hi in runs:
+                stand_in = next(p for p in pl[lo:hi] if p is not None)
+                for a, b in _fitting_runs(lo, hi, shape[0]):
+                    if not (sl[a:b] >= 0).any():
+                        continue
+                    outs = bp.gather_planes(
+                        [p if p is not None else stand_in for p in pl[a:b]],
+                        sl[a:b],
+                        first_call=plan.note_gather_first_call,
+                    )
+                    for t, out in enumerate(outs):
+                        launches += 1
+                        if tuple(out.shape) == shape:
+                            block = out
+                            continue
+                        if block is None:
+                            block = jnp.zeros(shape, dtype=jnp.uint32, device=dev)
+                        block = bp.place_rows(
+                            block,
+                            out,
+                            a + t * int(out.shape[0]),
+                            col0,
+                            first_call=plan.note_gather_first_call,
+                        )
+        if block is None:
+            block = jnp.zeros(shape, dtype=jnp.uint32, device=dev)
+        return block, launches
+
     def _assemble_mesh_batch_host(self, index: str, leaves, slices, mesh):
         """Host-side mesh batch assembly for COLD fragments: read leaf
         rows from the authoritative numpy planes, group by home device
-        (slice mod n_devices, same placement as _assemble_mesh_batch,
+        (slice mod n_devices, same placement as _assemble_gather_batch,
         including balanced-chunk spill), and ship ONE block per device.
         Returns (batch, pos_of, kept, empties); batch is None when
         nothing is set, and a plain single-device array when only one
@@ -1258,7 +1429,7 @@ class Executor:
     @staticmethod
     def _mesh_placement(kept: list[int], n_dev: int):
         """Slice -> device placement shared by BOTH batch assemblers
-        (device gather and cold host blocks): home device = slice mod
+        (plane gather and cold host blocks): home device = slice mod
         n_devices (matching fragment plane placement), chunk = balanced
         power-of-two (pow2 >= ceil(n/n_devices)), clustered overflow
         spilled to devices with free rows.  Returns ({device: [slices]},
@@ -1278,63 +1449,92 @@ class Executor:
                 groups[d].append(spill.pop())
         return groups, chunk
 
-    def _leaf_versions(
-        self, index: str, leaves, slices: list[int], with_cold: bool = False
-    ):
-        """(fragment identity, version) per (slice, leaf) — the cache
-        validity vector.  Pure dict lookups; no device work.  With
-        ``with_cold`` also returns (n_fragments, n_without_device_mirror)
-        from the same sweep, so callers never resolve the pairs twice."""
-        # Range resolution (frame lookup, timestamp parsing, time-view
-        # enumeration) is slice-invariant — hoist it out of the
-        # per-slice loop (954 slices at bench scale revalidate after
-        # every write anywhere).
-        range_ctx: dict[int, tuple | None] = {
-            j: self._range_leaf_context(index, leaf)
-            for j, leaf in enumerate(leaves)
-            if leaf.name == "Range"
-        }
+    def _leaf_sweep(self, index: str, leaves, slices: list[int]) -> list:
+        """What every leaf reads over ``slices``, resolved once a leaf
+        (one hold of a view's lock, ``View.fragments_at``) and not once
+        a (slice, leaf): a list, an entry a leaf, of
+
+        * ``("rows", view, row id, fragments)`` — a Bitmap or BsiPlane
+          leaf: one row of each slice's fragment (None where the view
+          has none; the view itself None where the frame lacks it);
+        * ``("range", quantum, [fragments a time view])`` — a
+          time-quantum Range leaf, ``("range", None, [])`` when it
+          cannot resolve (no quantum);
+        * ``("const",)`` — a slice-invariant row (BsiPred, BsiZero):
+          its identity is in the canonical call string of the key.
+
+        Pure dict lookups, no device work.  The cache's validity vector
+        and the device gather are both made from it, so a miss never
+        resolves the pairs twice."""
+        out: list = []
+        none = [None] * len(slices)
+        for leaf in leaves:
+            if leaf.name in plan.NEUTRAL_LEAVES:
+                out.append(("const",))
+            elif leaf.name == "Range":
+                # frame lookup, timestamp parsing and time-view
+                # enumeration are slice-invariant
+                ctx = self._range_leaf_context(index, leaf)
+                if ctx is None:
+                    out.append(("range", None, []))
+                    continue
+                frame, quantum, views = ctx
+                per_view = []
+                for name in views:
+                    v = self.holder.view(index, frame, name)
+                    per_view.append(none if v is None else v.fragments_at(slices))
+                out.append(("range", quantum, per_view))
+            else:
+                if leaf.name == "BsiPlane":
+                    view = self.holder.view(
+                        index,
+                        leaf.args["frame"],
+                        bsi.field_view_name(leaf.args["field"]),
+                    )
+                    row_id = leaf.args["row"]
+                else:
+                    view, row_id = self._resolve_bitmap_view(index, leaf)
+                # leaves of one view share the list (and the lock hold)
+                frags = next(
+                    (e[3] for e in out if e[0] == "rows" and e[1] is view),
+                    None,
+                )
+                if frags is None:
+                    frags = none if view is None else view.fragments_at(slices)
+                out.append(("rows", view, row_id, frags))
+        return out
+
+    @staticmethod
+    def _sweep_versions(sweep: list):
+        """``(validity vector, n_fragments, n_without_device_mirror)``
+        of a :meth:`_leaf_sweep`: (fragment identity, version) per
+        (leaf, slice)."""
         out = []
         n_frag = n_cold = 0
-        for s in slices:
-            for j, leaf in enumerate(leaves):
-                if j in range_ctx:
-                    ctx = range_ctx[j]
-                    if ctx is None:
-                        out.append(("range", None))
-                        continue
-                    frame, quantum, views = ctx
-                    vers = []
-                    for view in views:
-                        frag = self.holder.fragment(index, frame, view, s)
-                        if frag is None:
-                            vers.append(None)
-                        else:
-                            vers.append((frag._serial, frag._version))
-                            n_frag += 1
-                            if frag._device is None:
-                                n_cold += 1
-                    out.append(("range", quantum, tuple(vers)))
-                    continue
-                if leaf.name in plan.NEUTRAL_LEAVES:
-                    # Slice-invariant data rows: identity is fully
-                    # captured by the canonical call string in the key.
-                    out.append(("const",))
-                    continue
-                if leaf.name == "BsiPlane":
-                    frag = self._bsi_plane_fragment(index, leaf, s)
-                else:
-                    frag, _ = self._resolve_bitmap_leaf(index, leaf, s)
-                if frag is None:
-                    out.append(None)
-                else:
-                    out.append((frag._serial, frag._version))
-                    n_frag += 1
-                    if frag._device is None:
-                        n_cold += 1
-        if with_cold:
-            return tuple(out), n_frag, n_cold
-        return tuple(out)
+        for ent in sweep:
+            if ent[0] == "const":
+                out.append(ent)
+                continue
+            lists = [ent[3]] if ent[0] == "rows" else ent[2]
+            vers = []
+            for frags in lists:
+                vers.append(
+                    tuple(
+                        None if f is None else (f._serial, f._version)
+                        for f in frags
+                    )
+                )
+                live = [f for f in frags if f is not None]
+                n_frag += len(live)
+                n_cold += sum(1 for f in live if f._device is None)
+            out.append(
+                vers[0] if ent[0] == "rows" else ("range", ent[1], tuple(vers))
+            )
+        return tuple(out), n_frag, n_cold
+
+    def _leaf_versions(self, index: str, leaves, slices: list[int]):
+        """The cache validity vector alone (see :meth:`_leaf_sweep`)."""
+        return self._sweep_versions(self._leaf_sweep(index, leaves, slices))[0]
 
     def _range_leaf_context(self, index: str, c: Call):
         """Slice-invariant validity context for one Range leaf:
@@ -2040,58 +2240,6 @@ class Executor:
             retry_fn=direct,
             host_fn=lambda: self.hosteval.count_total(index, c, kept),
         )
-
-    def _assemble_mesh_batch(self, stacks, kept_slices, mesh):
-        """Group slices by home device (slice mod n_devices, matching
-        fragment plane placement), pad per-device blocks to one
-        power-of-two chunk, and assemble the global batch shard-local
-        (parallel/mesh.assemble_sharded_batch).  Returns ``(batch,
-        pos_of)`` with ``pos_of[slice]`` the slice's row in the global
-        batch.
-
-        The chunk is sized for a BALANCED distribution (pow2 >=
-        ceil(n/n_devices)); when the queried slice set is clustered mod
-        n_devices, the overflow spills to devices with free rows (one
-        plane transfer per spilled slice) instead of inflating every
-        device's padding to the largest group — at pod scale, mostly-
-        zero compute costs more than the occasional spill copy."""
-        n_dev = int(mesh.devices.size)
-        stack_of = dict(zip(kept_slices, stacks))
-        groups, chunk = self._mesh_placement(kept_slices, n_dev)
-
-        blocks = []
-        pos_of: dict[int, int] = {}
-        for d in range(n_dev):
-            dev = mesh.devices.flat[d]
-            entries = []
-            for i, s in enumerate(groups[d]):
-                st = stack_of[s]
-                if s % n_dev != d:  # spilled here: one plane-row move
-                    st = jax.device_put(st, dev)
-                entries.append(st)
-                pos_of[s] = d * chunk + i
-            if len(entries) < chunk:
-                zero_stack = jnp.stack(
-                    [self._zero_row_on(dev)] * stacks[0].shape[0]
-                )
-                entries = entries + [zero_stack] * (chunk - len(entries))
-            blocks.append(jnp.stack(entries))
-
-        return pmesh.assemble_sharded_batch(blocks, mesh), pos_of
-
-    def _zero_row(self, slice_i: int):
-        """An all-zero leaf row on a slice's home device."""
-        return self._zero_row_on(pmesh.home_device(slice_i))
-
-    def _zero_row_on(self, dev):
-        """An all-zero leaf row committed to ``dev`` (cached per device)."""
-        z = self._zero_rows.get(dev)
-        if z is None:
-            z = jax.device_put(
-                np.zeros(bp.WORDS_PER_SLICE, dtype=np.uint32), dev
-            )
-            self._zero_rows[dev] = z
-        return z
 
     def _execute_bitmap_call(
         self, index: str, c: Call, slices: list[int], opt: ExecOptions

@@ -970,6 +970,10 @@ def note_first_call(family: str, shape: str, start: float, ms: float) -> None:
 # The TopN scorer's (``bp.score_planes``) ``first_call`` hook: its
 # programs compile outside ``_Program``.
 note_scorer_first_call = functools.partial(note_first_call, "topn.score")
+# The same hook for the leaf-batch gather (``bp.gather_planes`` and the
+# in-place writes of its outputs), under the miss's ``plan.leaves`` /
+# ``plan.transfer``.
+note_gather_first_call = functools.partial(note_first_call, "plan.gather")
 
 
 def program_cache_compile_ms() -> dict[str, float]:
@@ -1020,6 +1024,9 @@ def program_cache_stats() -> dict[str, int]:
         "bitplane.scorePlanes": (
             _jit_cache_size(bp._score_planes_self_src)
             + _jit_cache_size(bp._score_planes_host_src)
+        ),
+        "bitplane.gatherPlanes": sum(
+            _jit_cache_size(fn) for fn in bp.GATHER_PROGRAMS
         ),
         "bitplane.fusedCount": _jit_cache_size(bp._fused_count_xla),
         "bitplane.topCounts": _jit_cache_size(bp._top_counts_xla),
@@ -1099,6 +1106,25 @@ def program_cache_bounds() -> dict[str, int]:
             * bp.bucket_classes(max(hw.get("score_rows", rb), rb), rb)
             * bp.bucket_classes(max(hw.get("score_slots", rb), rb), rb)
         ),
+        # the gather: member classes (as the scorer's) x plane-row
+        # classes x leaves of a run; its in-place writes: one a (block
+        # = slice-bucket class x leaves, launch shape), and the
+        # constant column's one a block — all on each device
+        "bitplane.gatherPlanes": (
+            len(bp.participating_devices())
+            * (
+                bp.bucket_classes(max(hw.get("gather_frags", 1), 1))
+                * bp.bucket_classes(max(hw.get("gather_rows", rb), rb), rb)
+                * max(hw.get("gather_leaves", 1), 1)
+                + bp.bucket_classes(max(hw.get("place_rows", 1), 1))
+                * max(hw.get("place_leaves", 1), 1)
+                * (
+                    bp.bucket_classes(max(hw.get("gather_frags", 1), 1))
+                    * max(hw.get("place_leaves", 1), 1)
+                    + 1
+                )
+            )
+        ),
         "bitplane.topCounts": bp.bucket_classes(
             max(hw.get("top_rows", rb), rb), rb
         ),
@@ -1160,7 +1186,7 @@ def clear_program_caches() -> None:
     _COMPILE_MS.clear()
     bp._SHAPE_HIGHWATER.clear()
     bp._SCORE_SEEN.clear()
-    for fn in (
+    for fn in bp.GATHER_PROGRAMS + (
         bp._score_planes_self_src,
         bp._score_planes_host_src,
         bp._fused_count_xla,

@@ -184,14 +184,16 @@ _TOPN_SHAPES = ((1, bp.ROW_BLOCK), (1, 2 * bp.ROW_BLOCK))
 _TOPN_SHAPES_MAX = 8
 
 
-def topn_shapes(holder) -> list[tuple[int, int]]:
-    """The ``(members, plane rows)`` of the scorer programs the holder's
-    indexes will use, the views with the most fragments first: the n
-    fragments of a view that share a home device are scored
-    ``bp.score_group_bucket(n)`` members a launch whatever n is, at the
-    pow2 row class of their planes."""
+def _view_shapes(holder) -> dict[tuple[int, int, int], int]:
+    """``(members, plane rows, block rows)`` of the programs that read
+    the holder's ranked views a home device at a time, each with the
+    fragment count of the largest view that has it: the n fragments of
+    a view that share a device are read ``bp.score_group_bucket(n)``
+    members a launch whatever n is, at the pow2 row class of their
+    planes, and a leaf batch over them is a block of
+    ``plan.slice_bucket(n)`` rows."""
     n_dev = len(bp.participating_devices())
-    weight: dict[tuple[int, int], int] = {}
+    weight: dict[tuple[int, int, int], int] = {}
     for idx in holder.indexes().values():
         for frame in idx.frames().values():
             for name, view in frame.views().items():
@@ -202,11 +204,89 @@ def topn_shapes(holder) -> list[tuple[int, int]]:
                 frags = view.fragments()
                 if not frags:
                     continue
-                members = bp.score_group_bucket(-(-len(frags) // n_dev))
+                n = -(-len(frags) // n_dev)
                 for rows in {f.plane_rows() for f in frags}:
-                    key = (members, rows)
+                    key = (bp.score_group_bucket(n), rows, plan.slice_bucket(n))
                     weight[key] = max(weight.get(key, 0), len(frags))
+    return weight
+
+
+def _heaviest(weight: dict) -> list:
     return sorted(weight, key=lambda k: -weight[k])[:_TOPN_SHAPES_MAX]
+
+
+def topn_shapes(holder) -> list[tuple[int, int]]:
+    """The ``(members, plane rows)`` of the scorer programs the holder's
+    indexes will use, the views with the most fragments first."""
+    weight: dict[tuple[int, int], int] = {}
+    for (members, rows, _), w in _view_shapes(holder).items():
+        weight[members, rows] = max(weight.get((members, rows), 0), w)
+    return _heaviest(weight)
+
+
+# Leaves of the gather that is warmed from the holder's shapes: the
+# two-row set algebra under a Count, the headline query.
+_GATHER_LEAVES = 2
+
+
+def gather_shapes(holder) -> list[tuple[int, int, int, int, int]]:
+    """The ``(members, plane rows, block rows, k, leaves)`` of the
+    leaf-batch gather (``bp.gather_planes``) and of its in-place write
+    over the holder's indexes, for a tree of ``_GATHER_LEAVES`` rows of
+    one view, the views with the most fragments first."""
+    return [
+        shape + (_GATHER_LEAVES, _GATHER_LEAVES)
+        for shape in _heaviest(_view_shapes(holder))
+    ]
+
+
+def prewarm_gather(shapes=(), devices=None) -> int:
+    """Compile the leaf-batch gather of a batch-cache miss at each
+    ``(members, plane rows, block rows, k, leaves)`` of ``shapes`` —
+    ``k`` rows of one view in a tree of ``leaves`` — and the in-place
+    write of a launch's output into the block where a launch does not
+    fill it.  The jit keys hold no expression and no slice count, so
+    one warm-up serves every operator.  On each of ``devices`` (a
+    compiled program is a device's own), side by side; as
+    :func:`prewarm_topn`, the first device alone unless given."""
+    import jax
+    import jax.numpy as jnp
+
+    def warm(dev, members, rows, block_rows, k, n_leaves):
+        zero = jax.device_put(
+            np.zeros((rows, bp.WORDS_PER_SLICE), dtype=np.uint32), dev
+        )
+        out = next(
+            bp.gather_planes(
+                [zero] * members,
+                np.zeros((members, k), dtype=np.int32),
+                first_call=plan.note_gather_first_call,
+            )
+        )
+        if (block_rows, n_leaves) != (members, k):
+            out = bp.place_rows(
+                jnp.zeros(
+                    (block_rows, n_leaves, bp.WORDS_PER_SLICE),
+                    dtype=jnp.uint32,
+                    device=dev,
+                ),
+                out,
+                0,
+                first_call=plan.note_gather_first_call,
+            )
+        out.block_until_ready()
+
+    todo = [
+        (dev,) + tuple(shape)
+        for shape in shapes
+        for dev in (devices or (bp.home_device(0),))
+    ]
+    threads = [threading.Thread(target=warm, args=t, daemon=True) for t in todo]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    return len(todo)
 
 
 def prewarm_topn(shapes=_TOPN_SHAPES) -> int:
@@ -242,11 +322,13 @@ def prewarm_topn(shapes=_TOPN_SHAPES) -> int:
 
 
 def prewarm(
-    buckets=(1, 2, 4, 8), exprs=_STANDARD_EXPRS, coalesce=False, topn=()
+    buckets=(1, 2, 4, 8), exprs=_STANDARD_EXPRS, coalesce=False, topn=(),
+    gather=(),
 ) -> int:
-    """Compile the standard (tree shape x slice bucket) programs, and
-    the TopN scorer at its standard shapes and at ``topn`` (the
-    ``(members, plane rows)`` of :func:`topn_shapes`).
+    """Compile the standard (tree shape x slice bucket) programs, the
+    TopN scorer at its standard shapes and at ``topn`` (the ``(members,
+    plane rows)`` of :func:`topn_shapes`), and the leaf-batch gather at
+    ``gather`` (:func:`gather_shapes`).
 
     Triggers real compilations by calling each program on a zero batch
     of the bucketed shape — with the persistent cache enabled this both
@@ -296,13 +378,16 @@ def prewarm(
     warmed += prewarm_topn(
         list(_TOPN_SHAPES) + [k for k in topn if k not in _TOPN_SHAPES]
     )
+    warmed += prewarm_gather(gather)
     if coalesce:
         warmed += prewarm_coalesce()
         warmed += prewarm_fuse()
     return warmed
 
 
-def prewarm_async(logger=None, coalesce=False, topn=()) -> threading.Thread:
+def prewarm_async(
+    logger=None, coalesce=False, topn=(), gather=()
+) -> threading.Thread:
     """Run :func:`prewarm` on a daemon thread (server open must not
     block on compiles) and return the thread, which carries the
     outcome once it ends: ``programs`` (the count compiled) or
@@ -311,7 +396,7 @@ def prewarm_async(logger=None, coalesce=False, topn=()) -> threading.Thread:
 
     def run():
         try:
-            t.programs = prewarm(coalesce=coalesce, topn=topn)
+            t.programs = prewarm(coalesce=coalesce, topn=topn, gather=gather)
         except Exception as e:  # noqa: BLE001 — recorded, not swallowed
             t.error = e
             if logger is not None:

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import functools
 import os
+import threading
 import time
 
 import jax
@@ -711,11 +712,29 @@ def score_group_bucket(n_frags: int) -> int:
     return min(pow2_bucket(n_frags), SCORE_GROUP)
 
 
-# Program shapes the scorer has been called with: a shape's first call
-# traces and compiles (or loads) its program, and ``score_planes`` tells
-# its caller so.  Plain set writes (no lock): a racing duplicate first
-# call reports a few ms twice, never corrupts.
+# Program shapes the scorer and the leaf-batch gather have been called
+# with: a shape's first call traces and compiles (or loads) its program,
+# one thread at a time, and its caller is told so (``_first_call``).
 _SCORE_SEEN: set = set()
+_FIRST_CALL_MU = threading.Lock()
+
+
+def _first_call(fn, shape: tuple, first_call, *args):
+    """``fn(*args)``; a program shape's first call is made one thread
+    at a time (eight requests that arrive together would otherwise each
+    trace and compile the same program) and told to ``first_call``:
+    the shape as text, its wall-clock start and how long it took."""
+    if shape in _SCORE_SEEN:
+        return fn(*args)
+    with _FIRST_CALL_MU:
+        if shape in _SCORE_SEEN:
+            return fn(*args)
+        start, t0 = time.time(), time.monotonic()
+        out = fn(*args)
+        _SCORE_SEEN.add(shape)
+    if first_call is not None:
+        first_call(str(shape), start, (time.monotonic() - t0) * 1e3)
+    return out
 
 
 def score_planes(planes, slots, src_slots=None, srcs=None, first_call=None) -> list:
@@ -773,13 +792,129 @@ def score_planes(planes, slots, src_slots=None, srcs=None, first_call=None) -> l
     for lo in range(0, n, bucket):
         idx = np.minimum(np.arange(lo, lo + bucket), n - 1)
         group = tuple(planes[i] for i in idx)
-        start, t0 = time.time(), time.monotonic()
-        outs.append(fn(group, slots[idx], src[idx]))
-        if shape not in _SCORE_SEEN:
-            _SCORE_SEEN.add(shape)
-            if first_call is not None:
-                first_call(str(shape), start, (time.monotonic() - t0) * 1e3)
+        outs.append(
+            _first_call(fn, shape, first_call, group, slots[idx], src[idx])
+        )
     return outs
+
+
+@jax.jit
+def _gather_planes_xla(planes, table, i):
+    sl = table[i]
+    rows = [
+        jax.lax.dynamic_slice_in_dim(
+            planes[f], jnp.maximum(sl[f, j], 0), 1, axis=0
+        )
+        for f in range(len(planes))
+        for j in range(sl.shape[1])
+    ]
+    out = jnp.concatenate(rows).reshape(len(planes), sl.shape[1], -1)
+    return jnp.where((sl >= 0)[:, :, None], out, jnp.uint32(0))
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _place_rows_xla(block, rows, row0, col0):
+    return jax.lax.dynamic_update_slice(block, rows, (row0, col0, 0))
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _place_const_xla(block, row, n, col):
+    live = jnp.arange(block.shape[0], dtype=jnp.int32)[:, None, None] < n
+    column = jnp.where(live, row[None, None, :], jnp.uint32(0))
+    return jax.lax.dynamic_update_slice(block, column, (0, col, 0))
+
+
+GATHER_PROGRAMS = (_gather_planes_xla, _place_rows_xla, _place_const_xla)
+
+# Launches one slot table serves (GATHER_TABLE x members bucket members):
+# the table's shape is in the gather's jit key, so it is one size
+# whatever the number of fragments, and a larger set takes more tables.
+GATHER_TABLE = 16
+
+@functools.lru_cache(maxsize=8192)
+def _device_int(value: int, dev):
+    """``value`` as an int32 scalar resident on ``dev``.  A launch's row
+    and column offsets are operands; handed over as host numbers each is
+    a host->device transfer of its own, and four of them a launch were
+    most of what a launch cost the host (PERF.md, PR 31)."""
+    return jax.device_put(np.int32(value), dev)
+
+
+def _device_of(arr):
+    return next(iter(arr.devices()))
+
+
+def gather_planes(planes, slots, first_call=None):
+    """Leaf rows of many fragments, gathered on the device from their
+    HBM-resident plane mirrors: the leaf batch of a query, with no host
+    copy of a row and no transfer but the slots.
+
+    ``planes``: sequence of uint32[plane_rows, words] mirror SNAPSHOTS
+    of one shape on ONE device, a member each; ``slots``: int[n, k], the
+    slot of each of a member's ``k`` rows in its plane, negative for a
+    row the fragment does not hold: that row gathers zeros (a full
+    plane has no spare zero slot to point at).
+
+    As ``score_planes``: ``score_group_bucket(n)`` members a launch,
+    every launch dispatched without waiting, the last one padded by
+    repeating its last member with no row valid, so its surplus rows
+    are zeros.  The slots go to the device once, as a table of
+    GATHER_TABLE launches, and a launch is told its place in it by a
+    resident scalar: its operands cross no host boundary.  Yields the
+    launches' device arrays, uint32[bucket, k, words] each, in member
+    order, each as it is dispatched: a caller that consumes one before
+    asking for the next keeps one output alive, not all.  The jit key
+    is (members bucket, plane shape, k, device) — never the expression,
+    never the number of fragments."""
+    n = len(planes)
+    bucket = score_group_bucket(n)
+    slots = np.asarray(slots, dtype=np.int32).reshape(n, -1)
+    k = int(slots.shape[1])
+    _note_shape(
+        gather_frags=bucket, gather_rows=int(planes[0].shape[0]), gather_leaves=k
+    )
+    dev = _device_of(planes[0])
+    shape = ("gather", bucket, tuple(planes[0].shape), k, str(dev))
+    per_table = GATHER_TABLE * bucket
+    for t0 in range(0, n, per_table):
+        table = np.full((per_table, k), -1, dtype=np.int32)
+        table[: min(n - t0, per_table)] = slots[t0 : t0 + per_table]
+        table = jax.device_put(table.reshape(GATHER_TABLE, bucket, k), dev)
+        for i, lo in enumerate(range(t0, min(n, t0 + per_table), bucket)):
+            group = tuple(planes[min(m, n - 1)] for m in range(lo, lo + bucket))
+            yield _first_call(
+                _gather_planes_xla, shape, first_call,
+                group, table, _device_int(i, dev),
+            )
+
+
+def place_rows(block, rows, row0: int, col0: int = 0, first_call=None):
+    """``block`` with ``rows`` (uint32[m, k, words], on the block's
+    device) written at ``[row0 : row0 + m, col0 : col0 + k]``, in place:
+    ``block`` is donated and must not be used again.  The caller keeps
+    the write inside the block (XLA would shift a write that does not
+    fit).  Keyed by the two shapes and the device; the offsets are
+    operands."""
+    _note_shape(place_rows=int(block.shape[0]), place_leaves=int(block.shape[1]))
+    dev = _device_of(block)
+    shape = ("place", tuple(block.shape), tuple(rows.shape), str(dev))
+    return _first_call(
+        _place_rows_xla, shape, first_call,
+        block, rows, _device_int(int(row0), dev), _device_int(int(col0), dev),
+    )
+
+
+def place_const(block, row, n: int, col: int, first_call=None):
+    """``block`` with the one row ``row`` (uint32[words], host or
+    device) written into column ``col`` of its first ``n`` members and
+    zeros into that column of the rest, in place (``block`` is
+    donated): a slice-invariant leaf, such as a BSI predicate row."""
+    dev = _device_of(block)
+    shape = ("const", tuple(block.shape), str(dev))
+    return _first_call(
+        _place_const_xla, shape, first_call,
+        block, row, _device_int(int(n), dev), _device_int(int(col), dev),
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

@@ -14,7 +14,8 @@ boundaries (via :func:`check` calls compiled into the hot paths):
   ``direct`` (executor direct launch), ``coalesce`` (a coalesced
   launch's waiter), ``collective`` (inside a mesh psum dispatch+fetch,
   where the launch watchdog can observe a hang), ``topn`` (the fused
-  TopN scorer) — and the check fires once per PARTICIPATING DEVICE with
+  TopN scorer), ``gather`` (the leaf-batch gather of a batch-cache
+  miss, which then fills on the host) — and the check fires once per PARTICIPATING DEVICE with
   ``device`` = its ordinal, so a ``device=`` rule can target ONE device
   of a mesh;
 * ``gossip.send`` — in ``GossipNodeSet._send``, before each UDP
@@ -35,7 +36,7 @@ stage):
 
 * ``path``  — fnmatch glob against the request path (no query string);
   for ``device.launch``, the launch site (``direct`` / ``coalesce`` /
-  ``collective`` / ``topn``)
+  ``collective`` / ``topn`` / ``gather``)
 * ``host``  — exact ``host:port`` (the TARGET host for rpc.send, the
   SERVING node for rpc.recv and device.launch)
 * ``device``— device ordinal (``device.launch`` only): fire only when

@@ -523,20 +523,22 @@ def test_batch_cache_hit_and_invalidation(ex, holder, monkeypatch):
     must_set_bits(holder, "i", "f", [(1, 3), (1, SLICE_WIDTH + 7), (2, 3)])
 
     gathers = []
-    orig_dev = Executor._gather_leaf_stacks
+    orig_dev = Executor._assemble_gather_batch
     orig_host = Executor._assemble_mesh_batch_host
 
-    def spy_dev(self, index, c, slices):
-        gathers.append(str(c))
-        return orig_dev(self, index, c, slices)
+    def spy_dev(self, leaves, slices, sweep, mesh):
+        built = orig_dev(self, leaves, slices, sweep, mesh)
+        if built is not None:
+            gathers.append("device")
+        return built
 
     def spy_host(self, index, leaves, slices, mesh):
         gathers.append("host")
         return orig_host(self, index, leaves, slices, mesh)
 
-    # Assembly has two entry points (device gather for warm mirrors,
-    # host blocks for cold fragments); the cache must avoid BOTH.
-    monkeypatch.setattr(Executor, "_gather_leaf_stacks", spy_dev)
+    # Assembly has two entry points (the plane gather for resident
+    # mirrors, host blocks for cold fragments); the cache must avoid BOTH.
+    monkeypatch.setattr(Executor, "_assemble_gather_batch", spy_dev)
     monkeypatch.setattr(Executor, "_assemble_mesh_batch_host", spy_host)
 
     pql = "Count(Intersect(Bitmap(rowID=1, frame=f), Bitmap(rowID=2, frame=f)))"

@@ -12,6 +12,7 @@ the interpreter program-cache entries flat as mix diversity grows.
 """
 
 import concurrent.futures
+import math
 import threading
 
 import jax.numpy as jnp
@@ -371,8 +372,9 @@ def test_fuse_oversized_tree_falls_back_to_coalesce(rng):
 
 
 def test_fuse_scratch_budget_splits_then_falls_back(rng, monkeypatch):
-    """MAX_FUSE_BYTES bounds the interpreter's register file (batch rows
-    x (leaf bucket + op bucket) slice-rows): a program set past it
+    """MAX_FUSE_BYTES bounds the interpreter's scratch: FUSE_SCRATCH_FACTOR
+    register files (batch rows x (leaf bucket + op bucket) slice-rows),
+    which is what the chip's compiler allots it: a program set past it
     splits into further fused launches, and a pair that still does not
     fit rides the concat path — same answers either way."""
     from pilosa_tpu.exec import coalesce as coalesce_mod
@@ -411,8 +413,9 @@ def test_fuse_scratch_budget_splits_then_falls_back(rng, monkeypatch):
     # rows x (8 + 8) slice-rows of registers; two programs over 4
     # leaves hold rows x (4 + 8).
     per_device = rows // len(jnp.zeros(1).devices())
-    whole = per_device * (8 + 8) * words * 4
-    halved = per_device * (4 + 8) * words * 4
+    factor = coalesce_mod.FUSE_SCRATCH_FACTOR
+    whole = math.ceil(factor * per_device * (8 + 8) * words * 4)
+    halved = math.ceil(factor * per_device * (4 + 8) * words * 4)
     snap = storm(whole)
     assert snap["fused_launches"] == 1 and snap["fused_queries"] == 4
     snap = storm(halved)

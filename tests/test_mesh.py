@@ -273,7 +273,7 @@ class TestShardedExecutor:
 
 
     def test_cold_and_warm_assembly_identical(self, tmp_path):
-        """The cold (host-blocks) and warm (device-gather) mesh batch
+        """The cold (host-blocks) and warm (plane-gather) mesh batch
         assemblers share one placement helper and MUST produce identical
         pos_of layouts and batch contents for the same slice set — they
         are interchangeable producers for the same batch cache."""
@@ -294,8 +294,11 @@ class TestShardedExecutor:
         cold_batch, cold_pos, cold_kept, cold_emp = (
             ex._assemble_mesh_batch_host("i", leaves, slices, mesh)
         )
-        expr, stacks, kept, emp = ex._gather_leaf_stacks("i", call, slices)
-        warm_batch, warm_pos = ex._assemble_mesh_batch(stacks, kept, mesh)
+        for frag in h.view("i", "f", "standard").fragments():
+            frag.device_plane()
+        warm_batch, warm_pos, kept, emp = ex._assemble_gather_batch(
+            leaves, slices, ex._leaf_sweep("i", leaves, slices), mesh
+        )
 
         assert cold_kept == kept and cold_emp == emp
         assert cold_pos == warm_pos

@@ -273,6 +273,68 @@ def test_the_scorer_compiles_for_the_chip_at_the_cells_own_width(one_v5e_chip):
     assert mem.temp_size_in_bytes < mem.argument_size_in_bytes // 8
 
 
+def test_the_leaf_batch_gather_compiles_for_the_chip_at_the_cells_own_width(
+    one_v5e_chip,
+):
+    """What a Count that misses the batch cache launches in
+    ``segment-1b.count-distinct``: the gather of two rows from each of
+    SCORE_GROUP plane mirrors, and the write of a launch's output into the
+    1024-row block, in place.  (Here beside the scorer's: the tests that
+    describe the chip stay in one file.)"""
+    import jax
+    import jax.numpy as jnp
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e_chip)
+
+    row = bp.WORDS_PER_SLICE * 4
+    planes = tuple(shape((64, bp.WORDS_PER_SLICE), jnp.uint32) for _ in range(G))
+    mem = bp._gather_planes_xla.lower(
+        planes, shape((bp.GATHER_TABLE, G, 2), jnp.int32), shape((), jnp.int32)
+    ).compile().memory_analysis()
+    assert mem.output_size_in_bytes == G * 2 * row
+    # the mirrors are operands, read where they lie: no stacked copy
+    assert mem.temp_size_in_bytes < G * 2 * row
+
+    block = 1024 * 2 * row
+    mem = bp._place_rows_xla.lower(
+        shape((1024, 2, bp.WORDS_PER_SLICE), jnp.uint32),
+        shape((G, 2, bp.WORDS_PER_SLICE), jnp.uint32),
+        shape((), jnp.int32), shape((), jnp.int32),
+    ).compile().memory_analysis()
+    # the donated block is the output: no second 256 MiB
+    assert mem.alias_size_in_bytes == block == mem.output_size_in_bytes
+    assert mem.temp_size_in_bytes < G * 2 * row
+
+
+@pytest.mark.parametrize("leaves,ops", [(4, 8), (8, 16)])
+def test_the_fused_interpreters_scratch_is_what_its_budget_counts(
+    one_v5e_chip, leaves, ops
+):
+    """``coalesce.FUSE_SCRATCH_FACTOR`` register files bound the temp the
+    chip's compiler allots the interpreter at the cells' 1024 batch rows:
+    counted as one, launches of 5-8 GiB passed a 2 GiB budget."""
+    import jax
+    import jax.numpy as jnp
+
+    from pilosa_tpu.exec import coalesce
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e_chip)
+
+    mem = plan._compiled_interp("count").fn.lower(
+        shape((1024, leaves, bp.WORDS_PER_SLICE), jnp.uint32),
+        shape((ops, 4), jnp.int32), shape((4,), jnp.int32),
+    ).compile().memory_analysis()
+    register_file = 1024 * (leaves + ops) * bp.WORDS_PER_SLICE * 4
+    counted = coalesce.FUSE_SCRATCH_FACTOR * register_file
+    assert register_file < mem.temp_size_in_bytes <= counted
+    # so at this size nothing fuses on one chip; a quarter of the rows (a
+    # device's share on four) does while the program is small
+    assert counted > coalesce.MAX_FUSE_BYTES
+    assert (counted / 4 < coalesce.MAX_FUSE_BYTES) == (leaves + ops == 12)
+
+
 # ---------------------------------------------------------------------------
 # (c) one accounting of a plane
 # ---------------------------------------------------------------------------

@@ -208,9 +208,20 @@ def _populate(s, slices):
         f.set_bit("standard", 1, sl * SLICE_WIDTH + 9)
 
 
+def _stage_mirrors(s):
+    """The plane mirrors resident, as on a node that has served: a miss
+    then gathers its rows on the device."""
+    for frag in s.holder.view("i", "f", "standard").fragments():
+        frag.device_plane()
+
+
 def _last_trace(c):
     _status, data = c._request("GET", "/debug/traces")
     return json.loads(data)["traces"][-1]
+
+
+def _span(t, name):
+    return next(s for s in t["spans"] if s["name"] == name)
 
 
 def _children(t, name):
@@ -224,6 +235,7 @@ UNION = 'Count(Union(Bitmap(frame="f", rowID=1), Bitmap(frame="f", rowID=2)))'
 
 def test_the_stages_of_a_served_count(server):
     _populate(server, 3)
+    _stage_mirrors(server)
     c = InternalClient(server.host, timeout=60.0)
     assert c.execute_pql("i", INTERSECT) == 3
     miss = _last_trace(c)
@@ -244,9 +256,9 @@ def test_the_stages_of_a_served_count(server):
     by_name = {s["name"]: s for s in stages}
     assert by_name["plan.resolve"]["tags"]["fragments"] == 6
     leaves = by_name["plan.leaves"]["tags"]
-    assert leaves["path"] in ("host_fill", "mesh_host_fill", "device_gather")
+    assert leaves["path"] == "plane_gather"
     assert leaves["rows"] == 6
-    assert (leaves["device_copies"] > 0) == (leaves["path"] == "device_gather")
+    assert leaves["device_copies"] == leaves["launches"] > 0
     assert by_name["plan.transfer"]["tags"]["bytes"] > 0
     assert by_name["plan.transfer"]["tags"]["devices"] >= 1
     assert by_name["plan.register"]["tags"]["displaced"] == 0
@@ -271,6 +283,77 @@ def test_the_stages_of_a_served_count(server):
                            "anchors_scanned": 0}
 
 
+def test_a_miss_over_cold_planes_fills_on_the_host(server, monkeypatch):
+    _populate(server, 3)
+    # (the prefetcher uploads a cold leaf's mirror as the query starts; held
+    # back here, or on a slow day half of them are up before the sweep)
+    monkeypatch.setattr(server.executor.prefetcher, "prefetch", lambda frags: 0)
+    c = InternalClient(server.host, timeout=60.0)
+    assert c.execute_pql("i", INTERSECT) == 3
+    stages = {s["name"]: s for s in _children(_last_trace(c), "plan")}
+    assert tuple(stages) == PLAN_STAGES
+    # the mirrors are on their way up (the prefetcher's doing, here held
+    # back): the next miss will gather, and its programs compile beside
+    # this fill, on a thread no request waits for
+    server.executor._gather_warming.join(timeout=120)
+    assert plan.program_cache_stats()["bitplane.gatherPlanes"] > 0
+    assert stages["plan.resolve"]["tags"] == {"fragments": 6, "cold": 6}
+    leaves = stages["plan.leaves"]["tags"]
+    assert leaves["path"] in ("host_fill", "mesh_host_fill")
+    assert leaves["rows"] == 6 and leaves["device_copies"] == 0
+
+
+MISS_SPANS = sorted([
+    "query", "parse", "admission", "execute", "call.Count", "map.local",
+    "anchored.prepass", "plan", *PLAN_STAGES, "coalesce", "launch"])
+
+
+@pytest.mark.parametrize("slices", [2, 70])
+def test_the_gather_of_a_miss_has_the_same_spans_at_any_slice_count(
+    one_chip, server, slices
+):
+    """``plan.leaves`` (the slot sweep, the ceil(slices / 64) launches of
+    the gather and their writes into the block) and ``plan.transfer``
+    (what is left of the assembly), a ``compile`` for a program shape's
+    first call, and as many spans at 70 slices as at 2."""
+    _populate(server, slices)
+    _stage_mirrors(server)
+    c = InternalClient(server.host, timeout=120.0)
+    assert c.execute_pql("i", UNION) == 2 * slices  # the count program
+    plan.clear_program_caches()
+    entries = plan.program_cache_stats()["bitplane.gatherPlanes"]
+    assert entries == 0
+    assert c.execute_pql("i", INTERSECT) == slices
+    first = _last_trace(c)
+    launches = -(-slices // bp.SCORE_GROUP)
+    leaves = _span(first, "plan.leaves")
+    assert leaves["tags"] == {"path": "plane_gather", "rows": 2 * slices,
+                              "launches": launches, "device_copies": launches}
+    transfer = _span(first, "plan.transfer")["tags"]
+    assert transfer["devices"] == 1 and transfer["spilled"] == 0
+    assert transfer["bytes"] == plan.slice_bucket(slices) * 2 * bp.WORDS_PER_SLICE * 4
+    # one gather program whatever the launches; where a launch does not
+    # fill the block, one more writes it there
+    compiled = [s for s in first["spans"] if s["name"] == "compile"
+                and s["tags"]["family"] == "plan.gather"]
+    assert [s["parent_id"] for s in compiled] == (
+        [leaves["span_id"]] * (1 if launches == 1 else 2))
+    stats, bounds = plan.program_cache_stats(), plan.program_cache_bounds()
+    assert stats["bitplane.gatherPlanes"] == len(compiled)
+    assert stats["bitplane.gatherPlanes"] <= bounds["bitplane.gatherPlanes"]
+    assert plan.program_cache_compile_ms()["plan.gather"] >= sum(
+        s["duration_ms"] for s in compiled)
+
+    # another operator over other rows: the programs that are there
+    assert c.execute_pql("i", INTERSECT.replace("Intersect", "Xor")) == slices
+    other = _last_trace(c)
+    assert plan.program_cache_stats()["bitplane.gatherPlanes"] == len(compiled)
+    assert sorted(s["name"] for s in other["spans"]
+                  if s["name"] != "compile") == MISS_SPANS
+    assert not [s for s in other["spans"] if s["name"] == "compile"
+                and s["tags"]["family"] == "plan.gather"]
+
+
 @pytest.mark.parametrize("slices", [2, 40])
 def test_no_span_sits_in_a_loop_over_slices(server, slices):
     _populate(server, slices)
@@ -283,9 +366,6 @@ def test_no_span_sits_in_a_loop_over_slices(server, slices):
 
 TOPN_SRC = 'TopN(Bitmap(frame="f", rowID=1), frame="f", n=10)'
 
-
-def _span(t, name):
-    return next(s for s in t["spans"] if s["name"] == name)
 
 
 @pytest.mark.parametrize("slices", [2, 70])
