@@ -5,7 +5,7 @@
 
 PYTHON ?= python
 
-.PHONY: default test lint analyze typecheck metrics-lint check bench bench-smoke chaos-smoke device-chaos-smoke load-smoke resize-smoke multichip-smoke tier-smoke replication-smoke subscribe-smoke ingest-smoke ingest-bench sparse-smoke sparse-bench churn-soak gameday gameday-smoke install build docker clean generate
+.PHONY: default test lint analyze typecheck metrics-lint check chaos-smoke device-chaos-smoke load-smoke resize-smoke multichip-smoke tier-smoke replication-smoke subscribe-smoke ingest-smoke sparse-smoke churn-soak gameday gameday-smoke install build docker clean generate
 
 default: build test
 
@@ -63,18 +63,6 @@ build:
 
 install:
 	$(PYTHON) -m pip install .
-
-# One JSON line on stdout; tiers and progress on stderr.  Uses the
-# accelerator when one is reachable, else re-execs onto the CPU backend.
-bench:
-	$(PYTHON) bench.py
-
-# Tiny CPU-only bench pass (seconds, few slices): asserts the JSON
-# artifact parses with the coalesce counters, the cold_restart tier,
-# and the program-cache bounds invariant.  BLOCKING in CI
-# (.github/workflows/check.yml).
-bench-smoke:
-	$(PYTHON) tools/bench_smoke.py
 
 # Tiny CPU chaos pass: two in-process nodes under PILOSA_FAULTS (one
 # erroring + one delayed RPC leg); a fan-out query must still answer
@@ -167,23 +155,6 @@ ingest-smoke:
 # (.github/workflows/check.yml), like subscribe-smoke.
 sparse-smoke:
 	$(PYTHON) tools/sparse_smoke.py
-
-# Sparse bench tier standalone (tools/sparse_bench.py): effective
-# Gcols/s + bytes read + format mix + resident ratio over 50%/5%/1%/
-# 0.1% density corpora with a byte-identity storm vs the forced-dense
-# arm.  One JSON line on stdout; also runs inside make bench (bench.py
-# "sparse" tier) and is asserted by bench-smoke.
-sparse-bench:
-	$(PYTHON) tools/sparse_bench.py
-
-# Ingest bench tier standalone (tools/ingest_bench.py): durable acked
-# write throughput with group commit on/off vs the WAL-off baseline,
-# read p99 under a 50/50 read/write storm vs read-only, and mirror
-# re-stage bytes with delta-scatter on/off.  One JSON line on stdout;
-# also runs inside make bench (bench.py "ingest" tier) and is asserted
-# by bench-smoke.
-ingest-bench:
-	$(PYTHON) tools/ingest_bench.py
 
 # The everything-at-once soak (tools/gameday.py): one seeded run
 # composing every failure mode the stack claims to survive — a

@@ -114,7 +114,6 @@ def build_server(cfg: config_mod.Config):
         latency_buckets_ms=(cfg.obs.latency_buckets_ms or None),
         slo_ms=cfg.obs.slo_ms,
         slo_objective=cfg.obs.slo_objective,
-        floor_probe=cfg.obs.floor_probe,
         mesh_devices=cfg.device.mesh_devices,
         hbm_budget_bytes=cfg.device.hbm_budget_bytes,
         device_prefetch=cfg.device.prefetch,

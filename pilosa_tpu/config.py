@@ -63,7 +63,6 @@ _KNOWN_KEYS = {
     "obs.latency-buckets-ms",
     "obs.slo-ms",
     "obs.slo-objective",
-    "obs.floor-probe",
     "net",
     "net.stream-chunk-bytes",
     "net.query-timeout-ms",
@@ -203,10 +202,6 @@ class ObsConfig:
     # SLO objective (fraction of queries that must land under slo-ms);
     # burn rate = windowed error rate / (1 - objective).
     slo_objective: float = 0.999
-    # One-shot per-device stream-floor probe at server open — anchors
-    # the /debug/perf roofline percentages.  Cached under the data dir,
-    # so restarts skip the measurement.
-    floor_probe: bool = True
 
 
 @dataclass
@@ -694,7 +689,6 @@ class Config:
             + "]",
             f"  slo-ms = {self.obs.slo_ms}",
             f"  slo-objective = {self.obs.slo_objective}",
-            f"  floor-probe = {str(self.obs.floor_probe).lower()}",
             "",
             "[net]",
             f"  stream-chunk-bytes = {self.net.stream_chunk_bytes}",
@@ -864,7 +858,6 @@ def from_toml(text: str) -> Config:
     cfg.obs.slo_objective = float(
         ob.get("slo-objective", cfg.obs.slo_objective)
     )
-    cfg.obs.floor_probe = ob.get("floor-probe", cfg.obs.floor_probe)
     nt = doc.get("net", {})
     cfg.net.stream_chunk_bytes = nt.get(
         "stream-chunk-bytes", cfg.net.stream_chunk_bytes
@@ -1058,7 +1051,6 @@ _ENV_MAP = {
     ),
     "PILOSA_OBS_SLO_MS": ("obs.slo_ms", float),
     "PILOSA_OBS_SLO_OBJECTIVE": ("obs.slo_objective", float),
-    "PILOSA_OBS_FLOOR_PROBE": ("obs.floor_probe", "bool"),
     "PILOSA_NET_STREAM_CHUNK_BYTES": ("net.stream_chunk_bytes", int),
     "PILOSA_NET_QUERY_TIMEOUT_MS": ("net.query_timeout_ms", float),
     "PILOSA_NET_BROADCAST_TIMEOUT_MS": ("net.broadcast_timeout_ms", float),

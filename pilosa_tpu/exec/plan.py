@@ -1054,6 +1054,9 @@ def program_cache_bounds() -> dict[str, int]:
 
     hw = bp.shape_highwater()
     rb = bp.ROW_BLOCK
+    # A program over planes that live on their slice's home device
+    # compiles one executable a device.
+    n_dev = bp.mesh_device_count()
 
     def slice_classes(family: str) -> int:
         return bp.bucket_classes(max(_BUCKET_HIGHWATER.get(family, 1), 1))
@@ -1084,9 +1087,10 @@ def program_cache_bounds() -> dict[str, int]:
         # one wrapper x update-count bucket classes (floor
         # ingest.scatter.UPDATE_BUCKET_FLOOR) x plane-row shape classes
         # (planes pad rows to pow2, floor ROW_BLOCK; the word axis is
-        # uniform, so it contributes no classes)
+        # uniform, so it contributes no classes) — on each device
         "plan.scatter": (
             _compiled_scatter.cache_info().currsize
+            * n_dev
             * bp.bucket_classes(
                 max(_BUCKET_HIGHWATER.get("plan.scatter", _scatter_floor()),
                     _scatter_floor()),
@@ -1099,9 +1103,10 @@ def program_cache_bounds() -> dict[str, int]:
         # (self-src + host-src) x fragment-group classes (at most
         # log2(bp.SCORE_GROUP) + 1 whatever the slice count: larger
         # groups relaunch the SCORE_GROUP program) x plane-row classes
-        # x candidate-slot classes
+        # x candidate-slot classes — on each device
         "bitplane.scorePlanes": (
             2
+            * n_dev
             * bp.bucket_classes(max(hw.get("score_frags", 1), 1))
             * bp.bucket_classes(max(hw.get("score_rows", rb), rb), rb)
             * bp.bucket_classes(max(hw.get("score_slots", rb), rb), rb)
@@ -1111,7 +1116,7 @@ def program_cache_bounds() -> dict[str, int]:
         # = slice-bucket class x leaves, launch shape), and the
         # constant column's one a block — all on each device
         "bitplane.gatherPlanes": (
-            len(bp.participating_devices())
+            n_dev
             * (
                 bp.bucket_classes(max(hw.get("gather_frags", 1), 1))
                 * bp.bucket_classes(max(hw.get("gather_rows", rb), rb), rb)
@@ -1125,7 +1130,7 @@ def program_cache_bounds() -> dict[str, int]:
                 )
             )
         ),
-        "bitplane.topCounts": bp.bucket_classes(
+        "bitplane.topCounts": n_dev * bp.bucket_classes(
             max(hw.get("top_rows", rb), rb), rb
         ),
         # (tree shape x container-format tuple) wrappers x slice-bucket
@@ -1152,8 +1157,9 @@ def program_cache_bounds() -> dict[str, int]:
             # payload axes.
             ** (max(_ANCHORED_HIGHWATER.get("leaves", 1), 1) + 1)
         ),
-        # (sparse + rle) expansion wrappers x payload bucket classes
-        "bitplane.expand": 2 * bp.bucket_classes(
+        # (sparse + rle) expansion wrappers x payload bucket classes,
+        # on each device
+        "bitplane.expand": 2 * n_dev * bp.bucket_classes(
             max(
                 hw.get("expand_payload", bp.PAYLOAD_BUCKET_FLOOR),
                 bp.PAYLOAD_BUCKET_FLOOR,

@@ -1970,8 +1970,8 @@ class Handler:
                 snap.setdefault("gauges", {}).update(self.subscribe.gauges())
             except Exception:  # noqa: BLE001 — stats must not fail the scrape
                 pass
-        # Scrape-time launch-telemetry gauges (per-site GB/s, % of the
-        # probed stream floor) — injected like the program-cache ones.
+        # Scrape-time launch-telemetry gauges (per-site launches, bytes,
+        # GB/s) — injected like the program-cache ones.
         try:
             snap.setdefault("gauges", {}).update(perf_mod.registry().gauges())
         except Exception:  # noqa: BLE001 — stats must not fail the scrape
@@ -2045,9 +2045,9 @@ class Handler:
             pass
 
     def handle_get_perf(self, req: Request) -> Response:
-        """The launch-telemetry roofline table (obs/perf.py): per-site
-        launches, logical bytes streamed, achieved GB/s, % of the
-        probed stream floor, p50/p99 launch ms, batch occupancy — plus
+        """The launch-telemetry table (obs/perf.py): per-site
+        launches, logical bytes streamed, host-clock GB/s, p50/p99
+        launch ms, batch occupancy — plus
         the slowest recent launches with their trace ids (feed one to
         ``/debug/traces`` for the full span breakdown) and cumulative
         per-family compile ms."""

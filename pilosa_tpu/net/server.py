@@ -119,7 +119,6 @@ class Server:
         latency_buckets_ms=None,
         slo_ms: float = 0.0,
         slo_objective: float = 0.999,
-        floor_probe: bool = True,
     ):
         self.data_dir = data_dir
         self.host = host
@@ -322,15 +321,12 @@ class Server:
         self.ingest_scatter = ingest_scatter
         self.ingest_wal_segment_bytes = ingest_wal_segment_bytes
         self.ingest = None
-        # Performance observability ([obs] latency-buckets-ms / slo-* /
-        # floor-probe, obs/perf.py + device/floorprobe.py): native
-        # fixed-bucket latency histograms + SLO burn gauges live on the
-        # Handler; the one-shot stream-floor probe runs at open() and
-        # anchors the /debug/perf roofline denominators.
+        # Performance observability ([obs] latency-buckets-ms / slo-*,
+        # obs/perf.py): native fixed-bucket latency histograms + SLO
+        # burn gauges live on the Handler.
         self.latency_buckets_ms = latency_buckets_ms
         self.slo_ms = slo_ms
         self.slo_objective = slo_objective
-        self.floor_probe = floor_probe
         self.executor: Executor | None = None
         self.handler: Handler | None = None
         self._http = None
@@ -418,21 +414,6 @@ class Server:
             "hbm budget: "
             + (f"{budget} bytes per device" if budget else "unbounded")
         )
-        # One-shot stream-floor probe ([obs] floor-probe): measures
-        # per-device achievable streaming GB/s (cached process-wide AND
-        # under the data dir, so restarts and in-process multi-server
-        # tests pay it once) and anchors every %-of-floor figure the
-        # /debug/perf roofline table reports.
-        if self.floor_probe:
-            from pilosa_tpu.device import floorprobe
-            from pilosa_tpu.obs import perf as perf_mod
-
-            fp = floorprobe.probe(
-                artifact_dir=self.data_dir,
-                stats=self.stats,
-                logger=self.logger,
-            )
-            perf_mod.registry().set_floor(fp["mean_gbps"])
         # Cold-start elimination (see exec/warmup.py): persistent XLA
         # compile cache so restarts deserialize programs from disk, and
         # a background pre-warm of the standard query shapes so even a

@@ -1,19 +1,15 @@
-"""Per-launch device telemetry and roofline accounting.
+"""Per-launch device telemetry.
 
-The production counterpart of bench.py's offline bandwidth figures
-(ROADMAP 2/5): every device launch site — executor direct, coalescer
-concat, fused interpreter, limb total-count (incl. the ICI collective),
-the TopN scorer, and the numpy host fallback — records a
-:class:`LaunchRecord` into a lock-light per-site accumulator, and the
-derived per-site achieved GB/s is compared against the stream floor the
-one-shot probe measured at server open (device/floorprobe.py).
-
-Roofline model (Williams et al., CACM 2009): the bitmap kernels are
-memory-bound, so "how fast could this go" is the stream floor and
-"how fast does it go" is logical plane bytes streamed / device time.
-``GET /debug/perf`` renders the table; ``exec.launch.gbps[site:*]`` /
-``exec.launch.floorPct[site:*]`` / ``device.streamFloorGbps`` land on
-/metrics as scrape-time gauges.
+Every device launch site — executor direct, coalescer concat, fused
+interpreter, limb total-count (incl. the ICI collective), the TopN
+scorer, and the numpy host fallback — records a :class:`LaunchRecord`
+into a lock-light per-site accumulator: launches, queries, rows,
+logical and effective bytes, and the host-clock time from dispatch to
+the end of the fetch.  ``GET /debug/perf`` renders the table;
+``exec.launch.launches[site:*]`` / ``exec.launch.gbps[site:*]`` land
+on /metrics as scrape-time gauges.  The per-site GB/s is bytes over
+that host-clock time, not a device rate: the benchmark takes device
+time from a profiler trace and the peak from ``benchmarks/peaks.json``.
 
 Discipline (Dapper-style always-on): ``record_launch`` must stay OFF
 every launch path's critical section — per-site locks guard only plain
@@ -143,9 +139,8 @@ class PerfRegistry:
     launch sites it instruments are process-global device state)."""
 
     def __init__(self, enabled: bool = True):
-        self._mu = threading.Lock()  # sites map + recent ring + floor
+        self._mu = threading.Lock()  # sites map + recent ring
         self._enabled = enabled
-        self._floor_gbps = 0.0
         self._sites: dict[str, _Site] = {}
         self._recent: deque = deque(maxlen=RECENT)
 
@@ -160,15 +155,8 @@ class PerfRegistry:
     def enabled(self) -> bool:
         return self._enabled
 
-    def set_floor(self, gbps: float) -> None:
-        with self._mu:
-            self._floor_gbps = float(gbps)
-
-    def floor_gbps(self) -> float:
-        return self._floor_gbps
-
     def reset(self) -> None:
-        """Drop accumulated launches (tests/bench tiers)."""
+        """Drop accumulated launches (tests)."""
         with self._mu:
             self._sites = {}
             self._recent = deque(maxlen=RECENT)
@@ -221,10 +209,9 @@ class PerfRegistry:
     # -- derived views -------------------------------------------------
 
     def snapshot(self) -> dict:
-        """The /debug/perf document: per-site roofline table + the
-        slowest recent launches (with trace ids) + the probed floor."""
+        """The /debug/perf document: per-site launch table + the
+        slowest recent launches (with trace ids)."""
         with self._mu:
-            floor = self._floor_gbps
             enabled = self._enabled
             sites = list(self._sites.items())
             recent = list(self._recent)
@@ -256,12 +243,6 @@ class PerfRegistry:
                 "eff_gbps": round(eff_gbps, 3),
                 "reduces": reduces,
             }
-            if floor > 0:
-                # %-of-floor from EFFECTIVE bytes: a compressed launch
-                # reading 1% of its logical geometry must not claim the
-                # logical GB/s against the stream floor.  Dense sites
-                # (eff == logical) are unchanged.
-                row["floor_pct"] = round(100.0 * eff_gbps / floor, 1)
             if window:
                 row["p50_ms"] = round(_percentile(window, 0.5), 3)
                 row["p99_ms"] = round(_percentile(window, 0.99), 3)
@@ -276,7 +257,6 @@ class PerfRegistry:
         ]
         return {
             "enabled": enabled,
-            "floor_gbps": round(floor, 3),
             "sites": table,
             "slowest": slowest,
         }
@@ -287,13 +267,9 @@ class PerfRegistry:
         backend)."""
         snap = self.snapshot()
         out: dict[str, float] = {}
-        if snap["floor_gbps"] > 0:
-            out["device.streamFloorGbps"] = snap["floor_gbps"]
         for site, row in snap["sites"].items():
             out[f"exec.launch.gbps[site:{site}]"] = row["gbps"]
             out[f"exec.launch.effGbps[site:{site}]"] = row["eff_gbps"]
-            if "floor_pct" in row:
-                out[f"exec.launch.floorPct[site:{site}]"] = row["floor_pct"]
             out[f"exec.launch.launches[site:{site}]"] = row["launches"]
             out[f"exec.launch.bytes[site:{site}]"] = row["bytes"]
             out[f"exec.launch.effBytes[site:{site}]"] = row["eff_bytes"]

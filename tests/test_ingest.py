@@ -533,23 +533,36 @@ class TestDeltaScatter:
         assert andnot_m.tolist() == [1 << 3]
 
     def test_pow2_bucketing_bounds_program_cache(self, tmp_path):
+        """Entries stay inside the bound whatever ran before in this
+        process: update counts bucket to powers of two, and a plane on
+        another home device is another executable, which the bound
+        counts (slices 0 and 1 live on two devices here)."""
         from pilosa_tpu.exec import plan
 
-        frag = Fragment(str(tmp_path / "0"), "i", "f", "standard", 0)
-        frag.open()
-        try:
-            frag.set_bit(0, 0)
-            frag.device_plane()
-            for n in (1, 2, 3, 5, 9, 17):
-                for c in range(n):
-                    frag.set_bit(1, 64 * c)
-                frag.device_row(1)
-            stats = plan.program_cache_stats()
-            bounds = plan.program_cache_bounds()
-            assert stats.get("plan.scatter", 0) >= 1
-            assert stats["plan.scatter"] <= bounds["plan.scatter"]
-        finally:
-            frag.close()
+        before = plan.program_cache_stats().get("plan.scatter", 0)
+        assert bp.home_device(0) != bp.home_device(1)
+        for slice_i in (0, 1):
+            base = slice_i * bp.SLICE_WIDTH
+            frag = Fragment(
+                str(tmp_path / str(slice_i)), "i", "f", "standard", slice_i
+            )
+            frag.open()
+            try:
+                frag.set_bit(0, base)
+                frag.device_plane()
+                for n in (1, 2, 3, 5, 9, 17):
+                    for c in range(n):
+                        frag.set_bit(1, base + 64 * c)
+                    frag.device_row(1)
+            finally:
+                frag.close()
+        stats = plan.program_cache_stats()
+        bounds = plan.program_cache_bounds()
+        assert stats.get("plan.scatter", 0) >= 1
+        assert stats["plan.scatter"] <= bounds["plan.scatter"]
+        # Six update counts under the 32-update floor are ONE bucket:
+        # at most one new executable a device.
+        assert stats["plan.scatter"] - before <= 2
 
     def test_concurrent_reader_sees_atomic_planes(self, tmp_path):
         """A reader racing a set-only storm must only ever observe a

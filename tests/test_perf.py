@@ -1,10 +1,10 @@
-"""Performance-observability tests (obs/perf.py + device/floorprobe.py
-+ the /debug/perf | /debug/profile | /debug/stacks endpoints + the
-native latency histogram families).
+"""Performance-observability tests (obs/perf.py + the /debug/perf |
+/debug/profile | /debug/stacks endpoints + the native latency histogram
+families).
 
-Covers the PR-17 acceptance bar: per-site roofline accounting visible
+Covers the PR-17 acceptance bar: per-site launch accounting visible
 at /debug/perf for the direct / coalesce / interp / collective / topn
-launch sites with %-of-floor figures; lifetime-monotonic histogram
+launch sites; lifetime-monotonic histogram
 ``_count``/``_sum`` past the reservoir size; StatsD truncation at
 UTF-8 codepoint boundaries; /metrics exposition validity under a
 concurrent scrape-vs-writer storm; launch byte accounting consistent
@@ -50,11 +50,9 @@ def _fresh_registry():
     """The perf registry is process-global (like the device pool) —
     isolate every test from its neighbors' launches."""
     perf.registry().reset()
-    perf.registry().set_floor(0.0)
     perf.registry().configure(enabled=True)
     yield
     perf.registry().reset()
-    perf.registry().set_floor(0.0)
     perf.registry().configure(enabled=True)
 
 
@@ -94,10 +92,9 @@ class TestPerfRegistry:
         assert perf.plane_bytes(1, WORDS_PER_SLICE) == ROW_SLOT_BYTES
         assert perf.plane_bytes(3, 64) == 3 * 64 * 4
 
-    def test_record_snapshot_gauges_and_floor_pct(self):
+    def test_record_snapshot_and_gauges(self):
         r = perf.registry()
-        r.set_floor(100.0)
-        # 1 GB in 0.1 s of device time = 10 GB/s = 10% of the floor.
+        # 1 GB in 0.101 s on the host's clock = 9.9 GB/s.
         r.record_launch(
             "coalesce", reduce="count", queries=4, rows=8,
             n_bytes=1_000_000_000, dispatch_ms=20.0, total_ms=100.0,
@@ -114,18 +111,13 @@ class TestPerfRegistry:
         assert site["occupancy"] == 3.0
         assert site["bytes"] == 1_000_000_000
         assert site["gbps"] == pytest.approx(1.0 / 0.101, rel=1e-3)
-        assert site["floor_pct"] == pytest.approx(
-            100.0 * site["gbps"] / 100.0, abs=0.11
-        )
         assert site["reduces"] == {"count": 1, "row": 1}
         assert site["p99_ms"] > site["p50_ms"] > 0
         # Slowest table keeps the trace id for /debug/traces handoff.
         assert snap["slowest"][0]["trace_id"] == "t1"
         g = r.gauges()
-        assert g["device.streamFloorGbps"] == 100.0
         assert g["exec.launch.launches[site:coalesce]"] == 2
         assert g["exec.launch.gbps[site:coalesce]"] == site["gbps"]
-        assert g["exec.launch.floorPct[site:coalesce]"] == site["floor_pct"]
 
     def test_disabled_registry_records_nothing(self):
         r = perf.registry()
@@ -479,7 +471,7 @@ def _populate(s, rows=2, cols=(5, 9, SLICE_WIDTH + 3)):
 
 
 class TestPerfEndpoint:
-    def test_all_launch_sites_reported_with_floor_pct(self, perf_server):
+    def test_all_launch_sites_reported(self, perf_server):
         s = perf_server
         _populate(s)
         c = InternalClient(s.host, timeout=30.0)
@@ -514,8 +506,6 @@ class TestPerfEndpoint:
         assert status == 200
         doc = json.loads(data)
         assert doc["enabled"] is True
-        # The open()-time stream-floor probe anchored the roofline.
-        assert doc["floor_gbps"] > 0
         sites = doc["sites"]
         for site in ("direct", "coalesce", "interp", "topn"):
             assert site in sites, f"missing site {site}: {sorted(sites)}"
@@ -523,7 +513,7 @@ class TestPerfEndpoint:
         for name, row in sites.items():
             assert row["launches"] >= 1, (name, row)
             assert row["gbps"] >= 0
-            assert "floor_pct" in row, (name, row)
+            assert row["queries"] >= row["launches"], (name, row)
             assert row["dispatch_ms"] <= row["device_ms"] + 1e-6
         assert isinstance(doc["compile_ms"], dict)
         # Slowest launches carry trace ids for /debug/traces handoff.
@@ -561,9 +551,8 @@ class TestPerfEndpoint:
         assert status == 200
         text = data.decode()
         _assert_valid_exposition(text)
-        assert "pilosa_device_streamFloorGbps" in text
         assert re.search(r'pilosa_exec_launch_gbps\{site="', text), text
-        assert re.search(r'pilosa_exec_launch_floorPct\{site="', text), text
+        assert re.search(r'pilosa_exec_launch_launches\{site="', text), text
         assert "# TYPE pilosa_query_latency_ms histogram" in text
         assert 'pilosa_query_latency_ms_bucket{class=' in text
         assert 'le="+Inf"' in text
@@ -627,68 +616,6 @@ class TestPerfEndpoint:
 
 
 # ---------------------------------------------------------------------------
-# floor probe
-# ---------------------------------------------------------------------------
-
-
-class TestFloorProbe:
-    def test_probe_measures_and_caches(self, tmp_path, monkeypatch):
-        from pilosa_tpu.device import floorprobe
-
-        floorprobe.reset_cache()
-        calls = []
-        real_measure = floorprobe._measure
-
-        def counting_measure(*a, **kw):
-            calls.append(1)
-            return real_measure(*a, **kw)
-
-        monkeypatch.setattr(floorprobe, "_measure", counting_measure)
-        stats = stats_mod.ExpvarStatsClient()
-        fp = floorprobe.probe(
-            artifact_dir=str(tmp_path), stats=stats, logger=lambda m: None
-        )
-        assert fp is not None
-        assert fp["mean_gbps"] > 0
-        assert fp["gbps"]
-        assert len(calls) == 1
-        assert stats.snapshot()["gauges"]["device.streamFloorGbps"] == (
-            pytest.approx(fp["mean_gbps"])
-        )
-        # Second probe: process cache, no re-measure.
-        fp2 = floorprobe.probe(artifact_dir=str(tmp_path))
-        assert fp2["mean_gbps"] == fp["mean_gbps"]
-        assert len(calls) == 1
-        # Fresh process (cache cleared): the disk artifact short-cuts.
-        floorprobe.reset_cache()
-        fp3 = floorprobe.probe(artifact_dir=str(tmp_path))
-        assert fp3["mean_gbps"] == pytest.approx(fp["mean_gbps"])
-        assert len(calls) == 1
-        assert (tmp_path / floorprobe.CACHE_FILE).exists()
-        # force=True re-measures.
-        floorprobe.probe(artifact_dir=str(tmp_path), force=True)
-        assert len(calls) == 2
-
-    def test_server_open_sets_registry_floor(self, perf_server):
-        assert perf.registry().floor_gbps() > 0
-
-    def test_floor_probe_disabled(self, tmp_path):
-        perf.registry().set_floor(0.0)
-        s = Server(
-            data_dir=str(tmp_path / "data2"),
-            floor_probe=False,
-            anti_entropy_interval=3600,
-            polling_interval=3600,
-            cache_flush_interval=3600,
-        )
-        s.open()
-        try:
-            assert perf.registry().floor_gbps() == 0.0
-        finally:
-            s.close()
-
-
-# ---------------------------------------------------------------------------
 # config plumbing
 # ---------------------------------------------------------------------------
 
@@ -700,16 +627,13 @@ class TestObsConfig:
             "latency-buckets-ms = [5.0, 50.0, 500.0]\n"
             "slo-ms = 100.0\n"
             "slo-objective = 0.99\n"
-            "floor-probe = false\n"
         )
         cfg.validate()
         assert cfg.obs.latency_buckets_ms == [5.0, 50.0, 500.0]
         assert cfg.obs.slo_ms == 100.0
         assert cfg.obs.slo_objective == 0.99
-        assert cfg.obs.floor_probe is False
         cfg2 = config_mod.from_toml(cfg.to_toml())
         assert cfg2.obs.latency_buckets_ms == [5.0, 50.0, 500.0]
-        assert cfg2.obs.floor_probe is False
 
     def test_env_overlay(self):
         cfg = config_mod.apply_env(
@@ -718,13 +642,11 @@ class TestObsConfig:
                 "PILOSA_OBS_LATENCY_BUCKETS_MS": "1,10,100",
                 "PILOSA_OBS_SLO_MS": "25",
                 "PILOSA_OBS_SLO_OBJECTIVE": "0.95",
-                "PILOSA_OBS_FLOOR_PROBE": "false",
             },
         )
         assert cfg.obs.latency_buckets_ms == [1.0, 10.0, 100.0]
         assert cfg.obs.slo_ms == 25.0
         assert cfg.obs.slo_objective == 0.95
-        assert cfg.obs.floor_probe is False
 
     def test_validation_rejects_bad_values(self):
         cfg = config_mod.Config()

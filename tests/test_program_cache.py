@@ -17,6 +17,7 @@ to an unpadded host (numpy) evaluation.
 
 from __future__ import annotations
 
+import jax
 import numpy as np
 import pytest
 
@@ -249,3 +250,43 @@ def test_program_cache_bounds_invariant_after_prewarm():
     for fam, bound in bounds.items():
         assert stats[fam] <= bound, (fam, stats, bounds)
     assert stats["total"] > 0
+
+
+def _top_counts_on(dev):
+    plane = jax.device_put(np.zeros((bp.ROW_BLOCK, bp.WORDS_PER_SLICE), np.uint32), dev)
+    bp.top_counts(plane, plane[0])
+
+
+def _expand_on(dev):
+    payload = np.full(bp.PAYLOAD_BUCKET_FLOOR, 7, np.uint32)
+    bp.expand_payload(bp.FMT_SPARSE, jax.device_put(payload, dev))
+
+
+def _score_on(dev):
+    plane = jax.device_put(np.zeros((bp.ROW_BLOCK, bp.WORDS_PER_SLICE), np.uint32), dev)
+    bp.score_planes(
+        [plane], np.zeros((1, bp.ROW_BLOCK), np.int32),
+        src_slots=np.zeros(1, np.int32),
+    )
+
+
+@pytest.mark.parametrize(
+    "family, launch",
+    [
+        ("bitplane.topCounts", _top_counts_on),
+        ("bitplane.expand", _expand_on),
+        ("bitplane.scorePlanes", _score_on),
+    ],
+)
+def test_a_program_over_per_device_planes_is_bounded_on_every_device(family, launch):
+    """A plane lives on its slice's home device and the jit compiles
+    one executable a device: the bound counts them, whatever this
+    process ran before."""
+    devices = bp.participating_devices()
+    assert len(devices) > 1
+    before = plan.program_cache_stats()[family]
+    for dev in devices:
+        launch(dev)
+    stats, bounds = plan.program_cache_stats(), plan.program_cache_bounds()
+    assert stats[family] - before <= len(devices)
+    assert len(devices) <= stats[family] <= bounds[family], (stats, bounds)

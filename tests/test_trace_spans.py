@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from pilosa_tpu.core import cache as cache_mod
 from pilosa_tpu.exec import plan
 from pilosa_tpu.exec.coalesce import CoalesceScheduler
 from pilosa_tpu.net import handler as handler_mod
@@ -341,8 +342,9 @@ def test_the_gather_of_a_miss_has_the_same_spans_at_any_slice_count(
     stats, bounds = plan.program_cache_stats(), plan.program_cache_bounds()
     assert stats["bitplane.gatherPlanes"] == len(compiled)
     assert stats["bitplane.gatherPlanes"] <= bounds["bitplane.gatherPlanes"]
+    # the gauge and each span round to a microsecond on their own
     assert plan.program_cache_compile_ms()["plan.gather"] >= sum(
-        s["duration_ms"] for s in compiled)
+        s["duration_ms"] for s in compiled) - 0.001 * len(compiled)
 
     # another operator over other rows: the programs that are there
     assert c.execute_pql("i", INTERSECT.replace("Intersect", "Xor")) == slices
@@ -370,7 +372,7 @@ TOPN_SRC = 'TopN(Bitmap(frame="f", rowID=1), frame="f", n=10)'
 
 @pytest.mark.parametrize("slices", [2, 70])
 def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
-    one_chip, server, slices
+    one_chip, server, slices, monkeypatch
 ):
     """``topn.prep`` / ``topn.score`` > ``topn.dispatch`` > ``compile``,
     ``topn.fetch`` / ``topn.select`` with the tags the per-layer metrics
@@ -378,6 +380,9 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
     at 2."""
     _populate(server, slices)
     plan.clear_program_caches()
+    # The memo lives from before the scorer's first call, and under six
+    # test workers that compile has outlasted its 10 s.
+    monkeypatch.setattr(cache_mod, "RECALCULATE_INTERVAL_S", 600.0)
     c = InternalClient(server.host, timeout=120.0)
     def topn(text):
         return [(p.id, p.count) for p in c.execute_pql("i", text)]
@@ -404,7 +409,7 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
     assert _span(first, "topn.fetch")["tags"]["arrays"] == launches
     assert _span(first, "topn.select")["tags"]["parts"] == slices
 
-    # the same text again inside the memo's 10 s: nothing is scored
+    # the same text again inside the memo's lifetime: nothing is scored
     assert topn(TOPN_SRC) == want
     again = _last_trace(c)
     assert _span(again, "topn.prep")["tags"]["prep_cache"] == "hit"
@@ -484,7 +489,10 @@ def test_a_profile_taken_while_a_query_runs_holds_the_programs_spans(
 
     _populate(server, 3)
     c = InternalClient(server.host, timeout=60.0)
-    assert c.execute_pql("i", INTERSECT) == 3  # compile outside the profile
+    # compile outside the profile: under six test workers a first call
+    # has outlasted the 1 s window, and no span then opens inside it
+    assert c.execute_pql("i", INTERSECT) == 3
+    assert c.execute_pql("i", UNION) == 6
     reply: dict = {}
 
     def profile():

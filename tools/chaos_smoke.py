@@ -7,7 +7,7 @@ Not a benchmark and not the full chaos suite (tests/test_resilience.py)
 the injected transport error is retried, the injected delay is absorbed
 within the deadline, the answer is exact, and the fault rules really
 fired.  Run via ``make chaos-smoke``; wired into CI as a non-blocking
-step next to bench-smoke.
+step.
 """
 
 from __future__ import annotations

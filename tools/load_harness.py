@@ -22,13 +22,13 @@ Modes:
   --self-boot        boot an in-process server (CPU or current backend),
                      seed it, sweep, tear down.  --compare runs the
                      sweep twice — admission ON then OFF — into one
-                     artifact (the bench's storm tier).
+                     artifact.
   --host HOST:PORT   storm an external node (expects index/frame/field
                      already seeded unless --seed).
 
 Prints ONE JSON artifact line on stdout (or --artifact PATH); all
 progress goes to stderr.  Used by ``make load-smoke``
-(tools/load_smoke.py) and bench.py's ``admission_storm`` tier.
+(tools/load_smoke.py) and ``tools/gameday.py``.
 """
 
 from __future__ import annotations
