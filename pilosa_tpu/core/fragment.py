@@ -250,6 +250,18 @@ class TopOptions:
     filter_values: list[Any] | None = None
     tanimoto_threshold: int = 0
 
+    @property
+    def keeps_every_counted(self) -> bool:
+        """Whether candidate filtering (``Fragment._filter_arrays``)
+        reduces to ``count > 0`` under these options, whatever the src:
+        no count window, no threshold an integer count above 0 could
+        miss, no attr filter."""
+        return (
+            not self.tanimoto_threshold
+            and self.min_threshold <= 1
+            and not (self.filter_field and self.filter_values)
+        )
+
 
 @dataclass
 class TopState:
@@ -276,6 +288,10 @@ class TopState:
     min_threshold: int = 0
     dev_counts: object = None
     counts: object = None
+    # The src is this row of the fragment's own plane and no host copy
+    # of it was made (top_prepare_own_parts): the device scorer reads
+    # it at its slot, the host scorer by this id.
+    src_row: int | None = None
 
 
 @dataclass
@@ -297,6 +313,28 @@ class SubRef:
     shape: tuple  # (padded_rows, words)
     plane_rows: int  # mirror row count (program-shape grouping)
     device: object
+
+
+@dataclass(frozen=True, eq=False)
+class TopLayout:
+    """The gather layout of a fragment's OWN ranked candidates: what a
+    TopN scoring pass over exactly those rows needs and no query text
+    changes.  ``ids`` / ``cnts`` are the rank cache's listing (count
+    falling, ids rising) less the rows it counts empty; ``dense_pos`` /
+    ``sparse_pos`` their positions by row tier; ``slots`` the padded
+    int32 slot vector a SubRef carries (None without a dense-tier
+    candidate).  Made by ``Fragment.top_layout`` and valid for the
+    fragment ``version`` and the rank-cache arrays ``ranked`` it was
+    made from; the arrays are shared by every query that uses it and
+    are never written."""
+
+    ids: np.ndarray
+    cnts: np.ndarray
+    dense_pos: np.ndarray
+    sparse_pos: np.ndarray
+    slots: np.ndarray | None
+    version: int
+    ranked: tuple
 
 
 class Fragment:
@@ -364,6 +402,9 @@ class Fragment:
         # splits (see _tier_key_arrays_locked), cached per version.
         self._tier_arrays = None
         self._tier_arrays_version = -1
+        # Gather layout of the ranked candidates (see top_layout), kept
+        # until a write or a rank-cache re-sort.
+        self._top_layout: TopLayout | None = None
         self._max_row_id = 0
         self._op_n = 0
         self._version = 0
@@ -2172,9 +2213,7 @@ class Fragment:
             )
         src_words = np.asarray(src_seg, dtype=np.uint32)
         with self._mu:
-            slot_ids, slot_vals, sparse_sorted = self._tier_key_arrays_locked()
-            dense_pos = np.flatnonzero(np.isin(ids, slot_ids))
-            sparse_pos = np.flatnonzero(np.isin(ids, sparse_sorted))
+            dense_pos, sparse_pos, slots = self._tier_split_locked(ids)
             if not len(dense_pos) and not len(sparse_pos):
                 return (
                     TopState(
@@ -2183,28 +2222,11 @@ class Fragment:
                     None,
                     None,
                 )
-            sub_ref = None
-            if len(dense_pos):
-                # Candidate rows gather from the HBM-resident plane —
-                # only the src row and slot indices travel host->device.
-                # The gather itself is LAZY (SubRef): the executor's
-                # stacked-batch cache usually already holds the rows.
-                slots = slot_vals[
-                    np.searchsorted(slot_ids, ids[dense_pos])
-                ].astype(np.int32)
-                # Pad to a full row block (repeating the last slot) so
-                # the scorer's row count stays on the tile-aligned
-                # kernel path; surplus scores are discarded on read.
-                padded = bp.pad_rows(len(slots))
-                if padded != len(slots):
-                    slots = np.pad(slots, (0, padded - len(slots)), mode="edge")
-                sub_ref = SubRef(
-                    plane=self.device_plane(),
-                    slots=slots,
-                    shape=(padded, bp.WORDS_PER_SLICE),
-                    plane_rows=int(self._plane.shape[0]),
-                    device=bp.home_device(self.slice),
-                )
+            # Candidate rows gather from the HBM-resident plane — only
+            # the src row and slot indices travel host->device.  The
+            # gather itself is LAZY (SubRef): the executor's
+            # stacked-batch cache usually already holds the rows.
+            sub_ref = self._sub_ref_locked(slots) if slots is not None else None
             # Sparse candidates (the low-count tail) score host-side in
             # O(set bits): probe src's words at each offset.
             sparse_cnt = np.empty(len(sparse_pos), np.int64)
@@ -2244,6 +2266,106 @@ class Fragment:
             self._tier_arrays = (sids[order], svals[order], spids)
             self._tier_arrays_version = self._version
         return self._tier_arrays
+
+    def _tier_split_locked(self, ids: np.ndarray):
+        """``(dense_pos, sparse_pos, slots)`` of candidate ``ids``:
+        their positions by row tier, and the dense ones' plane slots as
+        the int32 vector a SubRef carries (None without a dense
+        candidate).  Callers hold ``_mu``."""
+        slot_ids, slot_vals, sparse_sorted = self._tier_key_arrays_locked()
+        dense_pos = np.flatnonzero(np.isin(ids, slot_ids))
+        sparse_pos = np.flatnonzero(np.isin(ids, sparse_sorted))
+        if not len(dense_pos):
+            return dense_pos, sparse_pos, None
+        slots = slot_vals[np.searchsorted(slot_ids, ids[dense_pos])].astype(
+            np.int32
+        )
+        # Pad to a full row block (repeating the last slot) so the
+        # scorer's row count stays on the tile-aligned kernel path;
+        # surplus scores are discarded on read.
+        padded = bp.pad_rows(len(slots))
+        if padded != len(slots):
+            slots = np.pad(slots, (0, padded - len(slots)), mode="edge")
+        return dense_pos, sparse_pos, slots
+
+    def _sub_ref_locked(self, slots: np.ndarray) -> SubRef:
+        """The scorer's view of this fragment for padded ``slots``, on
+        the CURRENT mirror.  Callers hold ``_mu``, so the snapshot and
+        whatever slots they read under the same hold agree."""
+        return SubRef(
+            plane=self.device_plane(),
+            slots=slots,
+            shape=(len(slots), bp.WORDS_PER_SLICE),
+            plane_rows=int(self._plane.shape[0]),
+            device=bp.home_device(self.slice),
+        )
+
+    def top_layout(self) -> TopLayout:
+        """The gather layout of this fragment's own ranked candidates
+        (TopLayout): the one kept, while no write (``_version``) and no
+        re-sort of the rank cache (``top_arrays`` hands out new arrays)
+        has happened since it was made, so a distinct TopN text asks
+        nothing here that the last one did not.  A re-sort that lists
+        the same rows (new counts at most: the throttled re-sort of an
+        unwritten fragment) keeps the tier split and the slots, which
+        depend on the ids and the version alone.  The listing goes
+        through ``_top_candidates_arrays``, so the rank cache's
+        throttled re-sort happens exactly where it did."""
+        with self._mu:
+            ranked = self._top_candidates_arrays(None)
+            lay = self._top_layout
+            fresh = lay is not None and lay.version == self._version
+            if fresh and lay.ranked is ranked:
+                return lay
+            ids, cnts = ranked
+            keep = cnts > 0
+            if not keep.all():
+                ids, cnts = ids[keep], cnts[keep]
+            if fresh and np.array_equal(ids, lay.ids):
+                split = lay.dense_pos, lay.sparse_pos, lay.slots
+            else:
+                split = self._tier_split_locked(ids)
+            lay = self._top_layout = TopLayout(
+                ids, cnts, *split, version=self._version, ranked=ranked
+            )
+            return lay
+
+    def top_prepare_own_parts(
+        self, lay: TopLayout, min_threshold: int, src_row: int | None
+    ):
+        """``top_prepare_union_parts`` for a union that IS ``lay``'s
+        candidates, under options whose filters reduce to ``count > 0``
+        (``TopOptions.keeps_every_counted``) and a src that is row
+        ``src_row`` of this very fragment (None: no src).  Nothing is
+        foreign, so the TopState and the SubRef come from the layout:
+        no set algebra.  And no host copy of the src row: a
+        dense-tier src is scored from its slot in the plane
+        (``TopState.src_row``; the executor asks for the slot).
+
+        Returns ``(TopState, SubRef, None)`` as the ``*_parts`` APIs
+        do, or None where the layout cannot serve and the caller takes
+        the general way: a write since it was derived, a sparse-tier
+        candidate (probing it reads the src's host words), or a src row
+        that is not in the dense tier here."""
+        if src_row is None:
+            # No intersection: cached counts are final.
+            return TopState(done_ids=lay.ids, done_cnts=lay.cnts), None, None
+        if lay.slots is None or len(lay.sparse_pos):
+            return None
+        with self._mu:
+            if lay.version != self._version or src_row not in self._slot_of:
+                return None
+            sub_ref = self._sub_ref_locked(lay.slots)
+        st = TopState(
+            cand_ids=lay.ids,
+            cand_cached=lay.cnts,
+            dense_pos=lay.dense_pos,
+            sparse_pos=lay.sparse_pos,
+            sparse_cnt=self._EMPTY_I64,
+            min_threshold=min_threshold,
+            src_row=src_row,
+        )
+        return st, sub_ref, None
 
     def top_score_arrays(
         self, st: "TopState"

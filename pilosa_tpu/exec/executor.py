@@ -2499,12 +2499,14 @@ class Executor:
         operations and host<->device transfers as possible and fill
         each ``TopState.counts``.
 
-        ``parts``: list of (TopState, SubRef, src_words, src_spec,
+        ``parts``: list of (TopState, SubRef, src_words, src_slot,
         fragment) — the first three from the ``*_parts`` fragment APIs,
-        ``src_spec`` from ``_attach_dev_src`` (None when the src tree
-        is not a plain Bitmap leaf), ``fragment`` for the host scoring
+        ``src_slot`` from ``_attach_dev_src`` (None when the src is not
+        a row of the member's own plane; only then must ``src_words``
+        be there), ``fragment`` for the host scoring
         fallback.  Entries with a SubRef group by program shape
-        (sub shape, plane rows, home device); each group is scored by
+        (sub shape, plane rows, home device) and by where their src is
+        read from; each group is scored by
         ONE compiled program (bp.score_planes) that reads candidate AND
         src rows straight from the fragments' resident HBM mirrors — no
         stacked copy, no src upload — launched once per bp.SCORE_GROUP
@@ -2539,7 +2541,8 @@ class Executor:
             for entry in live:
                 ref = entry[1]
                 groups.setdefault(
-                    (ref.shape, ref.plane_rows, ref.device), []
+                    (ref.shape, ref.plane_rows, ref.device, entry[3] is None),
+                    [],
                 ).append(entry)
             # Scorer roofline accounting: each live member's fused
             # scoring pass streams its whole plane snapshot (the last
@@ -2555,14 +2558,14 @@ class Executor:
                 "topn.dispatch", groups=len(groups), rows=rows, bytes=n_bytes
             ) as sp:
                 self._fault_check_launch("topn")
-                for members in groups.values():
+                for (*_, host_src), members in groups.items():
                     planes = [m[1].plane for m in members]
                     slots = np.stack([m[1].slots for m in members])
                     # Same-plane src slot for every member -> zero src bytes
                     # cross the host boundary (and no extra leaf shapes in
                     # the jit key); otherwise one stacked host-snapshot
                     # transfer per launch.
-                    if all(m[3] is not None for m in members):
+                    if not host_src:
                         outs = bp.score_planes(
                             planes,
                             slots,
@@ -2648,7 +2651,22 @@ class Executor:
                 return res
         return jax.device_get(arrays)
 
-    def _attach_dev_src(self, index: str, c: Call, frag, part):
+    def _topn_src_leaf(self, index: str, c: Call):
+        """``(frame, view, row id)`` of a TopN src that is ONE plain
+        Bitmap leaf, None for any other src tree or none at all: the
+        slice-independent half of finding the src row in a member's own
+        plane, resolved once a call."""
+        if len(c.children) != 1:
+            return None
+        leaf = c.children[0]
+        if leaf.name != "Bitmap" or leaf.children:
+            return None
+        view, row_id = self._resolve_bitmap_view(index, leaf)
+        if view is None:
+            return None
+        return view.frame, view.name, row_id
+
+    def _attach_dev_src(self, index: str, c: Call, frag, part, leaf=None):
         """Extend a fragment's (st, SubRef, src_words) TopN part with
         the src row's SLOT in the member's own plane snapshot when the
         TopN src is a plain Bitmap leaf on the SAME fragment (the
@@ -2658,25 +2676,20 @@ class Executor:
         key.  Anything else (different src frame, sparse-tier src row,
         a mirror refresh since the prepare snapshot, non-Bitmap tree)
         returns None, falling the group back to the one host-snapshot
-        src transfer — always consistent, just not transfer-free."""
+        src transfer — always consistent, just not transfer-free.
+        ``leaf``: the call's ``_topn_src_leaf``, from a caller that
+        walks many fragments and resolved it once."""
         st, sub_ref, srcw = part
         slot = None
-        if (
-            sub_ref is not None
-            and len(c.children) == 1
-            and c.children[0].name == "Bitmap"
-            and not c.children[0].children
-        ):
-            sfrag, row_id = self._resolve_bitmap_leaf(
-                index, c.children[0], frag.slice
-            )
-            if sfrag is frag:
-                with sfrag._mu:
-                    s = sfrag._slot_of.get(row_id)
+        if sub_ref is not None:
+            leaf = leaf or self._topn_src_leaf(index, c)
+            if leaf is not None and leaf[:2] == (frag.frame, frag.view):
+                with frag._mu:
+                    s = frag._slot_of.get(leaf[2])
                     # The slot is only valid against the snapshot the
                     # prepare captured; a refresh since then (writes)
                     # may have reordered the slot layout.
-                    if s is not None and sfrag.device_plane() is sub_ref.plane:
+                    if s is not None and frag.device_plane() is sub_ref.plane:
                         slot = int(s)
         return st, sub_ref, srcw, slot
 
@@ -2689,14 +2702,19 @@ class Executor:
         turns the per-slice host walk from O(max_slice) into
         O(existing fragments) — at bench scale (954 index slices, one
         frame fragment) that walk dominated warm TopN host time."""
-        frame, view = self._topn_frame_view(c)
-        idx = self.holder.index(index)
-        f = idx.frame(frame) if idx is not None else None
-        v = f.view(view) if f is not None else None
+        v = self._topn_view(index, c)
         if v is None:
             return []
         have = v.fragment_slices()
         return [s for s in slices if s in have]
+
+    def _topn_view(self, index: str, c: Call):
+        """The view a TopN call ranks, or None: resolved once a call,
+        never once a slice."""
+        frame, view = self._topn_frame_view(c)
+        idx = self.holder.index(index)
+        f = idx.frame(frame) if idx is not None else None
+        return f.view(view) if f is not None else None
 
     def _all_slices_local(self, index: str, slices: list[int]) -> bool:
         rn = getattr(self.cluster, "route_nodes", None)
@@ -2717,13 +2735,12 @@ class Executor:
         fragment springing into existence must invalidate) plus, when a
         src tree exists, the versions of every fragment its leaves
         resolve to (the src rows were host-evaluated at prep time)."""
-        frame, view = self._topn_frame_view(c)
-        out: list = []
-        for s in slices:
-            frag = self.holder.fragment(index, frame, view, s)
-            out.append(
-                None if frag is None else (frag._serial, frag._version)
-            )
+        view = self._topn_view(index, c)
+        frags = view.fragments_at(slices) if view is not None else [None] * len(slices)
+        out: list = [
+            None if frag is None else (frag._serial, frag._version)
+            for frag in frags
+        ]
         if len(c.children) == 1:
             try:
                 _, leaves = plan.decompose(
@@ -2834,9 +2851,9 @@ class Executor:
             self._register_cache_entry(
                 self._topn_pool_key(key),
                 [
-                    p[5].plane
+                    p[4].plane
                     for p in ent.get("parts", ())
-                    if p[5] is not None and not p[0].mirror_is(p[5].plane)
+                    if p[4] is not None and not p[0].mirror_is(p[4].plane)
                 ],
                 {"cache": "topn", "index": index, "query": str(c)},
                 functools.partial(self._evict_topn_key, key),
@@ -2846,97 +2863,153 @@ class Executor:
     def _topn_folded_build(self, index: str, c: Call, slices: list[int]) -> dict:
         """Build a folded-TopN prep entry (see _topn_folded_entry for
         the caching contract).  Entry shapes: ``{"empty": True}``,
-        ``{"two_phase": True}``, or ``{"parts": [(frag, topt, cand_ids,
-        cand_mask, st_proto, sub_ref, src_words, src_slot), ...]}``
-        where st_proto is the UNSCORED TopState (cloned per query) and
-        cand_mask pre-resolves ``np.isin(union_order_ids, cand_ids)``
-        for phase-1 winner selection."""
+        ``{"two_phase": True}``, or ``{"parts": [(frag, cand_ids,
+        cand_mask, st_proto, sub_ref, src_words, src_slot), ...],
+        "union": n, "build": how}`` where st_proto is the UNSCORED
+        TopState (cloned per query) and cand_mask pre-resolves
+        ``np.isin(union_order_ids, cand_ids)`` for phase-1 winner
+        selection.
+
+        Per text the build does only what depends on the text's src.
+        What a fragment's candidates are, which tier holds each and
+        where its rows sit in the plane is the fragment's to know
+        (``Fragment.top_layout``, kept until a write or a re-sort), and
+        where the call's filters keep every counted row and the union
+        is a fragment's own candidate list, its part comes from that
+        layout (``top_prepare_own_parts``): no set algebra, and no host
+        copy of a src row that the scorer reads from the plane.  Any
+        other fragment is walked (``top_prepare_union_parts``, with the
+        src's host words).  ``build`` says which: ``"direct"`` when no
+        fragment was walked."""
         has_src = len(c.children) == 1
 
-        # Only slices whose fragment exists can contribute; restricting
-        # up front turns every per-slice walk below into O(fragments).
-        slices = self._existing_topn_slices(index, c, slices)
+        # Only slices whose fragment exists can contribute; one sweep of
+        # the view finds them, and the call's arguments are parsed once.
+        view = self._topn_view(index, c)
+        frags = (
+            [f for f in view.fragments_at(slices) if f is not None]
+            if view is not None
+            else []
+        )
+        if not frags:
+            return {"empty": True}
+        topt = self._topn_options(c)
+        plain = topt.keeps_every_counted
 
-        # Pass 1 (host-only): per-slice candidate (ids, cached counts)
+        # Pass 1 (host-only): per-fragment candidate (ids, cached counts)
         # arrays, WITHOUT evaluating the src tree yet — the union guard
         # below must be able to fall back before any src work is spent.
         # A src only shrinks candidate lists (tanimoto count-window), so
         # the src-free walk is a conservative union estimate.
         per: list[tuple] = []
-        for s in slices:
-            prep = self._topn_options_for_slice(index, c, s, None)
-            if prep is None:
-                continue
-            frag, topt = prep
-            per.append((frag, topt) + frag.top_candidates_arrays(topt))
-        if not per:
-            return {"empty": True}
+        for frag in frags:
+            if plain:
+                lay = frag.top_layout()
+                per.append((frag, lay, topt, lay.ids, lay.cnts))
+            else:
+                per.append((frag, None, topt) + frag.top_candidates_arrays(topt))
         # Guard against disjoint caches: every slice scores the WHOLE
         # union, so when the union dwarfs the largest per-slice candidate
         # list the folded pass does more device gather+score work than
         # the two saved round trips are worth — use the two-phase
         # protocol instead.  Overlapping hot rows (the common shape)
         # keep union ~= per-slice candidates and stay folded.
-        union = np.unique(np.concatenate([ids for _, _, ids, _ in per]))
+        union = np.unique(np.concatenate([p[3] for p in per]))
         if not len(union):
             return {"empty": True}
-        max_cand = max(len(ids) for _, _, ids, _ in per)
+        max_cand = max(len(p[3]) for p in per)
         if len(union) > max(2 * max_cand, 512):
             return {"two_phase": True}
 
-        if has_src:
-            src_rows = self._eval_tree_slices_host(index, c.children[0], slices)
-            if _uint_arg(c, "tanimotoThreshold")[0] > 0:
-                # Tanimoto count-windows depend on the src count, so
-                # re-derive candidates (and the union) with the real src.
-                per = []
-                for s in slices:
-                    prep = self._topn_options_for_slice(index, c, s, src_rows)
-                    if prep is None:
-                        continue
-                    frag, topt = prep
-                    per.append((frag, topt) + frag.top_candidates_arrays(topt))
-                if not per:
-                    return {"empty": True}
-                union = np.unique(
-                    np.concatenate([ids for _, _, ids, _ in per])
-                )
-            else:
-                # Without tanimoto, candidate filtering never reads the
-                # src — only the scorer does.  Attach it to the pass-1
-                # options instead of re-walking every candidate list.
-                attached = []
-                for frag, topt, ids, cnts in per:
-                    src = RowBitmap()
-                    row = src_rows.get(frag.slice)
-                    if row is not None:
-                        src.set_segment(frag.slice, row)
-                    attached.append((frag, replace(topt, src=src), ids, cnts))
-                per = attached
-        if not len(union):
-            return {"empty": True}
+        def with_src(frag, src_rows) -> TopOptions:
+            src = RowBitmap()
+            row = src_rows.get(frag.slice)
+            if row is not None:
+                src.set_segment(frag.slice, row)
+            return replace(topt, src=src)
+
+        src_rows = None
+        if has_src and topt.tanimoto_threshold > 0:
+            # Tanimoto count-windows depend on the src count, so
+            # re-derive candidates (and the union) with the real src.
+            src_rows = self._eval_tree_slices_host(
+                index, c.children[0], [f.slice for f in frags]
+            )
+            per = []
+            for frag in frags:
+                opt = with_src(frag, src_rows)
+                per.append((frag, None, opt) + frag.top_candidates_arrays(opt))
+            union = np.unique(np.concatenate([p[3] for p in per]))
+            if not len(union):
+                return {"empty": True}
 
         # Gather prep: the union scoring pass per fragment, WITHOUT the
         # kernel dispatch (all fragments score the same union, so the
-        # gathered submatrices share a shape).  Reuses each slice's
-        # candidate arrays, resolving counts only for the foreign
-        # winners (top_prepare_union_parts).
-        parts: list[tuple] = []
-        for frag, topt, cand_ids, cand_cnts in per:
-            st, sub_ref, srcw = frag.top_prepare_union_parts(
-                union, cand_ids, cand_cnts, topt
+        # gathered submatrices share a shape).  The short way first: own
+        # ⊆ union by construction, so a fragment whose candidates are as
+        # many as the union ranks exactly the union and nothing is
+        # foreign.  With a src it also needs the src to be a row of its
+        # own plane, which is where the scorer then reads it.
+        leaf = self._topn_src_leaf(index, c) if has_src else None
+        own_src = (
+            leaf[2]
+            if leaf is not None and leaf[:2] == (view.frame, view.name)
+            else None
+        )
+        short_way = not has_src or own_src is not None
+        own_mask = np.ones(len(union), dtype=bool)
+        parts: list = [None] * len(per)
+        walk: list[int] = []
+        for i, (frag, lay, _opt, cand_ids, _cnts) in enumerate(per):
+            part = None
+            if short_way and lay is not None and len(cand_ids) == len(union):
+                part = frag.top_prepare_own_parts(
+                    lay, topt.min_threshold, own_src
+                )
+            if part is not None:
+                st, sub_ref, _, src_slot = self._attach_dev_src(
+                    index, c, frag, part, leaf
+                )
+                if sub_ref is None or src_slot is not None:
+                    parts[i] = (
+                        frag,
+                        cand_ids,
+                        own_mask if sub_ref is not None else None,
+                        st,
+                        sub_ref,
+                        None,
+                        src_slot,
+                    )
+                    continue
+            walk.append(i)
+
+        # The general way for the rest: the src's host words (read for
+        # these fragments only, when the others are already settled),
+        # counts for the foreign winners, the tier split by set algebra.
+        if walk and has_src and src_rows is None:
+            # Without tanimoto, candidate filtering never reads the
+            # src — only the scorer does.  Attach it to the pass-1
+            # options instead of re-walking every candidate list.
+            src_rows = self._eval_tree_slices_host(
+                index, c.children[0], [per[i][0].slice for i in walk]
             )
-            _, _, _, src_slot = self._attach_dev_src(
-                index, c, frag, (st, sub_ref, srcw)
+        for i in walk:
+            frag, _lay, opt, cand_ids, cand_cnts = per[i]
+            if has_src and opt.src is None:
+                opt = with_src(frag, src_rows)
+            st, sub_ref, srcw, src_slot = self._attach_dev_src(
+                index,
+                c,
+                frag,
+                frag.top_prepare_union_parts(union, cand_ids, cand_cnts, opt),
+                leaf,
             )
             cand_mask = (
                 np.isin(st.cand_ids, cand_ids, assume_unique=True)
                 if st.cand_ids is not None
                 else None
             )
-            parts.append(
-                (frag, topt, cand_ids, cand_mask, st, sub_ref, srcw, src_slot)
-            )
+            parts[i] = (frag, cand_ids, cand_mask, st, sub_ref, srcw, src_slot)
         # "scores" memoizes the fetched count vectors for as long as
         # the ENTRY validates (fragments unchanged since build =>
         # scores unchanged); "score_event" single-flights the fused
@@ -2945,7 +3018,11 @@ class Executor:
         # so a 32-query storm of one TopN shape pays ONE
         # dispatch+fetch, not 32 — the topn.fetch residual ROADMAP 5
         # names.
-        return {"parts": parts, "union": len(union)}
+        return {
+            "parts": parts,
+            "union": len(union),
+            "build": "walked" if walk else "direct",
+        }
 
     def _execute_topn_folded(
         self, index: str, c: Call, slices: list[int], opt: ExecOptions
@@ -2971,11 +3048,15 @@ class Executor:
         c = plan.canonicalize_call(c)
         with self.tracer.span("topn.prep", slices=len(slices)) as sp:
             ent, how = self._topn_folded_entry(index, c, slices)
-            # ``union``: rows scored in every slice
+            # ``union``: rows scored in every slice; ``build``: whether
+            # a build walked any fragment the general way (the entry's
+            # "build"; a hit built nothing)
             sp.annotate(
                 prep_cache="two_phase" if ent.get("two_phase") else how,
                 union=ent.get("union", 0),
             )
+            if how == "built" and "build" in ent:
+                sp.annotate(build=ent["build"])
         if ent.get("empty"):
             return []
         if ent.get("two_phase"):
@@ -2985,11 +3066,11 @@ class Executor:
         # concurrent queries; scores are per-query), dispatch, fetch.
         states: list[tuple] = []
         score_parts: list[tuple] = []
-        for frag, topt, cand_ids, cand_mask, st_proto, sub_ref, srcw, src_slot in ent[
+        for frag, cand_ids, cand_mask, st_proto, sub_ref, srcw, src_slot in ent[
             "parts"
         ]:
             st = replace(st_proto, counts=None, dev_counts=None)
-            states.append((frag, topt, cand_ids, cand_mask, st))
+            states.append((frag, cand_ids, cand_mask, st))
             score_parts.append((st, sub_ref, srcw, src_slot, frag))
         # Score ONCE per validated entry: concurrent queries of the
         # same TopN shape single-flight (one leader dispatches +
@@ -3049,13 +3130,12 @@ class Executor:
         with self.tracer.span("topn.select", parts=len(states)):
             winner_ids: list[np.ndarray] = []
             fulls: list[tuple[np.ndarray, np.ndarray]] = []
-            for frag, topt, cand_ids, cand_mask, st in states:
+            has_src = len(c.children) == 1
+            for frag, cand_ids, cand_mask, st in states:
                 ids, cnts, keep, short = frag.top_score_arrays(st)
                 fulls.append((ids[keep], cnts[keep]))
-                if topt.src is None:
-                    winner_ids.append(
-                        cand_ids[: topt.n] if topt.n else cand_ids
-                    )
+                if not has_src:
+                    winner_ids.append(cand_ids[:n] if n else cand_ids)
                 elif short:
                     # Scoring short-circuited (e.g. no src segment
                     # here): the subset selection would short-circuit
@@ -3063,7 +3143,7 @@ class Executor:
                     winner_ids.append(ids)
                 else:
                     sel_ids, _ = frag.select_winners(
-                        ids, cnts, keep, cand_ids, topt.n, cand_mask=cand_mask
+                        ids, cnts, keep, cand_ids, n, cand_mask=cand_mask
                     )
                     winner_ids.append(sel_ids)
             ids2 = (
@@ -3197,14 +3277,15 @@ class Executor:
         c._topn_parsed = cached
         return cached
 
-    def _topn_options_for_slice(self, index: str, c: Call, slice_i: int, src_rows=None):
-        """reference: executor.go:346-415.  ``src_rows`` carries the
-        host-evaluated src rows from _execute_topn_slices.  Returns
-        ``(fragment, TopOptions)``, or None when the fragment does not
-        exist."""
+    def _topn_options(self, c: Call, src=None) -> TopOptions:
+        """The call's arguments as a fragment's TopOptions (reference:
+        executor.go:346-415); a walk over many fragments makes them
+        once.  Callers validate here only once a fragment exists,
+        matching the reference's ordering: a bad tanimoto over absent
+        fragments yields empty results, not an error."""
         (
-            frame,
-            view,
+            _frame,
+            _view,
             n,
             fld,
             row_ids,
@@ -3212,7 +3293,24 @@ class Executor:
             filters,
             tanimoto,
         ) = self._topn_parsed_args(c)
+        if tanimoto > 100:
+            raise ExecutorError("Tanimoto Threshold is from 1 to 100 only")
+        return TopOptions(
+            n=n,
+            src=src,
+            row_ids=list(row_ids) if row_ids else None,
+            filter_field=fld,
+            filter_values=list(filters) if filters else None,
+            min_threshold=min_threshold,
+            tanimoto_threshold=tanimoto,
+        )
 
+    def _topn_options_for_slice(self, index: str, c: Call, slice_i: int, src_rows=None):
+        """reference: executor.go:346-415.  ``src_rows`` carries the
+        host-evaluated src rows from _execute_topn_slices.  Returns
+        ``(fragment, TopOptions)``, or None when the fragment does not
+        exist."""
+        frame, view = self._topn_parsed_args(c)[:2]
         src = None
         if src_rows is not None:
             src = RowBitmap()
@@ -3223,20 +3321,7 @@ class Executor:
         f = self.holder.fragment(index, frame, view, slice_i)
         if f is None:
             return None
-        # Validated AFTER the fragment-existence early return, matching
-        # the reference's ordering (executor.go:346-415): a bad tanimoto
-        # over absent fragments yields empty results, not an error.
-        if tanimoto > 100:
-            raise ExecutorError("Tanimoto Threshold is from 1 to 100 only")
-        return f, TopOptions(
-            n=n,
-            src=src,
-            row_ids=list(row_ids) if row_ids else None,
-            filter_field=fld,
-            filter_values=list(filters) if filters else None,
-            min_threshold=min_threshold,
-            tanimoto_threshold=tanimoto,
-        )
+        return f, self._topn_options(c, src)
 
     def _prepare_topn_slice(
         self, index: str, c: Call, slice_i: int, src_rows=None

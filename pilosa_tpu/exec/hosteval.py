@@ -165,7 +165,9 @@ class HostEvaluator:
         the count is ``popcount(row AND src)`` over the fragment's
         authoritative host rows — the arithmetic ``bp.score_planes``
         runs on device, so ``top_score_arrays`` sees identical
-        vectors."""
+        vectors.  An entry prepared without a host copy of its src
+        (``src_words`` None: the device reads row ``st.src_row`` from
+        the plane) has the src read where the candidates are."""
         with self.ex.tracer.span("hosteval", kind="topn", parts=len(parts)):
             self._count("topn")
             t0 = time.monotonic()
@@ -173,13 +175,16 @@ class HostEvaluator:
             for st, sub_ref, srcw, _slot, frag in parts:
                 if sub_ref is None or st.dense_pos is None:
                     continue
-                src = np.asarray(srcw, dtype=np.uint32)
+                if srcw is None:
+                    srcw = frag._row_words_host(st.src_row)
                 ids = st.cand_ids[st.dense_pos]
                 counts = np.zeros(len(ids), dtype=np.int32)
-                for i, rid in enumerate(ids):
-                    row = frag._row_words_host(int(rid))
-                    if row is not None:
-                        counts[i] = popcount_words(row & src)
+                if srcw is not None:
+                    src = np.asarray(srcw, dtype=np.uint32)
+                    for i, rid in enumerate(ids):
+                        row = frag._row_words_host(int(rid))
+                        if row is not None:
+                            counts[i] = popcount_words(row & src)
                 st.counts = counts
                 n_rows += len(ids)
             if perf_mod.enabled():

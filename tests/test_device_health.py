@@ -414,6 +414,53 @@ def test_quarantined_device_serves_byte_identical_from_host(holder, rng):
         dh.close()
 
 
+def test_quarantined_topn_prepared_without_src_words_scores_on_the_host(
+    holder, rng, monkeypatch
+):
+    """A folded TopN(src) whose fragments all hold the src in their own
+    planes is prepared with no host copy of it (``build`` ``direct``);
+    with the device denied the host scorer reads the src row where it
+    reads the candidates, and the answer is the device's."""
+    from pilosa_tpu.exec import hosteval as hosteval_mod
+
+    _seed(holder, rng)
+    text = "TopN(Bitmap(rowID=2, frame=t), frame=t, n=6)"
+    c = new_cluster(1)
+    host = c.nodes[0].host
+    plain = Executor(holder, host=host, cluster=c)
+    try:
+        expected = _run_all(plain, [text])
+    finally:
+        plain.close()
+    assert len(expected[0][1]) == 6
+
+    built, scored = [], []
+    real_build = Executor._topn_folded_build
+    real_score = hosteval_mod.HostEvaluator.score_topn_parts
+
+    def keep_build(self, *a):
+        built.append(real_build(self, *a))
+        return built[-1]
+
+    def keep_score(self, parts):
+        scored.append([p[2] for p in parts])
+        return real_score(self, parts)
+
+    monkeypatch.setattr(Executor, "_topn_folded_build", keep_build)
+    monkeypatch.setattr(hosteval_mod.HostEvaluator, "score_topn_parts", keep_score)
+    dh = DeviceHealth(quarantine_threshold=1, open_ms=3600_000, watchdog_ms=0)
+    ex = Executor(holder, host=host, cluster=c, device_health=dh)
+    try:
+        dh.failure(dh.device_paths() + [COLLECTIVE], KIND_OOM)
+        assert _run_all(ex, [text]) == expected
+        assert [b["build"] for b in built] == ["direct"]
+        # the host scorer was handed no src words, for either slice
+        assert scored == [[None, None]]
+    finally:
+        ex.close()
+        dh.close()
+
+
 def test_persistent_fault_quarantines_then_heals_through_probe(holder, rng):
     _seed(holder, rng)
     c = new_cluster(1)

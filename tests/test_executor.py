@@ -330,8 +330,8 @@ def test_topn_src_mutated_falls_back_to_snapshot(ex, holder):
     # Drop every same-plane src slot so the host-snapshot path runs.
     orig = ex._attach_dev_src
 
-    def attach_force_host_src(index, c, frag, part):
-        st, sub, srcw, _slot = orig(index, c, frag, part)
+    def attach_force_host_src(*args):
+        st, sub, srcw, _slot = orig(*args)
         return st, sub, srcw, None
 
     ex._attach_dev_src = attach_force_host_src
@@ -1244,3 +1244,262 @@ def test_topn_folded_cache_invalidates_on_src_frame_write(ex, holder):
     (after,) = q(ex, "i", "TopN(Bitmap(rowID=0, frame=f), frame=f, n=3)")
     c1 = {p.id: p.count for p in after}
     assert c1[2] == c0[2] + 1
+
+
+# ---------------------------------------------------------------------------
+# the folded build's two ways: a part from the fragment's own layout
+# (``direct``) or walked through top_prepare_union_parts (``walked``)
+# ---------------------------------------------------------------------------
+
+
+def _traced(e, pql):
+    """``(pairs as tuples, {span name: tags})`` of one traced query."""
+    root = e.tracer.start_trace("test")
+    with root:
+        (pairs,) = q(e, "i", pql)
+    rec = e.tracer.finish_root(root)
+    return (
+        [(p.id, p.count) for p in pairs],
+        {s["name"]: s["tags"] for s in rec["spans"]},
+    )
+
+
+@pytest.fixture
+def two_ways(holder, monkeypatch):
+    """Four slices.  Frames ``f`` and ``o``: rows 0..7 in every slice,
+    all dense tier, so every slice ranks the same rows.  ``g``: the
+    same, and row 9 in slice 1 alone.  ``s``: the same rows under a
+    dense budget of 4, so rows 4..7 live in the sparse tier."""
+    import numpy as np
+
+    import pilosa_tpu.core.fragment as fr
+    from pilosa_tpu.obs import trace
+
+    rng = np.random.default_rng(33)
+    bits = []
+    for s in range(4):
+        for r in range(8):
+            cols = rng.choice(400, size=20 + 10 * r, replace=False)
+            bits += [(r, s * SLICE_WIDTH + int(col)) for col in cols]
+    idx = holder.create_index("i")
+    for name in ("f", "o", "g"):
+        idx.create_frame(name)
+        must_set_bits(holder, "i", name, bits)
+    must_set_bits(
+        holder, "i", "g", [(9, SLICE_WIDTH + col) for col in range(0, 300, 3)]
+    )
+    orig = fr.Fragment.__init__
+
+    def budget_of_four(self, *a, **kw):
+        kw.setdefault("dense_row_budget", 4)
+        orig(self, *a, **kw)
+
+    idx.create_frame("s")
+    monkeypatch.setattr(fr.Fragment, "__init__", budget_of_four)
+    must_set_bits(holder, "i", "s", bits)
+    monkeypatch.setattr(fr.Fragment, "__init__", orig)
+    assert len(holder.fragment("i", "s", "standard", 0)._sparse) == 4
+    store = holder.frame("i", "f").row_attr_store
+    for r in range(0, 8, 2):
+        store.set_attrs(r, {"cat": "a"})
+    c = new_cluster(1)
+    e = Executor(holder, host=c.nodes[0].host, cluster=c, tracer=trace.Tracer())
+    yield e, bits
+    e.close()
+
+
+def _exact(bits, src, ids=None):
+    """The exact ranking of ``bits``' rows by overlap with row ``src``."""
+    cols: dict[int, set] = {}
+    for r, col in bits:
+        cols.setdefault(r, set()).add(col)
+    pairs = [
+        (r, len(cols[r] & cols[src]))
+        for r in (ids if ids is not None else cols)
+    ]
+    return sorted([p for p in pairs if p[1]], key=lambda p: (-p[1], p[0]))
+
+
+TWO_WAYS = {
+    # every slice ranks the union itself, every row dense tier
+    "all_dense_equal": ("TopN(Bitmap(frame=f, rowID=3), frame=f, n=8)", "direct"),
+    "no_src": ("TopN(frame=f, n=8)", "direct"),
+    # row 9 is in the union and in slice 1's rank cache alone
+    "foreign_winner": ("TopN(Bitmap(frame=g, rowID=3), frame=g, n=9)", "walked"),
+    "sparse_tier_candidate": (
+        "TopN(Bitmap(frame=s, rowID=3), frame=s, n=8)", "walked"),
+    "threshold": (
+        "TopN(Bitmap(frame=f, rowID=2), frame=f, n=5, threshold=5)", "walked"),
+    "tanimoto": (
+        "TopN(Bitmap(frame=f, rowID=7), frame=f, n=4, tanimotoThreshold=20)",
+        "walked"),
+    "attr_filter": (
+        'TopN(Bitmap(frame=f, rowID=3), frame=f, n=8, field="cat", filters=["a"])',
+        "walked"),
+    "src_of_another_frame": (
+        "TopN(Bitmap(frame=o, rowID=3), frame=f, n=8)", "walked"),
+    "src_tree": (
+        "TopN(Union(Bitmap(frame=f, rowID=3), Bitmap(frame=f, rowID=4)), "
+        "frame=f, n=8)", "walked"),
+    # never folded: the per-slice protocol, through the same
+    # _top_score_parts
+    "ids": ("TopN(Bitmap(frame=f, rowID=3), frame=f, ids=[1, 3, 5, 6])", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TWO_WAYS))
+def test_topn_folded_ways_match_the_two_phase_and_per_slice_protocols(
+    two_ways, monkeypatch, case
+):
+    """Whichever way each fragment's part was prepared, the folded
+    answer is the two-phase protocol's and the per-slice protocol's,
+    and ``topn.prep`` says which way the build went."""
+    e, bits = two_ways
+    pql, build = TWO_WAYS[case]
+    got, spans = _traced(e, pql)
+    assert got, pql
+    call = parse_string(pql).calls[0]
+    two_phase = e._execute_topn_two_phase(
+        "i", call, [0, 1, 2, 3], ExecOptions(), call.args.get("n", 0)
+    )
+    assert got == [(p.id, p.count) for p in two_phase]
+    monkeypatch.setattr(Executor, "_all_slices_local", lambda *a: False)
+    (per_slice,) = q(e, "i", pql)
+    assert got == [(p.id, p.count) for p in per_slice]
+
+    if build is None:
+        assert "topn.prep" not in spans
+    else:
+        assert spans["topn.prep"]["prep_cache"] == "built"
+        assert spans["topn.prep"]["build"] == build
+    # and against the bits themselves where the ranking is exact (every
+    # row a candidate in every slice, n no trim)
+    if case == "all_dense_equal":
+        assert got == _exact(bits, 3)
+    elif case == "sparse_tier_candidate":
+        assert got == _exact(bits, 3)
+    elif case == "ids":
+        assert got == _exact(bits, 3, ids=[1, 3, 5, 6])
+    elif case == "attr_filter":
+        assert got == [p for p in _exact(bits, 3) if p[0] % 2 == 0]
+    elif case == "threshold":
+        # a threshold cuts slice by slice, so the sums are not _exact's
+        assert 1 < len(got) <= 5 and got[0] == _exact(bits, 2)[0]
+
+
+def test_topn_a_part_without_a_slot_is_scored_beside_parts_read_from_the_plane(
+    two_ways, monkeypatch
+):
+    """One fragment loses its src slot (as after a mirror refresh
+    between prepare and attach): it is walked and carries the src's host
+    snapshot, the others carry none and are scored from their planes,
+    in one answer."""
+    from pilosa_tpu.ops import bitplane as bp
+
+    e, bits = two_ways
+    # one home device, so the four members meet in the scorer's groups
+    monkeypatch.setattr(bp, "home_device", lambda slice_i: jax.devices()[0])
+    orig = e._attach_dev_src
+
+    def no_slot_in_slice_2(index, c, frag, part, leaf=None):
+        st, sub, srcw, slot = orig(index, c, frag, part, leaf)
+        return st, sub, srcw, (None if frag.slice == 2 else slot)
+
+    monkeypatch.setattr(e, "_attach_dev_src", no_slot_in_slice_2)
+    built = []
+    real = Executor._topn_folded_build
+
+    def keep(self, *a):
+        built.append(real(self, *a))
+        return built[-1]
+
+    monkeypatch.setattr(Executor, "_topn_folded_build", keep)
+    got, spans = _traced(e, "TopN(Bitmap(frame=f, rowID=5), frame=f, n=8)")
+    assert got == _exact(bits, 5)
+    assert spans["topn.prep"]["build"] == "walked"
+    assert spans["topn.dispatch"]["groups"] == 2  # by where the src is read
+    # (frag, cand_ids, cand_mask, st, sub_ref, src_words, src_slot)
+    words = {p[0].slice: p[5] is not None for p in built[0]["parts"]}
+    assert words == {0: False, 1: False, 2: True, 3: False}
+
+
+def test_topn_second_distinct_text_walks_nothing_and_copies_no_row(
+    two_ways, monkeypatch
+):
+    """On an unchanged index a new text asks the fragments nothing the
+    last one did not: no set algebra, no tier split, no 128 KiB copy."""
+    import pilosa_tpu.core.fragment as fr
+
+    e, bits = two_ways
+    assert _traced(e, "TopN(Bitmap(frame=f, rowID=0), frame=f, n=8)")[0]
+    calls = {"top_prepare_union_parts": 0, "_row_words_host": 0,
+             "_tier_split_locked": 0}
+    for name in calls:
+        real = getattr(fr.Fragment, name)
+
+        def spy(self, *a, _name=name, _real=real, **kw):
+            calls[_name] += 1
+            return _real(self, *a, **kw)
+
+        monkeypatch.setattr(fr.Fragment, name, spy)
+    got, spans = _traced(e, "TopN(Bitmap(frame=f, rowID=1), frame=f, n=8)")
+    assert got == _exact(bits, 1)
+    assert spans["topn.prep"]["build"] == "direct"
+    assert calls == {"top_prepare_union_parts": 0, "_row_words_host": 0,
+                     "_tier_split_locked": 0}
+
+
+@pytest.mark.parametrize("write", ["SetBit", "ClearBit", "re-sort"])
+def test_topn_layout_is_derived_again_after_a_write_or_a_re_sort(two_ways, write):
+    """The fragment's layout lives as long as its version and the rank
+    cache's arrays: a write to one fragment, or a re-sort of its rank
+    cache, makes the next build derive that fragment's again, and the
+    answer is the fresh one."""
+    e, bits = two_ways
+    frags = [e.holder.fragment("i", "f", "standard", s) for s in range(4)]
+    assert _traced(e, "TopN(Bitmap(frame=f, rowID=0), frame=f, n=8)")[0]
+    before = [fr_.top_layout() for fr_ in frags]
+    assert [fr_.top_layout() for fr_ in frags] == before  # kept, not remade
+
+    row1 = {col for r, col in bits if r == 1}
+    row6 = {col for r, col in bits if r == 6}
+    in_slice_2 = lambda cols: sorted(  # noqa: E731
+        col for col in cols if col // SLICE_WIDTH == 2)
+    if write == "SetBit":
+        col = in_slice_2(row1 - row6)[0]
+        q(e, "i", f"SetBit(frame=f, rowID=6, columnID={col})")
+        bits = bits + [(6, col)]
+    elif write == "ClearBit":
+        col = in_slice_2(row1 & row6)[0]
+        q(e, "i", f"ClearBit(frame=f, rowID=6, columnID={col})")
+        bits = [b for b in bits if b != (6, col)]
+    else:
+        frags[2].cache.recalculate()
+
+    got, spans = _traced(e, "TopN(Bitmap(frame=f, rowID=1), frame=f, n=8)")
+    assert spans["topn.prep"]["build"] == "direct"
+    assert got == _exact(bits, 1)
+    after = [fr_.top_layout() for fr_ in frags]
+    assert [a is b for a, b in zip(after, before)] == [True, True, False, True]
+    # a re-sort that lists the same rows keeps the tier split it had
+    assert (after[2].slots is before[2].slots) == (write == "re-sort")
+    c = new_cluster(1)
+    fresh = Executor(e.holder, host=c.nodes[0].host, cluster=c)
+    (same,) = q(fresh, "i", "TopN(Bitmap(frame=f, rowID=1), frame=f, n=8)")
+    assert got == [(p.id, p.count) for p in same]
+    fresh.close()
+
+
+def test_topn_a_re_sort_reaches_the_next_text_through_the_layout(two_ways):
+    """Cached counts come from the layout: after a forced re-sort the
+    next plain TopN ranks by the counts the writes left."""
+    e, bits = two_ways
+    (before, _) = _traced(e, "TopN(frame=f, n=8)")
+    assert before[0][0] == 7  # the fullest row
+    for col in range(1000, 1400):
+        q(e, "i", f"SetBit(frame=f, rowID=0, columnID={col})")
+    frag = e.holder.fragment("i", "f", "standard", 0)
+    frag.cache.recalculate()
+    after, spans = _traced(e, "TopN(frame=f, n=7)")
+    assert spans["topn.prep"]["build"] == "direct"
+    assert after[0] == (0, dict(before)[0] + 400)

@@ -364,14 +364,14 @@ def test_a_prep_entry_is_not_charged_for_mirrors_the_pool_already_holds(tmp_path
         # the prep entry that points at them added nothing
         assert grew == {"resident_bytes": mirrors, "cache_bytes": 0}
         assert len(ex._topn_cache) == 1
-        assert all(fr.mirror_is(p[5].plane)
+        assert all(fr.mirror_is(p[4].plane)
                    for fr, p in zip(frags, next(iter(ex._topn_cache.values()))["parts"]))
         # a snapshot that a write has replaced is the entry's own to carry
         key = next(iter(ex._topn_cache))
         ent = ex._topn_cache[key]
         f.set_bit("standard", 2, 9)
         frags[0].device_plane()  # the refresh: a new mirror array
-        assert not frags[0].mirror_is(ent["parts"][0][5].plane)
+        assert not frags[0].mirror_is(ent["parts"][0][4].plane)
     finally:
         ex.close()
         holder.close()

@@ -392,7 +392,10 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
     first = _last_trace(c)
     assert len(first["spans"]) <= 24
     prep = _span(first, "topn.prep")
-    assert prep["tags"] == {"slices": slices, "prep_cache": "built", "union": 2}
+    # every fragment ranks the union itself and holds the src in its
+    # plane: no fragment walked, no host copy of the src (``build``)
+    assert prep["tags"] == {"slices": slices, "prep_cache": "built", "union": 2,
+                            "build": "direct"}
     disp = _span(first, "topn.dispatch")
     launches = -(-slices // bp.SCORE_GROUP)
     assert disp["tags"]["launches"] == launches and disp["tags"]["groups"] == 1
@@ -413,6 +416,7 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
     assert topn(TOPN_SRC) == want
     again = _last_trace(c)
     assert _span(again, "topn.prep")["tags"]["prep_cache"] == "hit"
+    assert "build" not in _span(again, "topn.prep")["tags"]  # nothing built
     assert _span(again, "topn.score")["tags"]["score_cache"] == "shared"
     assert not {"topn.dispatch", "compile"} & {s["name"] for s in again["spans"]}
 
@@ -421,6 +425,7 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
         (1, slices), (2, slices)]
     other = _last_trace(c)
     assert _span(other, "topn.score")["tags"]["score_cache"] == "computed"
+    assert _span(other, "topn.prep")["tags"]["build"] == "direct"
     assert "compile" not in {s["name"] for s in other["spans"]}
     # a TopN(src) that is scored has these spans whatever the slice count
     assert sorted(s["name"] for s in other["spans"]) == sorted([

@@ -11,10 +11,11 @@ no deadline header unless ``--deadline-ms`` says one (the server's
 default is 60 s), checks the answer against the kind's reference and
 prints the request's spans from ``/debug/traces``: ``topn.prep``,
 ``topn.dispatch`` and the ``compile`` under it, ``topn.fetch``,
-``topn.select``.  Then it stops the server and boots it again on the
-same data: a new process, so the first request there shows what the
-persistent compile cache saves.  Last, ``--concurrent`` distinct texts
-at once, as the cell's warm-up sends them.  One JSON document on
+``topn.select``, and under ``stages`` their milliseconds on one line with
+``topn.prep``'s ``prep_cache`` and ``build`` tags.  Then it stops the server
+and boots it again on the same data: a new process, so the first request
+there shows what the persistent compile cache saves.  Last,
+``--concurrent`` distinct texts at once, as the cell's warm-up sends them.  One JSON document on
 stdout, also written to ``chiprun_out/topn_probe.json``.  The parent of
 the server never initialises a JAX backend.
 """
@@ -84,6 +85,19 @@ def spans_of(server: Server, trace_id: str) -> list[dict]:
     return []
 
 
+def stages(spans: list[dict]) -> dict:
+    """A request's stages on one line: the milliseconds of each TopN
+    span, how the prep entry was come by (``prep_cache``) and, where it
+    was built, whether any fragment was walked (``build``; a program
+    from before that tag prints None)."""
+    by_name = {s["name"]: s for s in spans}
+    out = {name.removeprefix("topn."): by_name[name]["ms"]
+           for name in ("topn.prep", "topn.dispatch", "topn.fetch", "topn.select")
+           if name in by_name}
+    tags = by_name.get("topn.prep", {}).get("tags", {})
+    return dict(out, prep_cache=tags.get("prep_cache"), build=tags.get("build"))
+
+
 def probe(server: Server, cell, ref, srcs, deadline_ms: int) -> list[dict]:
     cfg, out = cell.config, []
     for src in srcs:
@@ -96,6 +110,7 @@ def probe(server: Server, cell, ref, srcs, deadline_ms: int) -> list[dict]:
             rec["correct"] = (cell.kind.normalise(answer)
                               == ref.answer(("TopN", src, cfg["n"])))
         rec["spans"] = spans_of(server, rec["trace_id"])
+        rec["stages"] = stages(rec["spans"])
         run.say(json.dumps(rec))
         out.append(rec)
     return out
@@ -188,6 +203,8 @@ def main(argv=None) -> int:
         for rec in got:
             rec.pop("answer", None)
             rec["spans"] = spans_of(again, rec["trace_id"])
+            rec["stages"] = stages(rec["spans"])
+            run.say(json.dumps({k: rec[k] for k in ("text", "wall_s", "stages")}))
         doc["concurrent"] = {"wall_s": time.monotonic() - t0, "requests": got}
         hbm = again.get_json("/debug/hbm")
         doc["hbm_second"] = {k: hbm[k] for k in ("resident_bytes", "cache_bytes",
