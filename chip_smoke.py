@@ -76,6 +76,14 @@ BSI_FIELD = "q"
 BSI_SLICES = 4
 BSI_COLUMNS_PER_SLICE = 2000
 BSI_MIN, BSI_MAX = -1000, 1000
+# A time Range is a union over a frame's time views, the tree the
+# in-place aggregate does not take: a Sum filtered by one goes through
+# the leaf batch, so that way's "agg" reduce still meets the chip.  Frame
+# f has no time quantum, so the Range selects nothing and its Union with
+# a comparison is the comparison.
+TIME_RANGE = (
+    f'Range(frame={FRAME}, rowID=0, start="2017-01-01T00:00", end="2018-01-01T00:00")'
+)
 
 # BASELINE.json's own n ("TopN(n=100)"): at least the rows, so every row
 # is a candidate and the scorer runs the program a restart prewarms.
@@ -93,7 +101,7 @@ DEADLINE_MS = 600_000
 # did not launch is named in the result and not skipped.
 SITES = (
     "direct", "coalesce", "interp", "total", "collective",
-    "topn", "fetch", "anchored", "hosteval",
+    "topn", "fetch", "agg", "anchored", "hosteval",
 )
 
 LOG_MUST_NOT_HAVE = (
@@ -506,6 +514,13 @@ class Run:
         log(f"loaded {n_bits} bits over {self.args.slices} slices x "
             f"{self.args.rows} rows in {dt:.1f} s ({n_bits / dt:,.0f} bits/s)")
 
+    def bsi_ways(self) -> tuple[int, int]:
+        """How many BSI aggregates went in place and how many through
+        the leaf batch, by the program's own counters."""
+        m = self.server.metrics()
+        return (int(m.get("pilosa_exec_bsi_inPlace_total", 0)),
+                int(m.get("pilosa_exec_bsi_batch_total", 0)))
+
     def row_pairs(self) -> list[tuple[int, int]]:
         """Eight distinct row pairs over the two dense rows and a few
         sparse ones — more than the 4-entry batch cache holds, so the
@@ -606,11 +621,30 @@ class Run:
             f"{self.timings['topn_src_cold_s']} s")
 
         vals = orc.vals()
+        before = self.bsi_ways()
         got = srv.query(f"Sum(frame={BSI_FRAME}, field={BSI_FIELD})")
         self.expect(
             "Sum(frame=v, field=q)", (got["value"], got["count"]),
             (int(vals.sum()), int(vals.size)),
         )
+        # Cold mirrors take the leaf batch where it fits the chip, as
+        # here; the prefetcher, kicked as the query came in, may have
+        # brought them by the time the aggregate looks, and then it goes
+        # in place.  Either way it is one of the two, and the next check
+        # sends a tree that takes the batch whatever is resident.
+        first = self.bsi_ways()
+        self.expect("Sum(frame=v, field=q), the field's planes cold, went one way",
+                    sum(first) - sum(before), 1)
+        got = srv.query(
+            f"Sum(Union({TIME_RANGE}, Range(frame={BSI_FRAME}, {BSI_FIELD} > 100)), "
+            f"frame={BSI_FRAME}, field={BSI_FIELD})"
+        )
+        self.expect("Sum(Union(Range(f, start, end), Range(q > 100)), frame=v, field=q)",
+                    (got["value"], got["count"]),
+                    (int(vals[vals > 100].sum()), int((vals > 100).sum())))
+        after = self.bsi_ways()
+        self.expect("a Sum under a time Range went through the leaf batch",
+                    (after[0] - first[0], after[1] - first[1]), (0, 1))
         got = srv.query(f"Count(Range(frame={BSI_FRAME}, {BSI_FIELD} > 100))")
         self.expect("Count(Range(q > 100))", got, int((vals > 100).sum()))
 
@@ -695,6 +729,7 @@ class Run:
         self.expect(f"{label}: TopN(Bitmap({r}), frame=f, n={TOPN})",
                     pairs_of(got), orc.topn(TOPN, src=r))
         vals = orc.vals()
+        before = self.bsi_ways()
         got = srv.query(
             f"Sum(Range(frame={BSI_FRAME}, {BSI_FIELD} > 0), "
             f"frame={BSI_FRAME}, field={BSI_FIELD})"
@@ -702,6 +737,15 @@ class Run:
         self.expect(f"{label}: Sum(Range(q > 0), frame=v, field=q)",
                     (got["value"], got["count"]),
                     (int(vals[vals > 0].sum()), int((vals > 0).sum())))
+        # and the plain Sum, whose program a restart prewarms from the
+        # holder's own fields: the first boot has to have compiled it
+        got = srv.query(f"Sum(frame={BSI_FRAME}, field={BSI_FIELD})")
+        self.expect(f"{label}: Sum(frame=v, field=q)", (got["value"], got["count"]),
+                    (int(vals.sum()), int(vals.size)))
+        after = self.bsi_ways()
+        self.expect(
+            f"{label}: the multi-slice Sums over resident planes went in place",
+            (after[0] - before[0], after[1] - before[1]), (2, 0))
 
     def after_restart(self) -> None:
         srv, orc = self.server, self.oracle
@@ -799,7 +843,8 @@ class Run:
             f"{label}: no launch was answered by hosteval",
             sites["hosteval"] == 0, f"{sites['hosteval']} host evaluations",
         )
-        need = {"topn": sites["topn"], "fetch": sites["fetch"]}
+        need = {"topn": sites["topn"], "fetch": sites["fetch"],
+                "agg (the in-place aggregate)": sites["agg"]}
         if n_dev > 1:
             # The ICI-reduced limb count (plan.compiled_total_count and
             # the interpreter's "total") records as site "collective".

@@ -19,8 +19,8 @@ _FULL = 0xFFFFFFFF
 
 
 def _bit_mask(word, xp):
-    """uint32 scalar word (0/1) -> all-ones/all-zeros uint32 mask,
-    without overflow-warning-prone unsigned negation."""
+    """uint32 word (0/1), or a vector of them -> all-ones/all-zeros
+    uint32 masks, without overflow-warning-prone unsigned negation."""
     return (word & xp.uint32(1)) * xp.uint32(_FULL)
 
 
@@ -32,12 +32,16 @@ def magnitude_cmp(exists, planes, pred_bits, xp):
     eq = exists
     lt = xp.zeros_like(exists)
     gt = xp.zeros_like(exists)
+    # every plane's mask and its complement in two vector operations:
+    # made a word at a time they are a handful of scalar device
+    # operations a plane, in every launch of every program
+    masks = _bit_mask(pred_bits, xp)
+    nmasks = ~masks
     for k in reversed(range(len(planes))):
         b = planes[k]
-        m = _bit_mask(pred_bits[k], xp)
-        lt = lt | (eq & ~b & m)
-        gt = gt | (eq & b & ~m)
-        eq = eq & (b ^ ~m)
+        lt = lt | (eq & ~b & masks[k])
+        gt = gt | (eq & b & nmasks[k])
+        eq = eq & (b ^ nmasks[k])
     return lt, eq, gt
 
 

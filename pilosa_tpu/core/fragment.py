@@ -1456,6 +1456,43 @@ class Fragment:
         with self._mu:
             return row_id in self._slot_of or row_id in self._sparse
 
+    def _slots_locked(self, row_ids) -> list | None:
+        """The plane slot of each row, -1 for a row the fragment does
+        not hold; None when a row lives in the sparse tier."""
+        slots = []
+        for row_id in row_ids:
+            slot = self._slot_of.get(row_id)
+            if slot is None:
+                if row_id in self._sparse:
+                    return None
+                slot = -1
+            slots.append(slot)
+        return slots
+
+    def slots_of(self, row_ids) -> tuple | None:
+        """``([slot, ...], version)``: where each row lies in the plane
+        (-1: not held) and the version that holds for, with no device
+        work — a layout to keep while the version stands.  None when a
+        row lives in the sparse tier: no plane holds it."""
+        with self._mu:
+            slots = self._slots_locked(row_ids)
+            return None if slots is None else (slots, self._version)
+
+    def fresh_mirror(self, version: int):
+        """The device mirror if it is resident and current at
+        ``version``, else None.  No upload, no pool touch: a reader that
+        kept slots from ``slots_of`` at that version asks this first,
+        and takes ``gather_slots`` (an upload or a scatter on the way)
+        only when it says None.  The mirror and the version it holds
+        for are read under the lock that publishes them, so an
+        acknowledged write is never answered from the array before it.
+        The mirror is an immutable snapshot: a later write publishes a
+        new array."""
+        with self._mu:
+            if self._version != version or self._device_version != version:
+                return None
+            return self._device
+
     def gather_slots(self, row_ids) -> tuple | None:
         """``(mirror snapshot, [slot, ...])`` for the device gather of a
         leaf batch (exec/executor.py, ``bp.gather_planes``): the slot of
@@ -1466,14 +1503,9 @@ class Fragment:
         is None, and nothing is uploaded, when no row is held.  None when
         a row lives in the sparse tier: no plane holds it."""
         with self._mu:
-            slots = []
-            for row_id in row_ids:
-                slot = self._slot_of.get(row_id)
-                if slot is None:
-                    if row_id in self._sparse:
-                        return None
-                    slot = -1
-                slots.append(slot)
+            slots = self._slots_locked(row_ids)
+            if slots is None:
+                return None
             if max(slots, default=-1) < 0:
                 return None, slots
             return self.device_plane(), slots

@@ -243,6 +243,16 @@ class PlanePool:
             if key in self._entries:
                 self._entries.move_to_end(key)
 
+    def touch_many(self, keys) -> None:
+        """``touch`` each of ``keys`` under one hold of the lock: a
+        query that reads hundreds of resident mirrors keeps them recent
+        without taking the pool's lock once a mirror."""
+        with self._mu:
+            entries = self._entries
+            for key in keys:
+                if key in entries:
+                    entries.move_to_end(key)
+
     def resize(
         self, key: tuple, bytes_by_device: dict, info: dict | None = None
     ) -> None:

@@ -399,6 +399,9 @@ class Executor:
         # walks, union assembly, and gather prep cached per (query,
         # slice set), validated like _batch_cache entries.
         self._topn_cache: "OrderedDict[tuple, dict]" = OrderedDict()
+        # Slot layouts of the in-place BSI aggregate (see
+        # _agg_view_layout): host integers, no device bytes.
+        self._agg_layouts: "OrderedDict[tuple, dict]" = OrderedDict()
         # slice->node grouping LRU (see _slices_by_node) — host-only
         # dicts, no device bytes, so unlike the two caches above it is
         # NOT a residency-pool tenant; the count cap bounds it.
@@ -767,7 +770,8 @@ class Executor:
             )
         return frame, f
 
-    def _bsi_field_leaves(self, frame: str, fld) -> tuple[list[Call], int]:
+    @staticmethod
+    def _bsi_field_leaves(frame: str, fld) -> tuple[list[Call], int]:
         """The plane leaves of one field, padded to its depth bucket:
         exists, sign, ``depth`` magnitude planes, then all-zero pads —
         so every field in a bucket shares one compile shape (and one
@@ -854,13 +858,24 @@ class Executor:
         fld = f.bsi_field(field_name)
         if fld is None:
             raise ExecutorError(f"unknown field: {field_name!r}")
-        leaves, bucket = self._bsi_field_leaves(frame, fld)
-        has_filter = bool(c.children)
-        if has_filter:
-            leaves.append(self._rewrite_bsi(index, c.children[0]))
+        return self.bsi_agg_call(
+            c.name,
+            frame,
+            fld,
+            self._rewrite_bsi(index, c.children[0]) if c.children else None,
+        )
+
+    @staticmethod
+    def bsi_agg_call(name: str, frame: str, fld, filter_call: Call | None = None) -> Call:
+        """The synthetic aggregate node of ``name`` (Sum / Min / Max)
+        over one field's plane leaves, with an already-rewritten filter
+        tree or none."""
+        leaves, bucket = Executor._bsi_field_leaves(frame, fld)
+        if filter_call is not None:
+            leaves.append(filter_call)
         return Call(
-            "Bsi" + c.name,
-            {"filter": has_filter, "nplanes": bucket},
+            "Bsi" + name,
+            {"filter": filter_call is not None, "nplanes": bucket},
             children=leaves,
         )
 
@@ -2361,7 +2376,14 @@ class Executor:
         ValCount, or None when no slice holds a valued column.  Rides
         the device-health gate like the Count path: a quarantined (or
         finally-failed) launch decodes host-computed partial vectors —
-        the same ripple arithmetic through the numpy backend."""
+        the same ripple arithmetic through the numpy backend.
+
+        The per-slice partial vectors come one of two ways, chosen from
+        what the fragments show (:meth:`_agg_in_place_prep`): IN PLACE,
+        one program over the resident plane mirrors, when every leaf is
+        a slot of a dense plane, a predicate or a pad; else through the
+        leaf batch (:meth:`_bsi_agg_batch`).  Both end in the same
+        vectors and the same decode."""
         if not slices:
             return None
         rc = self._rewrite_bsi_agg(index, c)
@@ -2371,6 +2393,28 @@ class Executor:
         if mode == health_mod.MODE_DENY:
             parts = self.hosteval.agg_partials(index, rc, slices)
             return self._decode_agg_parts(c, bucket, parts.values())
+        with self.tracer.span("bsi.agg", slices=len(slices)) as sp:
+            with self.tracer.span("bsi.prep") as ps:
+                prep = self._agg_in_place_prep(index, rc, slices, ps)
+            if isinstance(prep, str):
+                sp.annotate(way="batch", reason=prep)
+                self.holder.stats.count("exec.bsi.batch")
+                vecs = self._bsi_agg_batch(index, rc, slices, paths, mode)
+            else:
+                sp.annotate(
+                    way="in_place", planes=prep["planes"], bytes=prep["bytes"]
+                )
+                self.holder.stats.count("exec.bsi.inPlace")
+                vecs = self._bsi_agg_in_place(index, rc, prep, paths, mode, sp)
+            if vecs is None:
+                return None
+            with self.tracer.span("bsi.decode", vectors=len(vecs)):
+                return self._decode_agg_parts(c, bucket, vecs)
+
+    def _bsi_agg_batch(self, index: str, rc: Call, slices, paths, mode):
+        """The partial vectors through the assembled leaf batch (the
+        batch cache, the coalescer's "agg" reduce): the way of a tree
+        the in-place program does not take."""
         ent = self._cached_batch(index, rc, slices)
         if ent["batch"] is None:
             if mode == health_mod.MODE_PROBE:
@@ -2404,23 +2448,366 @@ class Executor:
             host_fn=lambda: self.hosteval.agg_partials(index, rc, kept),
         )
         if isinstance(res, dict):
-            vecs = list(res.values())
-        else:
-            res = np.asarray(res)
-            vecs = [res[p] for p in ent["pos_of"].values()]
-        return self._decode_agg_parts(c, bucket, vecs)
+            return list(res.values())
+        res = np.asarray(res)
+        return [res[p] for p in ent["pos_of"].values()]
+
+    # Slot layouts kept for the in-place aggregate, one a (view, row
+    # ids, slice set): host integers alone — a mirror is the residency
+    # pool's to hold, and is asked of its fragment at every answer — so
+    # the count cap is the whole bound.  A field's rows are the same for
+    # every text; a date frame adds an entry a row that is asked for.
+    _AGG_LAYOUT_CAP = 256
+
+    def _agg_view_layout(self, view, row_ids: tuple, slices_key: tuple, frags):
+        """Where ``row_ids`` lie in the planes of ``view``'s fragments
+        over a slice set: ``{"slots": int32[n, k] (-1: not held),
+        "rows": each fragment's plane rows (0: none), "versions": the
+        fragment versions the slots hold for, "sparse": a row lives in
+        the sparse tier}``.  Kept across answers and validated as the
+        batch cache validates (the write epoch, then the version
+        vector): an answer over unchanged fragments does no per-row
+        lookup at all."""
+        n = len(frags)
+        if view is None:  # the frame has no such view yet: no row is held
+            return {
+                "versions": [0] * n,
+                "slots": np.full((n, len(row_ids)), -1, dtype=np.int32),
+                "rows": np.zeros(n, dtype=np.int32),
+                "held": np.zeros(n, dtype=bool),
+                "sparse": False,
+            }
+        key = (view.index, view.frame, view.name, row_ids, slices_key)
+        epoch = fragment_mod.write_epoch()
+        with self._batch_mu:
+            ent = self._agg_layouts.get(key)
+            if ent is not None:
+                self._agg_layouts.move_to_end(key)
+        if ent is not None:
+            if ent["epoch"] == epoch:
+                return ent
+            if ent["serials"] == tuple(
+                None if f is None else (f._serial, f._version) for f in frags
+            ):
+                ent["epoch"] = epoch
+                return ent
+        slots = np.full((n, len(row_ids)), -1, dtype=np.int32)
+        rows = np.zeros(n, dtype=np.int32)
+        versions = [0] * n
+        serials: list = [None] * n
+        sparse = False
+        for i, f in enumerate(frags):
+            if f is None:
+                continue
+            got = f.slots_of(row_ids)
+            if got is None:
+                sparse = True
+                break
+            slots[i], versions[i] = got
+            serials[i] = (f._serial, versions[i])
+            rows[i] = f.plane_rows()
+        ent = {
+            "epoch": epoch,
+            "serials": tuple(serials),
+            "versions": versions,
+            "slots": slots,
+            "rows": rows,
+            "held": slots.max(axis=1, initial=-1) >= 0,
+            "sparse": sparse,
+        }
+        with self._batch_mu:
+            self._agg_layouts[key] = ent
+            while len(self._agg_layouts) > self._AGG_LAYOUT_CAP:
+                self._agg_layouts.popitem(last=False)
+        return ent
+
+    @staticmethod
+    def _agg_columns(leaves):
+        """What a call's text alone decides of the in-place program:
+        ``(cols, units)`` as ``bp.aggregate_planes`` takes them, or None
+        for a tree it does not take (a time-quantum Range is a union
+        over views).  A field's planes are one ``"whole"`` unit — the
+        aggregate and the comparisons read most of their rows — and a
+        Bitmap's row a ``"tile"`` unit of its own; a predicate is an
+        operand and a depth-bucket pad a zero.  Which rows a fragment
+        keeps, and where, is the slot table's to say: data."""
+        cols: list[tuple] = []
+        units: list[str] = []
+        n_rows: list[int] = []  # row leaves a unit
+        field_unit: dict[tuple, int] = {}
+        n_pred = 0
+        for leaf in leaves:
+            if leaf.name == "BsiPred":
+                cols.append(("pred", n_pred))
+                n_pred += 1
+            elif leaf.name == "BsiZero":
+                cols.append(("zero",))
+            elif leaf.name in ("BsiPlane", "Bitmap"):
+                u = len(units)
+                if leaf.name == "BsiPlane":
+                    u = field_unit.setdefault((leaf.args["frame"], leaf.args["field"]), u)
+                if u == len(units):
+                    units.append("whole" if leaf.name == "BsiPlane" else "tile")
+                    n_rows.append(0)
+                cols.append(("row", u, n_rows[u]))
+                n_rows[u] += 1
+            else:
+                return None
+        # the slot table holds a unit's columns side by side
+        first = [sum(n_rows[:u]) for u in range(len(units))]
+        return (
+            tuple(
+                ("row", c[1], first[c[1]] + c[2]) if c[0] == "row" else c for c in cols
+            ),
+            tuple(units),
+        )
+
+    def _agg_batch_fits(self, batch_rows: int, plane_rows: int) -> bool:
+        """Whether the leaf batches of such a text that the batch cache
+        may keep (``_BATCH_CACHE_CAP``) fit a device's budget beside the
+        planes they are copied from (the prefetcher uploads those
+        whichever way the answer goes)."""
+        budget = device_mod.pool().budget_bytes()
+        rows = self._BATCH_CACHE_CAP * batch_rows + plane_rows
+        return not budget or (
+            perf_mod.plane_bytes(rows, bp.WORDS_PER_SLICE)
+            <= budget * bp.mesh_device_count()
+        )
+
+    def _agg_in_place_prep(self, index: str, rc: Call, slices: list[int], sp):
+        """What the in-place aggregate launches, from one sweep of the
+        fragments (no plane is read): ``{"expr", "cols", "units",
+        "preds", "groups", "kept", ...}`` — or, as a string, the reason
+        the tree goes through the leaf batch: ``time_range``
+        (:meth:`_agg_columns`), ``sparse_tier`` (a row no plane holds),
+        ``cold_mirrors`` (most mirrors are not on the device and the
+        leaf batch fits it, :meth:`_agg_batch_fits`: the host fills the
+        rows it reads, as for a Count, and the prefetcher brings the
+        planes for the next answer; where it does not fit — a fact
+        table's first answers — the planes upload on the way).
+
+        A ``group`` is the kept members of one device whose planes share
+        a shape a unit: ``(members, planes, slots)`` as
+        ``bp.aggregate_planes`` takes them."""
+        expr, leaves = plan.decompose(rc)
+        layout = self._agg_columns(leaves)
+        if layout is None:
+            return "time_range"
+        cols, units = layout
+        sweep = self._leaf_sweep(index, leaves, slices)
+        n = len(slices)
+        # what each unit reads: [view, fragments, row ids]
+        reads: list[list] = [[None, None, []] for _ in units]
+        for col, ent in zip(cols, sweep):
+            if col[0] == "row":
+                reads[col[1]][:2] = ent[1], ent[3]
+                reads[col[1]][2].append(ent[2])
+        slices_key = tuple(slices)
+        layouts = [
+            self._agg_view_layout(view, tuple(row_ids), slices_key, frags)
+            for view, frags, row_ids in reads
+        ]
+        if any(lay["sparse"] for lay in layouts):
+            return "sparse_tier"
+        # a slice takes part where it holds the field's not-null row
+        # (leaf 0: without it no column has a value there)
+        at = np.flatnonzero(layouts[0]["slots"][:, 0] >= 0)
+        kept = [slices[i] for i in at]
+        # the mirrors, where they are resident and current; which are cold
+        planes: list[list] = [[None] * n for _ in units]
+        stale: list[tuple[int, int]] = []
+        n_frag = n_cold = plane_rows = 0
+        counted: set = set()
+        for u, ((view, frags, _), lay) in enumerate(zip(reads, layouts)):
+            held, versions, mine = lay["held"], lay["versions"], planes[u]
+            first = id(view) not in counted  # two Bitmaps of one frame: one plane
+            counted.add(id(view))
+            for i in at:
+                if not held[i]:
+                    continue
+                f = frags[i]
+                mine[i] = f.fresh_mirror(versions[i])
+                if mine[i] is None:
+                    stale.append((u, i))
+                if first:
+                    n_frag += 1
+                    n_cold += f._device is None
+                    plane_rows += int(lay["rows"][i])
+        sp.annotate(slices=len(kept), cold=n_cold)
+        if n_cold * 2 > n_frag and self._agg_batch_fits(
+            len(leaves) * plan.slice_bucket(n), plane_rows
+        ):
+            return "cold_mirrors"
+        if not kept:
+            return {"kept": kept, "groups": [], "planes": 0, "bytes": 0}
+        # a mirror that is cold, or behind a write, is asked of its
+        # fragment (an upload or a scatter on the way) with its slots
+        tables = [lay["slots"] for lay in layouts]
+        rows = [lay["rows"] for lay in layouts]
+        for u, i in stale:
+            ref = reads[u][1][i].gather_slots(reads[u][2])
+            if ref is None:
+                return "sparse_tier"
+            if tables[u] is layouts[u]["slots"]:
+                tables[u], rows[u] = tables[u].copy(), rows[u].copy()
+            planes[u][i], tables[u][i] = ref
+            rows[u][i] = 0 if ref[0] is None else int(ref[0].shape[0])
+        device_mod.pool().touch_many(
+            [
+                frags[i]._pool_key
+                for u, (_, frags, _) in enumerate(reads)
+                for i in at
+                if planes[u][i] is not None
+            ]
+        )
+        table = np.concatenate([t[at] for t in tables], axis=1)
+        n_held = int((table >= 0).sum())
+        preds = [
+            bsi.pred_row(leaf.args["v"], leaf.args["d"])[: bp.PRED_WORDS]
+            for leaf in leaves
+            if leaf.name == "BsiPred"
+        ]
+        return {
+            "expr": expr,
+            "cols": cols,
+            "units": units,
+            "preds": (
+                np.stack(preds)
+                if preds
+                else np.zeros((0, bp.PRED_WORDS), dtype=np.uint32)
+            ),
+            "groups": self._agg_member_groups(
+                kept,
+                [[mine[i] for i in at] for mine in planes],
+                [r[at] for r in rows],
+                table,
+            ),
+            "kept": kept,
+            "planes": n_held,
+            "bytes": perf_mod.plane_bytes(n_held, bp.WORDS_PER_SLICE),
+        }
+
+    @staticmethod
+    def _agg_member_groups(kept: list[int], planes, rows, table):
+        """The launch groups of an in-place aggregate: the kept slices
+        by home device and by the shape of their planes, a view at a
+        time (the jit key holds both), each ``(slices, planes, slots)``
+        as ``bp.aggregate_planes`` takes them — ``planes`` member-major,
+        a view each.  ``planes[v][m]`` / ``rows[v][m]``: member ``m``'s
+        mirror of view ``v`` and its rows (None / 0: it holds none of
+        the view's rows); such a member rides with a neighbour's plane
+        and reads no row of it."""
+        shape_of = np.stack(
+            [np.asarray(kept, dtype=np.int64) % bp.mesh_device_count(), *rows], axis=1
+        )
+        for v in range(1, shape_of.shape[1]):
+            none = shape_of[:, v] == 0
+            for d in np.unique(shape_of[none, 0]):
+                on_d = shape_of[:, 0] == d
+                some = shape_of[on_d & ~none, v]
+                shape_of[on_d & none, v] = some[0] if some.size else -1
+        _, which = np.unique(shape_of, axis=0, return_inverse=True)
+        which = which.reshape(-1)
+        groups = []
+        for u in range(int(which.max()) + 1):
+            members = np.flatnonzero(which == u)
+            stand_in = [
+                next((p[m] for m in members if p[m] is not None), None)
+                for p in planes
+            ]
+            any_plane = next(p for p in stand_in if p is not None)
+            stand_in = [any_plane if p is None else p for p in stand_in]
+            groups.append(
+                (
+                    [kept[m] for m in members],
+                    [
+                        p[m] if p[m] is not None else stand_in[v]
+                        for m in members
+                        for v, p in enumerate(planes)
+                    ],
+                    table[members],
+                )
+            )
+        return groups
+
+    def _bsi_agg_in_place(self, index: str, rc: Call, prep: dict, paths, mode, sp):
+        """The partial vectors from the resident plane mirrors in
+        place: per group ``ceil(members / bp.agg_members(...))`` launches of
+        one program (``bp.aggregate_planes``), all dispatched without
+        waiting (``bsi.dispatch``; a shape's first call leaves a
+        ``compile`` span under it), then ONE fetch of every launch's
+        vectors through the dispatcher's lane (``bsi.fetch`` ›
+        ``launch``).  Under the same health gate as the leaf batch:
+        a launch that finally fails decodes ``hosteval``'s vectors."""
+        kept = prep["kept"]
+        if not kept:
+            if mode == health_mod.MODE_PROBE:
+                self.device_health.cancel_probe(paths)
+            return None
+
+        def device_fn():
+            t0 = time.monotonic()
+            with self.tracer.span("bsi.dispatch", groups=len(prep["groups"])) as ds:
+                self._fault_check_launch("agg")
+                outs = [
+                    bp.aggregate_planes(
+                        plan._eval_expr,
+                        prep["expr"],
+                        prep["cols"],
+                        prep["units"],
+                        planes,
+                        slots,
+                        prep["preds"],
+                        first_call=plan.note_agg_first_call,
+                    )
+                    for _, planes, slots in prep["groups"]
+                ]
+                launches = sum(len(o) for o in outs)
+                ds.annotate(launches=launches)
+            sp.annotate(launches=launches)
+            t_disp = time.monotonic()
+            flat = [o for group in outs for o in group]
+            with self.tracer.span("bsi.fetch", arrays=len(flat)) as fs:
+                fetched = iter(self._shared_fetch(flat, fs))
+            vecs = []
+            for group, (members, _, _) in zip(outs, prep["groups"]):
+                arr = np.concatenate([np.asarray(next(fetched)) for _ in group])
+                vecs.extend(arr[: len(members)])
+            if perf_mod.enabled():
+                perf_mod.record_launch(
+                    "agg",
+                    reduce="agg",
+                    rows=prep["planes"],
+                    n_bytes=prep["bytes"],
+                    dispatch_ms=(t_disp - t0) * 1e3,
+                    total_ms=(time.monotonic() - t0) * 1e3,
+                    trace_id=perf_mod.current_trace_id(),
+                )
+            return vecs
+
+        res = self._launch_guarded(
+            paths,
+            mode,
+            device_fn,
+            retry_fn=device_fn,
+            host_fn=lambda: self.hosteval.agg_partials(index, rc, kept),
+        )
+        return list(res.values()) if isinstance(res, dict) else res
 
     @staticmethod
     def _decode_agg_parts(c: Call, bucket: int, vecs):
         """Reduce per-slice aggregate partial vectors (device OR host
         produced — identical layout) into one ValCount."""
         if c.name == "Sum":
-            total = 0
-            count = 0
-            for vec in vecs:
-                part, n = ripple.decode_sum(vec, bucket)
-                total += part
-                count += n
+            # The decode is linear in the vector: the slices' popcounts
+            # add up first (int64 holds 2^43 slices of them), and the
+            # weights meet Python ints once.
+            vecs = list(vecs)
+            if not vecs:
+                return None
+            total, count = ripple.decode_sum(
+                np.asarray(vecs, dtype=np.int64).sum(axis=0), bucket
+            )
             return bsi.ValCount(total, count) if count else None
         best = None
         for vec in vecs:

@@ -974,6 +974,9 @@ note_scorer_first_call = functools.partial(note_first_call, "topn.score")
 # in-place writes of its outputs), under the miss's ``plan.leaves`` /
 # ``plan.transfer``.
 note_gather_first_call = functools.partial(note_first_call, "plan.gather")
+# And for the in-place BSI aggregate (``bp.aggregate_planes``), under the
+# aggregate's ``bsi.dispatch``.
+note_agg_first_call = functools.partial(note_first_call, "bsi.agg")
 
 
 def program_cache_compile_ms() -> dict[str, float]:
@@ -1028,6 +1031,7 @@ def program_cache_stats() -> dict[str, int]:
         "bitplane.gatherPlanes": sum(
             _jit_cache_size(fn) for fn in bp.GATHER_PROGRAMS
         ),
+        "bitplane.aggregatePlanes": _jit_cache_size(bp._aggregate_planes_xla),
         "bitplane.fusedCount": _jit_cache_size(bp._fused_count_xla),
         "bitplane.topCounts": _jit_cache_size(bp._top_counts_xla),
     }
@@ -1130,6 +1134,19 @@ def program_cache_bounds() -> dict[str, int]:
                 )
             )
         ),
+        # the in-place aggregate: the (expression, leaf layout, unit
+        # kinds) called so far — what a call's text decides, as the
+        # wrappers of plan.batched — x member classes (at most
+        # log2(bp.AGG_GROUP) + 1) x plane-row classes a unit (the
+        # mirrors are operands: a shape each) — on each device.  What a
+        # fragment holds, and where, is data and makes no program.
+        "bitplane.aggregatePlanes": (
+            len(bp._AGG_SEEN)
+            * n_dev
+            * bp.bucket_classes(max(hw.get("agg_frags", 1), 1))
+            * bp.bucket_classes(max(hw.get("agg_rows", rb), rb), rb)
+            ** max(hw.get("agg_units", 1), 1)
+        ),
         "bitplane.topCounts": n_dev * bp.bucket_classes(
             max(hw.get("top_rows", rb), rb), rb
         ),
@@ -1192,7 +1209,9 @@ def clear_program_caches() -> None:
     _COMPILE_MS.clear()
     bp._SHAPE_HIGHWATER.clear()
     bp._SCORE_SEEN.clear()
+    bp._AGG_SEEN.clear()
     for fn in bp.GATHER_PROGRAMS + (
+        bp._aggregate_planes_xla,
         bp._score_planes_self_src,
         bp._score_planes_host_src,
         bp._fused_count_xla,

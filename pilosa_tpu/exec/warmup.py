@@ -289,6 +289,74 @@ def prewarm_gather(shapes=(), devices=None) -> int:
     return len(todo)
 
 
+def agg_shapes(holder) -> list[tuple]:
+    """The ``(expr, cols, units, plane rows, members)`` of the in-place
+    BSI aggregate (``bp.aggregate_planes``) for a plain ``Sum`` of each
+    integer field the holder's indexes hold, the fields with the most
+    fragments first.  The expression and the leaf layout are the
+    executor's own (``Executor.bsi_agg_call``, ``_agg_columns``); the
+    holder adds what the fragments decide of the program: the row class
+    of the field's planes, and ``bp.agg_members`` for the n fragments
+    that share a device.  A filtered aggregate's program holds its
+    filter tree and compiles on its first call.  No mirror is uploaded
+    for it."""
+    from pilosa_tpu.exec.executor import Executor  # it imports this module
+
+    n_dev = len(bp.participating_devices())
+    weight: dict[tuple, int] = {}
+    for idx in holder.indexes().values():
+        for name, frame in idx.frames().items():
+            for fld in frame.bsi_fields():
+                view = frame.view(fld.view)
+                frags = view.fragments() if view is not None else []
+                if not frags:
+                    continue
+                expr, leaves = plan.decompose(Executor.bsi_agg_call("Sum", name, fld))
+                cols, units = Executor._agg_columns(leaves)
+                n = -(-len(frags) // n_dev)
+                k = sum(c[0] == "row" for c in cols)
+                for rows in {f.plane_rows() for f in frags}:
+                    key = (expr, cols, units, rows, bp.agg_members(n, rows + k))
+                    weight[key] = max(weight.get(key, 0), len(frags))
+    return _heaviest(weight)
+
+
+def prewarm_agg(shapes=(), devices=None) -> int:
+    """Compile the in-place BSI aggregate at each ``(expr, cols, units,
+    plane rows, members)`` of ``shapes`` (:func:`agg_shapes`), on each
+    of ``devices`` (a compiled program is a device's own), side by
+    side; the first device alone unless given."""
+    import jax
+
+    def warm(dev, expr, cols, units, rows, members):
+        zero = jax.device_put(
+            np.zeros((rows, bp.WORDS_PER_SLICE), dtype=np.uint32), dev
+        )
+        for out in bp.aggregate_planes(
+            plan._eval_expr,
+            expr,
+            cols,
+            units,
+            [zero] * (members * len(units)),
+            np.zeros((members, sum(c[0] == "row" for c in cols)), dtype=np.int32),
+            np.zeros((0, bp.PRED_WORDS), dtype=np.uint32),
+            first_call=plan.note_agg_first_call,
+        ):
+            out.block_until_ready()
+
+    todo = [
+        (dev,) + tuple(shape)
+        for shape in shapes
+        for dev in (devices or (bp.home_device(0),))
+    ]
+    threads = [threading.Thread(target=warm, args=t, daemon=True) for t in todo]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    return len(todo)
+
+
 def prewarm_topn(shapes=_TOPN_SHAPES) -> int:
     """Compile the fused TopN scorer — the self-src variant of
     ``bp.score_planes`` (the common ``TopN(Bitmap(frame=f), frame=f)``
@@ -323,12 +391,13 @@ def prewarm_topn(shapes=_TOPN_SHAPES) -> int:
 
 def prewarm(
     buckets=(1, 2, 4, 8), exprs=_STANDARD_EXPRS, coalesce=False, topn=(),
-    gather=(),
+    gather=(), agg=(),
 ) -> int:
     """Compile the standard (tree shape x slice bucket) programs, the
     TopN scorer at its standard shapes and at ``topn`` (the ``(members,
-    plane rows)`` of :func:`topn_shapes`), and the leaf-batch gather at
-    ``gather`` (:func:`gather_shapes`).
+    plane rows)`` of :func:`topn_shapes`), the leaf-batch gather at
+    ``gather`` (:func:`gather_shapes`) and the in-place BSI aggregate at
+    ``agg`` (:func:`agg_shapes`).
 
     Triggers real compilations by calling each program on a zero batch
     of the bucketed shape — with the persistent cache enabled this both
@@ -379,6 +448,7 @@ def prewarm(
         list(_TOPN_SHAPES) + [k for k in topn if k not in _TOPN_SHAPES]
     )
     warmed += prewarm_gather(gather)
+    warmed += prewarm_agg(agg)
     if coalesce:
         warmed += prewarm_coalesce()
         warmed += prewarm_fuse()
@@ -386,7 +456,7 @@ def prewarm(
 
 
 def prewarm_async(
-    logger=None, coalesce=False, topn=(), gather=()
+    logger=None, coalesce=False, topn=(), gather=(), agg=()
 ) -> threading.Thread:
     """Run :func:`prewarm` on a daemon thread (server open must not
     block on compiles) and return the thread, which carries the
@@ -396,7 +466,9 @@ def prewarm_async(
 
     def run():
         try:
-            t.programs = prewarm(coalesce=coalesce, topn=topn, gather=gather)
+            t.programs = prewarm(
+                coalesce=coalesce, topn=topn, gather=gather, agg=agg
+            )
         except Exception as e:  # noqa: BLE001 — recorded, not swallowed
             t.error = e
             if logger is not None:

@@ -516,15 +516,16 @@ class Server:
             # With coalescing on, also compile the coalescer's
             # power-of-two bucket shapes for the common Count trees so
             # the first coalesced batch doesn't eat a cold compile.
-            # The TopN scorer's and the leaf-batch gather's shapes are
-            # read off the holder HERE, as it was opened: read on the
-            # prewarm thread they would be those of whatever an import
-            # had loaded by then.
+            # The TopN scorer's, the leaf-batch gather's and the in-place
+            # BSI aggregate's shapes are read off the holder HERE, as it
+            # was opened: read on the prewarm thread they would be those
+            # of whatever an import had loaded by then.
             prewarm_thread = warmup.prewarm_async(
                 logger=self.logger,
                 coalesce=self.coalesce,
                 topn=warmup.topn_shapes(self.holder),
                 gather=warmup.gather_shapes(self.holder),
+                agg=warmup.agg_shapes(self.holder),
             )
 
         # Start HTTP listener first so ":0" resolves to the real port

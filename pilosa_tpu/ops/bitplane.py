@@ -712,9 +712,10 @@ def score_group_bucket(n_frags: int) -> int:
     return min(pow2_bucket(n_frags), SCORE_GROUP)
 
 
-# Program shapes the scorer and the leaf-batch gather have been called
-# with: a shape's first call traces and compiles (or loads) its program,
-# one thread at a time, and its caller is told so (``_first_call``).
+# Program shapes the scorer, the leaf-batch gather and the in-place
+# aggregate have been called with: a shape's first call traces and
+# compiles (or loads) its program, one thread at a time, and its caller
+# is told so (``_first_call``).
 _SCORE_SEEN: set = set()
 _FIRST_CALL_MU = threading.Lock()
 
@@ -840,7 +841,7 @@ def _device_int(value: int, dev):
     return jax.device_put(np.int32(value), dev)
 
 
-def _device_of(arr):
+def device_of(arr):
     return next(iter(arr.devices()))
 
 
@@ -873,7 +874,7 @@ def gather_planes(planes, slots, first_call=None):
     _note_shape(
         gather_frags=bucket, gather_rows=int(planes[0].shape[0]), gather_leaves=k
     )
-    dev = _device_of(planes[0])
+    dev = device_of(planes[0])
     shape = ("gather", bucket, tuple(planes[0].shape), k, str(dev))
     per_table = GATHER_TABLE * bucket
     for t0 in range(0, n, per_table):
@@ -896,7 +897,7 @@ def place_rows(block, rows, row0: int, col0: int = 0, first_call=None):
     fit).  Keyed by the two shapes and the device; the offsets are
     operands."""
     _note_shape(place_rows=int(block.shape[0]), place_leaves=int(block.shape[1]))
-    dev = _device_of(block)
+    dev = device_of(block)
     shape = ("place", tuple(block.shape), tuple(rows.shape), str(dev))
     return _first_call(
         _place_rows_xla, shape, first_call,
@@ -909,12 +910,262 @@ def place_const(block, row, n: int, col: int, first_call=None):
     device) written into column ``col`` of its first ``n`` members and
     zeros into that column of the rest, in place (``block`` is
     donated): a slice-invariant leaf, such as a BSI predicate row."""
-    dev = _device_of(block)
+    dev = device_of(block)
     shape = ("const", tuple(block.shape), str(dev))
     return _first_call(
         _place_const_xla, shape, first_call,
         block, row, _device_int(int(n), dev), _device_int(int(col), dev),
     )
+
+
+class _LeafRows(list):
+    """A member's leaf rows as the expression evaluator takes them: it
+    indexes them by leaf, and asks their shape only for an empty fold."""
+
+    shape = (0, WORDS_PER_SLICE)
+    dtype = jnp.uint32
+
+
+# Rows of one tile of a plane mirror on the chip: a DMA moves whole
+# tiles, so a copy that starts inside a plane starts at a multiple of
+# this and takes this many rows (ROW_BLOCK, the planes' own row class
+# floor, is the same 8).
+TILE_ROWS = 8
+
+
+def _unit_rows(units, planes) -> list[int]:
+    """Rows the DMA stage copies of each unit of a member: the whole
+    plane of a ``"whole"`` unit (its shape is the operand's own), the
+    TILE_ROWS around one row of a ``"tile"`` unit."""
+    return [
+        int(p.shape[0]) if unit == "whole" else TILE_ROWS
+        for unit, p in zip(units, planes)
+    ]
+
+
+def _copy_units(units, planes, tiles, interpret: bool):
+    """The DMA stage of the aggregate: uint32[members, R, words], for
+    every member its units' rows one after another.  ``units``: a unit
+    each ``"whole"`` — the member's whole plane of that unit — or
+    ``"tile"`` — the TILE_ROWS rows of it that start at
+    ``tiles[member, unit] * TILE_ROWS``; ``planes``: the mirrors,
+    member-major, a unit each.  One kernel a launch whatever the
+    members: every copy is started, then every copy is waited for, and
+    no byte passes through the core."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_units = len(units)
+    members = len(planes) // n_units
+    rows = _unit_rows(units, planes)
+    offsets = [sum(rows[:u]) for u in range(n_units)]
+
+    def kernel(tiles_ref, *refs):
+        mirrors, out_ref, sem = refs[:-2], refs[-2], refs[-1]
+        copies = []
+        for f in range(members):
+            for u, unit in enumerate(units):
+                src = mirrors[f * n_units + u]
+                if unit == "tile":
+                    first = pl.multiple_of(tiles_ref[f, u] * TILE_ROWS, TILE_ROWS)
+                    src = src.at[pl.ds(first, TILE_ROWS), :]
+                copy = pltpu.make_async_copy(
+                    src, out_ref.at[f, pl.ds(offsets[u], rows[u]), :], sem
+                )
+                copy.start()
+                copies.append(copy)
+        for copy in copies:
+            copy.wait()
+
+    block = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(
+            (members, sum(rows), WORDS_PER_SLICE), jnp.uint32
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(planes),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        ),
+        interpret=interpret,
+    )(tiles, *planes)
+    return block, offsets, rows
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _aggregate_planes_xla(evaluate, expr, cols, units, interpret, planes, table, i, preds):
+    sl = table[i]
+    held = sl >= 0
+    at = jnp.maximum(sl, 0)
+    # the column that says where a "tile" unit's rows start
+    tile_col = {c[1]: c[2] for c in cols if c[0] == "row" and units[c[1]] == "tile"}
+    tiles = jnp.stack(
+        [
+            at[:, tile_col[u]] // TILE_ROWS
+            if u in tile_col
+            else jnp.zeros(sl.shape[:1], jnp.int32)
+            for u in range(len(units))
+        ],
+        axis=1,
+    )
+    block, offsets, unit_rows = _copy_units(units, planes, tiles, interpret)
+    # Every leaf row, picked out of the block by the member's own slot
+    # (of a tile: the slot within it) in ONE gather a launch: where the
+    # fragments keep a row is data, so neither a write nor another field
+    # of the same shape is a new program.  (A gather a leaf is four times
+    # the device operations a launch, 412 against 103, and a profile of
+    # the cell's 10 s then holds millions: my chip run, PR 34.)
+    where = [c for c in cols if c[0] == "row"]
+    at_row = jnp.stack(
+        [offsets[u] + at[:, k] % unit_rows[u] for _, u, k in where], axis=1
+    )
+    picked = jnp.take_along_axis(block, at_row[:, :, None], axis=1)
+    rows = []  # a leaf: uint32[members, words], or what every member shares
+    n = 0
+    for col in cols:
+        if col[0] == "pred":
+            rows.append(preds[col[1]])
+        elif col[0] == "row":
+            rows.append(
+                jnp.where(held[:, col[2]][:, None], picked[:, n], jnp.uint32(0))
+            )
+            n += 1
+        else:
+            rows.append(jnp.zeros((WORDS_PER_SLICE,), jnp.uint32))
+    batched = [r for r in rows if r.ndim == 2]
+
+    def one(*mine):
+        mine = iter(mine)
+        return evaluate(
+            expr, _LeafRows(next(mine) if r.ndim == 2 else r for r in rows)
+        )
+
+    return jax.vmap(one)(*batched)
+
+
+# Members one aggregate launch takes, at most: its DMA stage copies
+# their units into one block inside the program (uint32[members, R,
+# words]), which the expression then reads as ONE operand, so a launch
+# is a hundred-odd device operations whatever its members.  (Its first
+# form read every row where it lay, unrolled a member, and ran 29,000
+# tiny operations an answer: 19 ms on the device for 3.8 ms of bytes,
+# 21 s of compile a query template, and a profile of a 10 s window that
+# did not return in 10 minutes.  PERF.md, PR 34.)  Larger sets run as
+# ceil(n / members) launches of the same program.
+AGG_GROUP = 16
+
+# And the bytes of that block and of the rows picked out of it (both
+# are alive while the gather runs), at most: up to here the chip's
+# compiler keeps them in the core's fast memory (8 members x (56 + 44)
+# rows, 100 MiB, it does; a block of 16 x 64 rows it left in HBM, where
+# the copy took 2.8 times as long and reading a row out of it 12 times:
+# my chip runs, PR 34).
+AGG_BLOCK_BYTES = 112 << 20
+
+
+def agg_members(n: int, rows: int) -> int:
+    """Members of the program that takes ``n`` members of ``rows`` rows
+    each (what the DMA stage copies of a member and the leaf rows picked
+    out of that): the pow2 class of a small set, never more than
+    AGG_GROUP, never over AGG_BLOCK_BYTES."""
+    fit = AGG_BLOCK_BYTES // (rows * WORDS_PER_SLICE * 4)
+    return max(1, min(pow2_bucket(n), AGG_GROUP, 1 << max(fit.bit_length() - 1, 0)))
+
+
+# Words of a predicate row that travel to an aggregate launch: the
+# magnitude bits of the deepest field (bsi.MAX_DEPTH) and the sign flag.
+PRED_WORDS = 128
+
+# The (expression, leaf layout, unit kinds) of the aggregate programs
+# called so far — what the call's text alone decides: the bound of the
+# family counts them, times the shape classes of what the fragments
+# decide (exec/plan.program_cache_bounds).
+_AGG_SEEN: set = set()
+
+
+def aggregate_planes(evaluate, expr, cols, units, planes, slots, preds, first_call=None):
+    """A BSI aggregate (or any expression that reduces inside itself)
+    over many slices, computed from the fragments' HBM-resident plane
+    mirrors by ONE program a launch: the mirrors are operands, a DMA
+    stage copies each member's units side by side into a block that
+    lives inside the program (``_copy_units``), and the expression,
+    vmapped over the members, picks every leaf row out of it by slot.
+    No leaf batch is assembled, cached or padded, and nothing of a launch
+    outlives it but its vectors.
+
+    ``evaluate(expr, leaves)`` is the expression evaluator (exec/plan's
+    own, handed in: this module does not import the plan layer) and
+    ``expr`` the decomposed tree; ``cols`` says, a leaf, where its row
+    comes from: ``("row", unit, k)`` — the row at ``slots[:, k]`` of the
+    member's plane of ``unit``; ``("pred", p)`` — ``preds[p]``, a packed
+    predicate (``preds``: uint32[n, PRED_WORDS], one small transfer a
+    call: a constant is data); ``("zero",)`` — a depth-bucket pad, a
+    literal zero that the compiler folds away.  ``units``: what the DMA
+    stage copies of a member, each ``"whole"`` (a plane most of whose
+    rows are read: a field's) or ``"tile"`` (the tile of rows around one
+    row: a Bitmap's).  All three come from the call's text alone.
+    ``planes``: the members' mirror SNAPSHOTS, member-major, a unit each,
+    all on ONE device and of one shape a unit; ``slots``: int[members,
+    K], negative for a row the member's fragment does not hold (it reads
+    as zeros): which rows a fragment keeps, and where, is data.
+
+    As ``score_planes`` and ``gather_planes``: ``agg_members`` members a
+    launch, the last padded by repeating its last member with no row
+    held; the slots cross to the device once per GATHER_TABLE launches.
+    Returns the launches' device arrays, int32[bucket, vector] each, in
+    member order.  The jit key is (expression, cols, units, the units'
+    plane shapes, members bucket, device): never the slice count, a
+    constant, or what a fragment holds."""
+    n_units = len(units)
+    n = len(planes) // n_units
+    slots = np.asarray(slots, dtype=np.int32).reshape(n, -1)
+    k = int(slots.shape[1])
+    bucket = agg_members(n, sum(_unit_rows(units, planes)) + k)
+    _AGG_SEEN.add((expr, cols, units))
+    _note_shape(
+        agg_frags=bucket,
+        agg_units=n_units,
+        agg_rows=max(int(p.shape[0]) for p in planes[:n_units]),
+    )
+    dev = device_of(planes[0])
+    shape = (
+        "agg", expr, cols, units,
+        tuple(int(p.shape[0]) for p in planes[:n_units]), bucket, str(dev),
+    )
+    interpret = dev.platform != "tpu"
+    preds = jax.device_put(
+        np.asarray(preds, dtype=np.uint32).reshape(-1, PRED_WORDS), dev
+    )
+    per_table = GATHER_TABLE * bucket
+    outs = []
+    # The DMA kernel travels inside the program as bytes that carry its
+    # operations' source locations; with whole call stacks in them the
+    # same kernel lowered under the prewarm and under a request is two
+    # programs to the persistent compile cache (a restart then compiles
+    # what the first boot cached: chip_smoke.py, PR 34).
+    from jax._src import config as jax_config
+
+    with jax_config.include_full_tracebacks_in_locations(False):
+        for t0 in range(0, n, per_table):
+            table = np.full((per_table, k), -1, dtype=np.int32)
+            table[: min(n - t0, per_table)] = slots[t0 : t0 + per_table]
+            table = jax.device_put(table.reshape(GATHER_TABLE, bucket, k), dev)
+            for i, lo in enumerate(range(t0, min(n, t0 + per_table), bucket)):
+                group = tuple(
+                    planes[min(m, n - 1) * n_units + u]
+                    for m in range(lo, lo + bucket)
+                    for u in range(n_units)
+                )
+                outs.append(
+                    _first_call(
+                        _aggregate_planes_xla, shape, first_call,
+                        evaluate, expr, cols, units, interpret,
+                        group, table, _device_int(i, dev), preds,
+                    )
+                )
+    return outs
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

@@ -270,12 +270,23 @@ def _score_on(dev):
     )
 
 
+def _aggregate_on(dev):
+    plane = jax.device_put(np.zeros((bp.ROW_BLOCK, bp.WORDS_PER_SLICE), np.uint32), dev)
+    expr = ("bsiSum", False) + tuple(("leaf", j) for j in range(10))
+    cols = (("row", 0, 0), ("zero",), ("row", 0, 1)) + (("zero",),) * 7
+    bp.aggregate_planes(
+        plan._eval_expr, expr, cols, ("whole",), [plane],
+        np.zeros((1, 2), np.int32), np.zeros((0, bp.PRED_WORDS), np.uint32),
+    )
+
+
 @pytest.mark.parametrize(
     "family, launch",
     [
         ("bitplane.topCounts", _top_counts_on),
         ("bitplane.expand", _expand_on),
         ("bitplane.scorePlanes", _score_on),
+        ("bitplane.aggregatePlanes", _aggregate_on),
     ],
 )
 def test_a_program_over_per_device_planes_is_bounded_on_every_device(family, launch):

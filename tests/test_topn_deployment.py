@@ -8,6 +8,7 @@ cell does on the chip only a chip run can say (``PERF.md``)."""
 import copy
 import json
 import os
+import re
 import sys
 
 import numpy as np
@@ -305,6 +306,91 @@ def test_the_leaf_batch_gather_compiles_for_the_chip_at_the_cells_own_width(
     # the donated block is the output: no second 256 MiB
     assert mem.alias_size_in_bytes == block == mem.output_size_in_bytes
     assert mem.temp_size_in_bytes < G * 2 * row
+
+
+def test_the_in_place_aggregate_compiles_for_the_chip_at_the_cells_own_width(
+    one_v5e_chip, tmp_path
+):
+    """What every answer of ``ssb-sf100-q1.sum-drill`` launches: SSB's
+    Q1.1 as the executor lays it out over the deployment's own fields (a
+    27-bit ``lo_discounted`` in a 32-row plane, ``lo_discount``,
+    ``lo_quantity`` and ``d_year`` in 8-row planes), ``bp.AGG_GROUP``
+    slices a launch, the plane mirrors as operands, the DMA stage as the
+    chip's own kernel compiler takes it.  (Here beside the scorer's: the
+    tests that describe the chip stay in one file.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from pilosa_tpu.obs import trace
+
+    cfg = run.read_json(os.path.join(BENCH, "configs", "ssb-sf100-q1.json"))
+    mix = run.read_json(os.path.join(BENCH, "traffic", "sum-drill.json"))
+    holder = Holder(str(tmp_path))
+    holder.open()
+    try:
+        idx = holder.create_index("ssb")
+        lo = idx.create_frame("lo")
+        lo.set_options(range_enabled=True)
+        for name, (low, high) in cfg["measures"]["fields"].items():
+            lo.create_field(name, low, high)
+        cols = np.arange(27)
+        lo.import_value("lo_discounted", cols, 1 << cols)  # every magnitude plane
+        lo.import_value("lo_discount", cols, cols % 11)
+        lo.import_value("lo_quantity", cols, cols * 2 % 50 + 1)
+        idx.create_frame("d_year").import_bulk(np.full(27, 1993), cols)
+        holder.warm_device_mirrors()  # cold ones would take the leaf batch
+        ex = Executor(holder)
+        try:
+            text = mix["read"]["templates"]["q1.1"].format(year=1993, dlo=1, dhi=3, k=25)
+            rc = ex._rewrite_bsi_agg("ssb", parse_string(text).calls[0])
+            with trace.NOP_TRACER.span("bsi.prep") as sp:
+                prep = ex._agg_in_place_prep("ssb", rc, [0], sp)
+        finally:
+            ex.close()
+    finally:
+        holder.close()
+    ((_, planes, (slots,)),) = prep["groups"]
+    assert [int(p.shape[0]) for p in planes] == [32, 8, 8, 8]
+    # the rows an answer reads in a slice, as the benchmark's reducer counts them
+    assert (slots >= 0).sum() == prep["planes"] == cfg["planes_read"]["q1.1"] == 41
+    # the pads to the depth bucket are the program's zeros (the schema says
+    # them); a sign row no column ever set is a slot not held: data
+    assert sum(c[0] == "zero" for c in prep["cols"]) == 5 + 4 + 2
+    assert (slots < 0).sum() == 3
+    assert len(prep["preds"]) == 3
+    # the fields' planes are copied whole; of d_year's plane the tile
+    # around the one row
+    assert prep["units"] == ("whole", "tile", "whole", "whole")
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e_chip)
+
+    # Q1.1: 56 rows copied and 44 picked a member, 100 MiB a launch of 8:
+    # what stays in the core's fast memory; Q1.2, Q1.3: 64 and 45 rows
+    g = bp.agg_members(573, 32 + bp.TILE_ROWS + 8 + 8 + slots.size)
+    assert g == 8 == bp.agg_members(573, 64 + 45)
+    operands = tuple(shape(p.shape, jnp.uint32) for _ in range(g) for p in planes)
+    compiled = bp._aggregate_planes_xla.lower(
+        plan._eval_expr, prep["expr"], prep["cols"], prep["units"], False,
+        operands, shape((bp.GATHER_TABLE, g, slots.size), jnp.int32),
+        shape((), jnp.int32), shape(prep["preds"].shape, jnp.uint32),
+    ).compile()
+    mem = compiled.memory_analysis()
+    mirrors = g * (32 + 8 + 8 + 8) * bp.WORDS_PER_SLICE * 4
+    # the operands are the mirrors themselves, a slot table and the predicates
+    assert mirrors < mem.argument_size_in_bytes < mirrors + (1 << 18)
+    # a 65-word vector a slice comes back (padded to the chip's tiles)
+    assert g * (2 * 32 + 1) * 4 <= mem.output_size_in_bytes <= g * 128 * 4
+    # the block of a launch and the rows picked out of it live in the
+    # core's fast memory: next to nothing of them in HBM, let alone a leaf
+    # batch of 58 rows a padded slice
+    assert 0 < mem.temp_size_in_bytes < 4 * g * bp.WORDS_PER_SLICE * 4
+    # a launch is a hundred-odd device operations (a gather a leaf made it
+    # 412, and a profile of the cell's 10 s could not be read: PERF.md §6)
+    entry = re.search(r"ENTRY [^{]*\{(.*?)\n\}", compiled.as_text(), re.S).group(1)
+    ops = [m.group(1) for m in re.finditer(r"= \S+ ([\w-]+)\(", entry)]
+    idle = {"parameter", "get-tuple-element", "bitcast", "tuple", "constant"}
+    assert 50 < sum(op not in idle for op in ops) < 150
 
 
 @pytest.mark.parametrize("leaves,ops", [(4, 8), (8, 16)])

@@ -433,6 +433,67 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
         "topn.score", "topn.dispatch", "topn.fetch", "launch", "topn.select"])
 
 
+SUM_FILTERED = 'Sum(Intersect(Bitmap(frame="f", rowID=1), Range(frame="v", q >< [2, 6])), frame="v", field="q")'
+
+
+@pytest.mark.parametrize("slices", [2, 20])
+def test_the_stages_of_a_served_sum_and_no_span_in_a_loop_over_slices(
+    one_chip, server, slices
+):
+    """``bsi.agg`` › ``bsi.prep``, ``bsi.dispatch`` › ``compile``,
+    ``bsi.fetch`` › ``launch``, ``bsi.decode`` with the tags the
+    per-layer metrics read, and as many spans at 20 slices (two
+    launches of the aggregate's program) as at 2."""
+    _populate(server, slices)
+    v = server.holder.index("i").create_frame_if_not_exists("v")
+    v.set_options(range_enabled=True)
+    v.create_field("q", 0, 7)
+    for sl in range(slices):
+        v.import_value("q", [sl * SLICE_WIDTH + 5, sl * SLICE_WIDTH + 9], [3, 7])
+    server.holder.warm_device_mirrors()  # cold ones take the leaf batch
+    plan.clear_program_caches()
+    c = InternalClient(server.host, timeout=120.0)
+    def ask(text):
+        # the internal client's protobuf leg carries a ValCount as one Pair
+        return [(p.id, p.count) for p in c.execute_pql("i", text)]
+
+    assert ask(SUM_FILTERED) == [(3 * slices, slices)]
+    first = _last_trace(c)
+    assert len(first["spans"]) <= 24
+    agg = _span(first, "bsi.agg")
+    launches = -(-slices // bp.agg_members(slices, bp.TILE_ROWS + 8 + 9))
+    # exists + 3 magnitude rows of q read twice (the Sum's and the
+    # Range's leaves) and the filter's row: 9 rows of planes a slice
+    assert agg["tags"] == {"slices": slices, "way": "in_place", "planes": 9 * slices,
+                           "bytes": 9 * slices * bp.WORDS_PER_SLICE * 4,
+                           "launches": launches}
+    assert [s["name"] for s in _children(first, "bsi.agg")] == [
+        "bsi.prep", "bsi.dispatch", "bsi.fetch", "bsi.decode"]
+    assert _span(first, "bsi.prep")["tags"] == {"slices": slices, "cold": 0}
+    disp = _span(first, "bsi.dispatch")
+    assert disp["tags"] == {"groups": 1, "launches": launches}
+    # the program shape's first call compiled under the dispatch, once
+    # however many launches there were, and compileMs counted it
+    compiles = _children(first, "bsi.dispatch")
+    assert [s["name"] for s in compiles] == ["compile"]
+    assert compiles[0]["tags"]["family"] == "bsi.agg"
+    assert plan.program_cache_compile_ms()["bsi.agg"] >= compiles[0]["duration_ms"]
+    assert _span(first, "bsi.fetch")["tags"]["arrays"] == launches
+    assert [s["name"] for s in _children(first, "bsi.fetch")] == ["launch"]
+    assert _span(first, "bsi.decode")["tags"] == {"vectors": slices}
+
+    # other constants and another row: the program that is there
+    assert ask(SUM_FILTERED.replace("[2, 6]", "[7, 7]").replace(
+        "rowID=1", "rowID=2")) == [(0, 0)]
+    assert ask(SUM_FILTERED.replace("[2, 6]", "[4, 7]")) == [(7 * slices, slices)]
+    other = _last_trace(c)
+    assert plan.program_cache_stats()["bitplane.aggregatePlanes"] == 1
+    # an in-place Sum has these spans whatever the slice count
+    assert sorted(s["name"] for s in other["spans"]) == sorted([
+        "query", "parse", "admission", "execute", "call.Sum", "map.local",
+        "bsi.agg", "bsi.prep", "bsi.dispatch", "bsi.fetch", "launch", "bsi.decode"])
+
+
 # ---------------------------------------------------------------------------
 # /debug/profile
 # ---------------------------------------------------------------------------
