@@ -1438,6 +1438,20 @@ class Fragment:
                 pool.touch(self._pool_key)
             return self._device
 
+    def _mirror_locked(self):
+        """``device_plane()`` for a reader of MANY fragments' mirrors,
+        which keeps them recent in the residency pool itself, all under
+        one hold of the pool's lock (``PlanePool.touch_many``): a mirror
+        that is resident and current is handed over with no pool call.
+        The pool has ONE lock; a touch a fragment from every request
+        thread is a convoy on it (eight TopN builds over 954 fragments
+        each took 40 times what one takes alone, PERF.md PR 35).
+        Anything else — an upload, a queued scatter — goes through
+        ``device_plane()``.  Callers hold ``_mu``."""
+        if self._device is not None and self._device_version == self._version:
+            return self._device
+        return self.device_plane()
+
     def plane_rows(self) -> int:
         """Rows of the dense plane as allocated (a pow2 class, floor
         ROW_BLOCK): the row dimension of every program that reads the
@@ -2195,6 +2209,7 @@ class Fragment:
             # the lock), never the live mirror: a concurrent write
             # could reorder the slot layout out from under the
             # prepared slot indices.
+            device_mod.pool().touch(self._pool_key)
             st.dev_counts = bp.top_counts(
                 sub_ref.plane[sub_ref.slots], src_words
             )
@@ -2323,9 +2338,12 @@ class Fragment:
     def _sub_ref_locked(self, slots: np.ndarray) -> SubRef:
         """The scorer's view of this fragment for padded ``slots``, on
         the CURRENT mirror.  Callers hold ``_mu``, so the snapshot and
-        whatever slots they read under the same hold agree."""
+        whatever slots they read under the same hold agree.  The mirror
+        is not touched in the residency pool here: who scores the
+        SubRefs of many fragments touches them all at once
+        (``_mirror_locked``)."""
         return SubRef(
-            plane=self.device_plane(),
+            plane=self._mirror_locked(),
             slots=slots,
             shape=(len(slots), bp.WORDS_PER_SLICE),
             plane_rows=int(self._plane.shape[0]),

@@ -56,6 +56,7 @@ from pilosa_tpu.core.view import VIEW_INVERSE, VIEW_STANDARD
 from pilosa_tpu.exec import coalesce as coalesce_mod
 from pilosa_tpu.exec import hosteval as hosteval_mod
 from pilosa_tpu.exec import plan
+from pilosa_tpu.exec import topn_stack
 from pilosa_tpu.exec import warmup
 from pilosa_tpu.net import resilience
 from pilosa_tpu.obs import perf as perf_mod
@@ -181,18 +182,6 @@ def needs_slices(calls: list[Call]) -> bool:
     if not calls:
         return False
     return any(c.name not in WRITE_CALLS for c in calls)
-
-
-def isin_sorted(values: np.ndarray, sorted_ref: np.ndarray) -> np.ndarray:
-    """Membership of ``values`` in SORTED-unique ``sorted_ref`` via one
-    binary search — np.isin's sort-based path costs ~80 us/call even on
-    tiny arrays, and the folded TopN's phase-2 pays it once per slice
-    per query."""
-    if not len(sorted_ref):
-        return np.zeros(len(values), dtype=bool)
-    idx = np.searchsorted(sorted_ref, values)
-    idx[idx == len(sorted_ref)] = len(sorted_ref) - 1
-    return sorted_ref[idx] == values
 
 
 def merge_counts_by_id(parts):
@@ -2881,123 +2870,102 @@ class Executor:
             trimmed = trimmed[:n]
         return trimmed
 
-    def _score_topn_parts(self, parts) -> None:
+    def _score_topn_parts(self, stack: topn_stack.ScoreStack) -> np.ndarray:
         """Score many fragments' TopN parts with as FEW device
-        operations and host<->device transfers as possible and fill
-        each ``TopState.counts``.
+        operations and host<->device transfers as possible; returns the
+        flat ``int32`` score vector that ``stack.base`` indexes.
 
-        ``parts``: list of (TopState, SubRef, src_words, src_slot,
-        fragment) — the first three from the ``*_parts`` fragment APIs,
-        ``src_slot`` from ``_attach_dev_src`` (None when the src is not
-        a row of the member's own plane; only then must ``src_words``
-        be there), ``fragment`` for the host scoring
-        fallback.  Entries with a SubRef group by program shape
-        (sub shape, plane rows, home device) and by where their src is
-        read from; each group is scored by
+        ``stack``: the ``topn_stack.score_stack`` of the score entries
+        (TopState, SubRef, src_words, src_slot, fragment) — the first
+        three from the ``*_parts`` fragment APIs, ``src_slot`` from
+        ``_attach_dev_src`` (None when the src is not a row of the
+        member's own plane; only then must ``src_words`` be there),
+        ``fragment`` for the host scoring fallback.  Entries with a
+        SubRef are grouped there by program shape (sub shape, plane
+        rows, home device) and by where their src is read from, each
+        group's operands stacked once; each group is scored by
         ONE compiled program (bp.score_planes) that reads candidate AND
         src rows straight from the fragments' resident HBM mirrors — no
         stacked copy, no src upload — launched once per bp.SCORE_GROUP
         members without waiting, and every launch of every group is
         fetched in ONE round trip, where a per-fragment path would pay a
         dispatch + a 128 KiB src upload + a fetch PER SLICE.  The
-        program's operands are bounded whatever the slice count.
+        program's operands are bounded whatever the slice count, and
+        nothing here walks the members in Python.
 
         Rides the device-health gate: a quarantined device (or a
-        finally-failed scorer launch) fills the count vectors from the
+        finally-failed scorer launch) fills the score vector from the
         fragments' authoritative host rows instead
         (hosteval.score_topn_parts) — identical arithmetic, identical
         vectors.
 
         The ``topn.dispatch`` / ``topn.fetch`` spans split the device
-        cost: dispatch covers gather prep + the async program launches
-        (a program shape's first call leaves a ``compile`` span under
-        it), fetch the blocking device->host transfer — with
-        ``topn.select`` in the callers, the per-stage TopN(src)
-        breakdown."""
-        live = [e for e in parts if e[1] is not None]
-        if not live:
-            return
+        cost: dispatch covers the async program launches (a program
+        shape's first call leaves a ``compile`` span under it), fetch
+        the blocking device->host transfer — with ``topn.select`` in
+        the folded caller, the per-stage TopN(src) breakdown."""
+        if not stack.groups:
+            return topn_stack.NO_SCORES
+
+        def host_fn():
+            # The stack's states are shared by every query of a prep
+            # entry: the host scorer fills clones.
+            live = [(replace(e[0]), *e[1:]) for e in stack.live]
+            self.hosteval.score_topn_parts(live)
+            return topn_stack.host_scores(stack, [e[0].counts for e in live])
+
         paths = self.device_health.device_paths()
         mode = self.device_health.acquire(paths)
         if mode == health_mod.MODE_DENY:
-            self.hosteval.score_topn_parts(live)
-            return
+            return host_fn()
 
         def device_fn():
-            groups: dict[tuple, list] = {}
-            for entry in live:
-                ref = entry[1]
-                groups.setdefault(
-                    (ref.shape, ref.plane_rows, ref.device, entry[3] is None),
-                    [],
-                ).append(entry)
-            # Scorer roofline accounting: each live member's fused
-            # scoring pass streams its whole plane snapshot (the last
-            # launch's pad repeats are bucketing, not counted).
-            rows = sum(int(e[1].plane_rows) for e in live)
-            n_bytes = sum(
-                perf_mod.plane_bytes(int(e[1].plane_rows), bp.WORDS_PER_SLICE)
-                for e in live
-            )
-            dev_outs = []  # ([device arrays], [states]) fetched in one pass
+            dev_outs = []  # a group's launches, fetched in one pass
             t0 = time.monotonic()
             with self.tracer.span(
-                "topn.dispatch", groups=len(groups), rows=rows, bytes=n_bytes
+                "topn.dispatch",
+                groups=len(stack.groups),
+                rows=stack.rows,
+                bytes=stack.n_bytes,
             ) as sp:
                 self._fault_check_launch("topn")
-                for (*_, host_src), members in groups.items():
-                    planes = [m[1].plane for m in members]
-                    slots = np.stack([m[1].slots for m in members])
-                    # Same-plane src slot for every member -> zero src bytes
-                    # cross the host boundary (and no extra leaf shapes in
-                    # the jit key); otherwise one stacked host-snapshot
-                    # transfer per launch.
-                    if not host_src:
-                        outs = bp.score_planes(
-                            planes,
-                            slots,
-                            src_slots=np.asarray(
-                                [m[3] for m in members], dtype=np.int32
+                for group in stack.groups:
+                    dev_outs.append(
+                        bp.score_planes(
+                            group.planes,
+                            group.slots,
+                            src_slots=group.src_slots,
+                            srcs=(
+                                None
+                                if group.srcs is None
+                                else np.stack(group.srcs)
                             ),
                             first_call=plan.note_scorer_first_call,
                         )
-                    else:
-                        outs = bp.score_planes(
-                            planes,
-                            slots,
-                            srcs=np.stack([m[2] for m in members]),
-                            first_call=plan.note_scorer_first_call,
-                        )
-                    dev_outs.append((outs, [m[0] for m in members]))
-                sp.annotate(launches=sum(len(o) for o, _ in dev_outs))
+                    )
+                sp.annotate(launches=sum(len(o) for o in dev_outs))
             t_disp = time.monotonic()
-            flat = [o for outs, _ in dev_outs for o in outs]
+            flat = [o for outs in dev_outs for o in outs]
             with self.tracer.span("topn.fetch", arrays=len(flat)) as sp:
                 fetched = iter(self._shared_fetch(flat, sp))
-            for outs, sts in dev_outs:
-                arr = np.concatenate(
-                    [np.asarray(next(fetched)) for _ in outs]
-                )
-                for i, st in enumerate(sts):
-                    st.counts = arr[i]
+            scores = topn_stack.flatten_scores(
+                stack,
+                [[np.asarray(next(fetched)) for _ in outs] for outs in dev_outs],
+            )
             if perf_mod.enabled():
                 perf_mod.record_launch(
                     "topn",
                     reduce="topn",
-                    rows=rows,
-                    n_bytes=n_bytes,
+                    rows=stack.rows,
+                    n_bytes=stack.n_bytes,
                     dispatch_ms=(t_disp - t0) * 1e3,
                     total_ms=(time.monotonic() - t0) * 1e3,
                     trace_id=perf_mod.current_trace_id(),
                 )
-            return True
+            return scores
 
-        self._launch_guarded(
-            paths,
-            mode,
-            device_fn,
-            retry_fn=device_fn,
-            host_fn=lambda: self.hosteval.score_topn_parts(live),
+        return self._launch_guarded(
+            paths, mode, device_fn, retry_fn=device_fn, host_fn=host_fn
         )
 
     def _shared_fetch(self, arrays, sp):
@@ -3076,7 +3044,7 @@ class Executor:
                     # The slot is only valid against the snapshot the
                     # prepare captured; a refresh since then (writes)
                     # may have reordered the slot layout.
-                    if s is not None and frag.device_plane() is sub_ref.plane:
+                    if s is not None and frag._mirror_locked() is sub_ref.plane:
                         slot = int(s)
         return st, sub_ref, srcw, slot
 
@@ -3251,11 +3219,15 @@ class Executor:
         """Build a folded-TopN prep entry (see _topn_folded_entry for
         the caching contract).  Entry shapes: ``{"empty": True}``,
         ``{"two_phase": True}``, or ``{"parts": [(frag, cand_ids,
-        cand_mask, st_proto, sub_ref, src_words, src_slot), ...],
-        "union": n, "build": how}`` where st_proto is the UNSCORED
-        TopState (cloned per query) and cand_mask pre-resolves
-        ``np.isin(union_order_ids, cand_ids)`` for phase-1 winner
-        selection.
+        own_mask, st, sub_ref, src_words, src_slot), ...], "union": n,
+        "build": how, "score": ..., "stack": ..., "pins": ...}`` where
+        ``st`` is the part's UNSCORED TopState and ``own_mask`` says
+        which of the ids it lists are the fragment's own candidates
+        (phase 1 ranks those only; None: all of them).  ``score`` and
+        ``stack`` hold the same parts as arrays (``topn_stack``): what
+        runs per answer reads those and walks no parts, and nothing of
+        an entry is written after its build but the score memo.
+        ``pins``: the pool keys of every part's mirror.
 
         Per text the build does only what depends on the text's src.
         What a fragment's candidates are, which tier holds each and
@@ -3344,7 +3316,6 @@ class Executor:
             else None
         )
         short_way = not has_src or own_src is not None
-        own_mask = np.ones(len(union), dtype=bool)
         parts: list = [None] * len(per)
         walk: list[int] = []
         for i, (frag, lay, _opt, cand_ids, _cnts) in enumerate(per):
@@ -3358,15 +3329,7 @@ class Executor:
                     index, c, frag, part, leaf
                 )
                 if sub_ref is None or src_slot is not None:
-                    parts[i] = (
-                        frag,
-                        cand_ids,
-                        own_mask if sub_ref is not None else None,
-                        st,
-                        sub_ref,
-                        None,
-                        src_slot,
-                    )
+                    parts[i] = (frag, cand_ids, None, st, sub_ref, None, src_slot)
                     continue
             walk.append(i)
 
@@ -3391,13 +3354,23 @@ class Executor:
                 frag.top_prepare_union_parts(union, cand_ids, cand_cnts, opt),
                 leaf,
             )
-            cand_mask = (
-                np.isin(st.cand_ids, cand_ids, assume_unique=True)
-                if st.cand_ids is not None
-                else None
+            # cand_ids is a subset of what the state lists (the union's
+            # foreign ids came on top), so equal lengths are equal sets.
+            listed = st.done_ids if st.cand_ids is None else st.cand_ids
+            own_mask = (
+                None
+                if len(listed) == len(cand_ids)
+                else np.isin(listed, cand_ids, assume_unique=True)
             )
-            parts[i] = (frag, cand_ids, cand_mask, st, sub_ref, srcw, src_slot)
-        # "scores" memoizes the fetched count vectors for as long as
+            parts[i] = (frag, cand_ids, own_mask, st, sub_ref, srcw, src_slot)
+        score = topn_stack.score_stack(
+            [(st, ref, srcw, slot, frag) for frag, _, _, st, ref, srcw, slot in parts]
+        )
+        pins = tuple(p[0]._pool_key for p in parts)
+        # The mirrors the parts read stay recent in the residency pool:
+        # one hold of its lock a build, not one a fragment.
+        device_mod.pool().touch_many(pins)
+        # "scores" memoizes the fetched score vector for as long as
         # the ENTRY validates (fragments unchanged since build =>
         # scores unchanged); "score_event" single-flights the fused
         # scorer across concurrent queries of this entry (leader
@@ -3409,6 +3382,9 @@ class Executor:
             "parts": parts,
             "union": len(union),
             "build": "walked" if walk else "direct",
+            "score": score,
+            "stack": topn_stack.stack_parts(parts, union, score),
+            "pins": pins,
         }
 
     def _execute_topn_folded(
@@ -3449,23 +3425,14 @@ class Executor:
         if ent.get("two_phase"):
             return self._execute_topn_two_phase(index, c, slices, opt, n)
 
-        # Clone the unscored states (the prep is shared across
-        # concurrent queries; scores are per-query), dispatch, fetch.
-        states: list[tuple] = []
-        score_parts: list[tuple] = []
-        for frag, cand_ids, cand_mask, st_proto, sub_ref, srcw, src_slot in ent[
-            "parts"
-        ]:
-            st = replace(st_proto, counts=None, dev_counts=None)
-            states.append((frag, cand_ids, cand_mask, st))
-            score_parts.append((st, sub_ref, srcw, src_slot, frag))
         # Score ONCE per validated entry: concurrent queries of the
         # same TopN shape single-flight (one leader dispatches +
         # fetches; everyone else waits on an Event — never on a lock —
-        # and reuses the fetched count vectors).  Scores stay valid
+        # and reuses the fetched score vector).  Scores stay valid
         # exactly as long as the entry does: entry validation already
-        # proved the scored fragments unchanged since build.
-        with self.tracer.span("topn.score", parts=len(score_parts)) as sp:
+        # proved the scored fragments unchanged since build.  The
+        # vector is the only state an answer adds to the entry's arrays.
+        with self.tracer.span("topn.score", parts=len(ent["parts"])) as sp:
             scores = None
             leader = False
             ev = None
@@ -3477,8 +3444,8 @@ class Executor:
                         ev = ent["score_event"] = threading.Event()
                         leader = True
             if scores is None and not leader:
-                # A leader is scoring right now; its fetched vectors
-                # arrive with the event.  A failed leader leaves
+                # A leader is scoring right now; its fetched vector
+                # arrives with the event.  A failed leader leaves
                 # scores unset — fall through and score directly.
                 ev.wait(timeout=coalesce_mod.RESULT_TIMEOUT_S)
                 with self._batch_mu:
@@ -3489,73 +3456,34 @@ class Executor:
                     # mirror for the fused scorer's dispatch+fetch: the
                     # pool may evict none of the planes this program
                     # reads mid-query.
-                    pin_keys = [
-                        self._topn_pool_key((index, str(c), tuple(slices)))
-                    ]
-                    pin_keys += [p[0]._pool_key for p in ent["parts"]]
-                    with device_mod.pool().pinned(*pin_keys):
-                        self._score_topn_parts(score_parts)
+                    with device_mod.pool().pinned(
+                        self._topn_pool_key((index, str(c), tuple(slices))),
+                        *ent["pins"],
+                    ):
+                        scores = self._score_topn_parts(ent["score"])
                     with self._batch_mu:
-                        ent["scores"] = [p[0].counts for p in score_parts]
+                        ent["scores"] = scores
                     sp.annotate(score_cache="computed")
                 finally:
                     if leader:
                         ev.set()
             else:
-                for part, cnts in zip(score_parts, scores):
-                    part[0].counts = cnts
                 sp.annotate(score_cache="shared")
                 self.holder.stats.count("exec.topn.scoreShared")
 
-        # Phase-1 winner selection per slice, from the same scores the
-        # two-phase protocol's first round would have produced for the
-        # slice's own candidates (cand_ids is a subset of the union) —
-        # all in numpy: at union scale, Pair-object bookkeeping in
-        # Python dominated warm TopN host time.  The ``topn.select``
-        # span is the host-winner-selection leg of the per-stage
-        # TopN(src) breakdown (with topn.dispatch/topn.fetch).
-        with self.tracer.span("topn.select", parts=len(states)):
-            winner_ids: list[np.ndarray] = []
-            fulls: list[tuple[np.ndarray, np.ndarray]] = []
-            has_src = len(c.children) == 1
-            for frag, cand_ids, cand_mask, st in states:
-                ids, cnts, keep, short = frag.top_score_arrays(st)
-                fulls.append((ids[keep], cnts[keep]))
-                if not has_src:
-                    winner_ids.append(cand_ids[:n] if n else cand_ids)
-                elif short:
-                    # Scoring short-circuited (e.g. no src segment
-                    # here): the subset selection would short-circuit
-                    # identically.
-                    winner_ids.append(ids)
-                else:
-                    sel_ids, _ = frag.select_winners(
-                        ids, cnts, keep, cand_ids, n, cand_mask=cand_mask
-                    )
-                    winner_ids.append(sel_ids)
-            ids2 = (
-                np.unique(np.concatenate(winner_ids))
-                if winner_ids
-                else np.empty(0, np.int64)
-            )
-            if not len(ids2):
-                return []
-
-            # Phase-2 equivalent: exact counts for the winner union,
-            # already in hand; counts SUM across slices (reference
-            # reduce: Pairs.Add, cache.go:312-334).
-            kept = []
-            for i, cts in fulls:
-                m = isin_sorted(i, ids2)
-                kept.append((i[m], cts[m]))
-            merged = merge_counts_by_id(kept)
-            if merged is None:
-                return []
-            uids, sums = merged
-            order = np.lexsort((uids, -sums))
-            if n and n < len(order):
-                order = order[:n]
-            return [Pair(int(uids[k]), int(sums[k])) for k in order]
+        # Both protocol phases from the one score vector, over the
+        # entry's stacked arrays (topn_stack.select): each slice's
+        # phase-1 winners among its own candidates, as the two-phase
+        # protocol's first round would have chosen them, then exact
+        # sums for the winner union, which are already in hand.  No
+        # call a part: ``way`` says so.  The ``topn.select`` span is
+        # the host-winner-selection leg of the per-stage TopN(src)
+        # breakdown (with topn.dispatch/topn.fetch).
+        with self.tracer.span(
+            "topn.select", parts=len(ent["parts"]), way="stacked"
+        ):
+            ids, sums = topn_stack.select(ent["stack"], scores, n)
+            return [Pair(i, cnt) for i, cnt in zip(ids.tolist(), sums.tolist())]
 
     def _execute_topn_slices(
         self, index: str, c: Call, slices: list[int], opt: ExecOptions
@@ -3586,15 +3514,15 @@ class Executor:
                 for s in local_slices
             ]
             states = [p for p in prepped if p is not None]
-            with device_mod.pool().pinned(
-                *[frag._pool_key for frag, _ in states]
-            ):
-                self._score_topn_parts(
-                    [
-                        (*self._attach_dev_src(index, c, frag, part), frag)
-                        for frag, part in states
-                    ]
-                )
+            entries = [
+                (*self._attach_dev_src(index, c, frag, part), frag)
+                for frag, part in states
+            ]
+            stack = topn_stack.score_stack(entries)
+            pool, pins = device_mod.pool(), [frag._pool_key for frag, _ in states]
+            pool.touch_many(pins)
+            with pool.pinned(*pins):
+                stack.hand_out(entries, self._score_topn_parts(stack))
             states = [(frag, part[0]) for frag, part in states]
             # Merge all slices' results in one numpy pass (counts sum
             # by id — Pairs.Add semantics, reference: cache.go:312-334);

@@ -16,7 +16,7 @@ import pytest
 
 from pilosa_tpu import device as device_mod
 from pilosa_tpu.core.holder import Holder
-from pilosa_tpu.exec import plan
+from pilosa_tpu.exec import plan, topn_stack
 from pilosa_tpu.exec.executor import Executor
 from pilosa_tpu.net.client import InternalClient
 from pilosa_tpu.net.server import Server
@@ -149,7 +149,10 @@ def test_the_bucketed_scorer_gives_hostevals_vectors(one_chip, many_slices, memb
         parts = _score_parts(ex, "TopN(Bitmap(frame=f, rowID=1), frame=f, n=10)",
                              list(range(members)))
         assert all(p[3] is not None for p in parts)  # src read from the plane
-        ex._score_topn_parts(parts)
+        stack = topn_stack.score_stack(parts)
+        assert [g.slots.shape for g in stack.groups] == [(members, len(parts[0][1].slots))]
+        assert stack.groups[0].slots.flags.c_contiguous
+        stack.hand_out(parts, ex._score_topn_parts(stack))
         device = [np.array(p[0].counts[: len(p[0].dense_pos)]) for p in parts]
         assert len(device) == members and any(v.any() for v in device)
         for p in parts:
@@ -171,7 +174,9 @@ def test_the_number_of_scorer_programs_does_not_depend_on_the_slice_count(
         text = "TopN(Bitmap(frame=f, rowID=1), frame=f, n=10)"
         seen = []
         for members in (G - 24, G + 6, n):  # 40, 70 and 191 fragments at G = 64
-            ex._score_topn_parts(_score_parts(ex, text, list(range(members))))
+            ex._score_topn_parts(
+                topn_stack.score_stack(_score_parts(ex, text, list(range(members))))
+            )
             seen.append(plan.program_cache_stats()["bitplane.scorePlanes"])
         assert seen == [1, 1, 1]
         assert bp.shape_highwater()["score_frags"] == G
@@ -519,7 +524,7 @@ def test_the_cells_files_are_found_by_name(tiny_bench, kind):
     listed = {m["name"] for m in cell.per_layer}
     assert {"exec.topn_prep_ms", "exec.topn_select_ms", "device.topn_dispatch_ms",
             "device.topn_fetch_ms", "exec.topn_scored_share",
-            "device.topn_roofline"} <= listed
+            "exec.topn_select_stacked_share", "device.topn_roofline"} <= listed
     assert not {"exec.plan_ms", "exec.map_local_self_ms", "device.count_roofline"} & listed
 
 
@@ -558,6 +563,8 @@ def test_a_traced_rehearsal_is_correct_and_reads_every_listed_metric(
     m = {k: v["value"] for k, v in line["metrics"].items()}
     # 68 texts over a prep cache of 8: no text finds its memo again
     assert m["exec.topn_scored_share"] >= 90.0
+    # every answer selected from the entry's stacked arrays: no call a part
+    assert m["exec.topn_select_stacked_share"] == 100.0
     assert m["device.window_new_programs"] == 0 and m["device.window_compile_ms"] == 0
     assert m["exec.topn_prep_ms"] > 0 and m["device.topn_dispatch_ms"] > 0
 

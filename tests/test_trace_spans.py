@@ -410,7 +410,8 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
     assert compiles[0]["tags"]["family"] == "topn.score"
     assert plan.program_cache_compile_ms()["topn.score"] >= compiles[0]["duration_ms"]
     assert _span(first, "topn.fetch")["tags"]["arrays"] == launches
-    assert _span(first, "topn.select")["tags"]["parts"] == slices
+    # selected from the entry's stacked arrays, no call a part (``way``)
+    assert _span(first, "topn.select")["tags"] == {"parts": slices, "way": "stacked"}
 
     # the same text again inside the memo's lifetime: nothing is scored
     assert topn(TOPN_SRC) == want

@@ -12,7 +12,7 @@ default is 60 s), checks the answer against the kind's reference and
 prints the request's spans from ``/debug/traces``: ``topn.prep``,
 ``topn.dispatch`` and the ``compile`` under it, ``topn.fetch``,
 ``topn.select``, and under ``stages`` their milliseconds on one line with
-``topn.prep``'s ``prep_cache`` and ``build`` tags.  Then it stops the server
+``topn.prep``'s ``prep_cache`` and ``build`` tags and ``topn.select``'s ``way``.  Then it stops the server
 and boots it again on the same data: a new process, so the first request
 there shows what the persistent compile cache saves.  Last,
 ``--concurrent`` distinct texts at once, as the cell's warm-up sends them.  One JSON document on
@@ -87,15 +87,19 @@ def spans_of(server: Server, trace_id: str) -> list[dict]:
 
 def stages(spans: list[dict]) -> dict:
     """A request's stages on one line: the milliseconds of each TopN
-    span, how the prep entry was come by (``prep_cache``) and, where it
-    was built, whether any fragment was walked (``build``; a program
-    from before that tag prints None)."""
+    span, how the prep entry was come by (``prep_cache``), where it
+    was built, whether any fragment was walked (``build``), and whether
+    the winners were selected from the entry's stacked arrays
+    (``topn.select``'s ``way``: ``stacked``); a program from before a
+    tag prints None for it."""
     by_name = {s["name"]: s for s in spans}
     out = {name.removeprefix("topn."): by_name[name]["ms"]
            for name in ("topn.prep", "topn.dispatch", "topn.fetch", "topn.select")
            if name in by_name}
     tags = by_name.get("topn.prep", {}).get("tags", {})
-    return dict(out, prep_cache=tags.get("prep_cache"), build=tags.get("build"))
+    way = by_name.get("topn.select", {}).get("tags", {}).get("way")
+    return dict(out, prep_cache=tags.get("prep_cache"), build=tags.get("build"),
+                way=way)
 
 
 def probe(server: Server, cell, ref, srcs, deadline_ms: int) -> list[dict]:
