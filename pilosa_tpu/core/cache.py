@@ -20,6 +20,8 @@ from __future__ import annotations
 
 import time
 from collections import OrderedDict
+
+import numpy as np
 from dataclasses import dataclass
 from collections.abc import Iterable
 from typing import Protocol
@@ -64,6 +66,7 @@ class Cache(Protocol):
 
     def add(self, row_id: int, n: int) -> None: ...
     def bulk_add(self, row_id: int, n: int) -> None: ...
+    def bulk_add_many(self, row_ids, counts) -> None: ...
     def get(self, row_id: int) -> int: ...
     def len(self) -> int: ...
     def ids(self) -> list[int]: ...
@@ -90,6 +93,10 @@ class LRUCache:
             self.stats.count("cacheEvict")
 
     bulk_add = add
+
+    def bulk_add_many(self, row_ids, counts) -> None:
+        for row_id, n in zip(row_ids.tolist(), counts.tolist()):
+            self.add(row_id, n)
 
     def get(self, row_id: int) -> int:
         if row_id in self._od:
@@ -118,8 +125,6 @@ class LRUCache:
         """(ids, counts) int64 ndarrays in canonical (-count, id) order —
         the array-native twin of top() (LRU caches are small; built on
         demand)."""
-        import numpy as np
-
         pairs = self.top()
         n = len(pairs)
         return (
@@ -141,8 +146,11 @@ class RankCache:
     def __init__(self, max_entries: int = DEFAULT_CACHE_SIZE):
         self.max_entries = max_entries or DEFAULT_CACHE_SIZE
         self.entries: dict[int, int] = {}
-        self._rankings: list[Pair] = []
-        self._arrays = None  # (ids, counts) mirror of _rankings
+        # The ranking: (ids, counts) int64 arrays, count falling and ids
+        # rising.  Arrays, because a fragment whose rows are molecules
+        # ranks millions of entries: a sort of Pair objects there holds
+        # the process's one GIL for seconds, an array sort for none.
+        self._arrays = (np.empty(0, np.int64), np.empty(0, np.int64))
         self._updated_at = 0.0
         self._stale = True
         self.threshold_value = 0
@@ -168,6 +176,25 @@ class RankCache:
 
     bulk_add = add
 
+    def bulk_add_many(self, row_ids, counts) -> None:
+        """``bulk_add`` for each of ``row_ids`` (int64 array) with its
+        count, in order.  While the cache has no floor and the rows
+        cannot overflow it, no add depends on another and the entries
+        take them in one update, with no Python step a row."""
+        if (
+            self.threshold_value
+            or len(self.entries) + len(row_ids)
+            > self.max_entries * THRESHOLD_FACTOR
+        ):
+            for row_id, n in zip(row_ids.tolist(), counts.tolist()):
+                self.add(row_id, n)
+            return
+        live = counts != 0
+        self.entries.update(zip(row_ids[live].tolist(), counts[live].tolist()))
+        for row_id in row_ids[~live].tolist():
+            self.entries.pop(row_id, None)
+        self._stale = True
+
     def get(self, row_id: int) -> int:
         n = self.entries.get(row_id)
         if n is None:
@@ -192,51 +219,47 @@ class RankCache:
         self._recompute(force=True)
 
     def top(self) -> list[Pair]:
-        self._recompute()
-        return list(self._rankings)
+        ids, counts = self.top_arrays()
+        return [Pair(i, c) for i, c in zip(ids.tolist(), counts.tolist())]
 
     def top_arrays(self):
-        """(ids, counts) int64 ndarrays mirroring top()'s ranking order,
-        cached until the next re-sort — the folded TopN path consumes
+        """(ids, counts) int64 ndarrays in ranking order, the same two
+        objects until the next re-sort — the folded TopN path consumes
         candidates array-native, so the per-query cost is two array
         reads instead of an O(cache) Pair walk."""
-        import numpy as np
-
         self._recompute()
-        if self._arrays is None:
-            n = len(self._rankings)
-            self._arrays = (
-                np.fromiter((p.id for p in self._rankings), np.int64, n),
-                np.fromiter((p.count for p in self._rankings), np.int64, n),
-            )
         return self._arrays
+
+    def _ranked(self):
+        """The entries as ``(ids, counts)`` in canonical order, the
+        first ``max_entries`` of them."""
+        n = len(self.entries)
+        ids = np.fromiter(self.entries.keys(), np.int64, n)
+        counts = np.fromiter(self.entries.values(), np.int64, n)
+        order = np.lexsort((ids, -counts))[: self.max_entries]
+        return ids[order], counts[order]
 
     def _recompute(self, force: bool = False) -> None:
         now = time.monotonic()
         if not self._stale:
             return
-        if not force and self._rankings and (
+        if not force and len(self._arrays[0]) and (
             now - self._updated_at < RECALCULATE_INTERVAL_S
         ):
             return
-        self._rankings = sort_pairs(
-            Pair(i, c) for i, c in self.entries.items()
-        )[: self.max_entries]
-        self._arrays = None
+        self._arrays = self._ranked()
         self._updated_at = now
         self._stale = False
 
     def _prune(self) -> None:
         dropped = len(self.entries)
-        keep = sort_pairs(Pair(i, c) for i, c in self.entries.items())[
-            : self.max_entries
-        ]
-        self.entries = {p.id: p.count for p in keep}
+        ids, counts = self._ranked()
+        self.entries = dict(zip(ids.tolist(), counts.tolist()))
         dropped -= len(self.entries)
         if dropped > 0:
             self.stats.count("cacheEvict", dropped)
-        if len(keep) == self.max_entries and keep:
-            self.threshold_value = keep[-1].count
+        if len(ids) == self.max_entries and len(ids):
+            self.threshold_value = int(counts[-1])
         self._stale = True
 
 

@@ -60,14 +60,17 @@ SLICE_WIDTH = bp.SLICE_WIDTH
 # reference: fragment.go:58-65
 HASH_BLOCK_SIZE = 100
 DEFAULT_FRAGMENT_MAX_OP_N = 2000
-# Dense-tier budget: up to this many rows live in the device-mirrored
-# dense plane (128 KiB/row — the batched-kernel fast path).  Rows beyond
+# Dense-tier budget: the device-mirrored dense plane (the batched-kernel
+# fast path) holds rows up to DENSE_PLANE_BYTES: 65,536 rows of a plane
+# whose rows span the whole slice (128 KiB each), 16.8M rows of one whose
+# columns end at 4,096 (512 B each: ``bp.row_words``).  Rows beyond
 # the budget live in the SPARSE tier as sorted uint32 offset arrays,
 # paying only for set bits — the dense-plane analog of roaring's
 # pay-per-container storage (reference: roaring/roaring.go:43-52), so
 # tall-sparse fragments (inverse views, where the row axis is the
 # column space — up to 2^20 distinct rows per slice) are unbounded.
 DENSE_ROW_BUDGET = 1 << 16
+DENSE_PLANE_BYTES = DENSE_ROW_BUDGET * bp.WORDS_PER_SLICE * 4
 # Sparse rows whose bit count crosses this are promoted to the dense
 # tier when budget remains: past it, offset arrays (4 B/bit) cost more
 # than the 128 KiB plane row.
@@ -337,6 +340,26 @@ class TopLayout:
     ranked: tuple
 
 
+@dataclass(frozen=True, eq=False)
+class RowsLayout:
+    """A fragment's ranked candidates BY PLANE SLOT, for the scorer that
+    walks every row of the plane (``bp.score_rows``): ``ids`` int64[plane
+    rows], the row in each slot (-1: none); ``cnts`` the ranked cache's
+    count of it as an int32 device array beside the mirror (0: no
+    candidate — an empty slot, a row the cache does not rank);
+    ``window`` the ranked counts ascending, which answer "how many
+    candidates does a count window keep" by two binary searches.  Made
+    by ``Fragment.rows_layout`` and valid for the fragment ``version``
+    and the rank-cache arrays ``ranked`` it was made from; shared by
+    every query that uses it and never written."""
+
+    ids: np.ndarray
+    cnts: object
+    window: np.ndarray
+    version: int
+    ranked: tuple
+
+
 class Fragment:
     """One frame-view x slice bit-plane with caches and sync hooks."""
 
@@ -350,7 +373,7 @@ class Fragment:
         cache_type: str = cache_mod.TYPE_RANKED,
         cache_size: int = cache_mod.DEFAULT_CACHE_SIZE,
         max_op_n: int = DEFAULT_FRAGMENT_MAX_OP_N,
-        dense_row_budget: int = DENSE_ROW_BUDGET,
+        dense_row_budget: int | None = None,
     ):
         self.path = path
         self.index = index
@@ -360,6 +383,8 @@ class Fragment:
         self.cache_type = cache_type
         self.cache_size = cache_size
         self.max_op_n = max_op_n
+        # Rows of the dense tier; None (every caller but a test): as
+        # many as DENSE_PLANE_BYTES holds at the plane's row width.
         self.dense_row_budget = dense_row_budget
 
         self.row_attr_store = None  # wired by Frame
@@ -380,8 +405,11 @@ class Fragment:
         # dense_row_budget touched rows (device-mirrored fast path);
         # _slot_of maps logical row id -> slot.  SPARSE: every further
         # row is a sorted uint32 array of in-slice bit offsets — memory
-        # scales with set bits, so fragments are row-unbounded.
-        self._plane = bp.empty_plane(bp.ROW_BLOCK)
+        # scales with set bits, so fragments are row-unbounded.  A
+        # plane row is as many words as the columns the fragment holds
+        # ask for (bp.row_words: a pow2 class, derived from the data
+        # alone); a write beyond it re-lays the plane (_relayout_locked).
+        self._plane = bp.empty_plane(bp.ROW_BLOCK, bp.MIN_ROW_WORDS)
         self._slot_of: dict[int, int] = {}
         self._sparse: dict[int, np.ndarray] = {}
         # Sparse rows paged to the home device for query leaves (LRU).
@@ -403,8 +431,11 @@ class Fragment:
         self._tier_arrays = None
         self._tier_arrays_version = -1
         # Gather layout of the ranked candidates (see top_layout), kept
-        # until a write or a rank-cache re-sort.
+        # until a write or a rank-cache re-sort; and the same candidates
+        # by plane slot, for the scorer that walks rows (rows_layout).
         self._top_layout: TopLayout | None = None
+        self._rows_layout: RowsLayout | None = None
+        self._rows_pool_key = ("frag", self._serial, "rowcounts")
         self._max_row_id = 0
         self._op_n = 0
         self._version = 0
@@ -619,6 +650,8 @@ class Fragment:
             self._sparse_dev_nbytes = 0
             self._payload_cache.clear()
             device_mod.pool().remove(self._sparse_pool_key)
+            self._rows_layout = None
+            device_mod.pool().remove(self._rows_pool_key)
             self._opened = False
             # A fragment leaving service (shutdown OR frame/index/view
             # deletion) must invalidate epoch-validated read caches —
@@ -752,6 +785,52 @@ class Fragment:
     def max_row_id(self) -> int:
         return self._max_row_id
 
+    def _dense_cap(self, words: int | None = None) -> int:
+        """Rows the dense tier may hold at plane rows of ``words`` words
+        (the plane's own when None)."""
+        if self.dense_row_budget is not None:
+            return self.dense_row_budget
+        return DENSE_PLANE_BYTES // (4 * (words or self._plane.shape[1]))
+
+    def _ensure_width_locked(self, max_offset: int) -> None:
+        """Make the plane's rows cover in-slice column ``max_offset``."""
+        words = bp.row_words(max_offset)
+        if words > self._plane.shape[1]:
+            self._relayout_locked(words)
+
+    def _relayout_locked(self, words: int) -> None:
+        """Re-lay the dense plane at rows of ``words`` words: a write
+        beyond the columns the plane covered.  Rare (the width is a pow2
+        class: at most eight times in a fragment's life) and structural:
+        the mirror is dropped and the version moves.  Rows the byte
+        budget no longer holds at the new width move to the sparse
+        tier, last slots first, so one far column never turns a million
+        512 B rows into a million 128 KiB ones."""
+        old = self._plane
+        keep = min(len(self._slot_of), self._dense_cap(words))
+        demoted = keep < len(self._slot_of)
+        if demoted:
+            for row_id, slot in list(self._slot_of.items()):
+                if slot >= keep:
+                    del self._slot_of[row_id]
+                    self._sparse[row_id] = bp.np_row_to_columns(old[slot]).astype(
+                        np.uint32
+                    )
+                    self._row_cache.pop(row_id, None)
+        rows = bp.pad_rows(max(keep, 1)) if demoted else old.shape[0]
+        plane = bp.empty_plane(rows, words)
+        n = min(rows, old.shape[0])
+        plane[:n, : old.shape[1]] = old[:n]
+        self._plane = plane
+        self._tier_arrays = None
+        if self._slot_of:
+            self.stats.count("fragment.relayouts")
+        if self._device is not None:
+            ingest_scatter.note_fallback()
+        self._invalidate_device()
+        self._version += 1
+        _bump_write_epoch()
+
     def _ensure_slot(self, row_id: int) -> int | None:
         """Dense-tier slot for a row, or None when the row lives in (or
         a first touch lands in) the SPARSE tier.  Dense capacity is
@@ -769,7 +848,7 @@ class Fragment:
         if row_id >= MAX_ROW_ID:
             raise FragmentError(f"row id out of range: {row_id}")
         self._max_row_id = max(self._max_row_id, row_id)
-        if len(self._slot_of) >= self.dense_row_budget:
+        if len(self._slot_of) >= self._dense_cap():
             self._sparse[row_id] = np.empty(0, dtype=np.uint32)
             self._count_of[row_id] = 0
             return None
@@ -783,7 +862,7 @@ class Fragment:
         needed = bp.pad_rows(slot + 1)
         if needed > self._plane.shape[0]:
             self._reserve_dense(
-                max(needed, min(2 * self._plane.shape[0], self.dense_row_budget))
+                max(needed, min(2 * self._plane.shape[0], self._dense_cap()))
             )
         return slot
 
@@ -795,7 +874,7 @@ class Fragment:
         needed = bp.pad_rows(max(n_slots, 1))
         if needed > self._plane.shape[0]:
             extra = np.zeros(
-                (needed - self._plane.shape[0], bp.WORDS_PER_SLICE), np.uint32
+                (needed - self._plane.shape[0], self._plane.shape[1]), np.uint32
             )
             self._plane = np.vstack([self._plane, extra])
             # the device mirror no longer matches the plane's shape —
@@ -812,15 +891,17 @@ class Fragment:
         if (
             offs is None
             or len(offs) <= PROMOTE_BITS
-            or len(self._slot_of) >= self.dense_row_budget
+            or len(self._slot_of) >= self._dense_cap(bp.row_words(int(offs[-1])))
         ):
             return
+        self._ensure_width_locked(int(offs[-1]))
         del self._sparse[row_id]
         self._payload_cache.pop(row_id, None)
         if self._sparse_dev.pop(row_id, None) is not None:
             self._sync_sparse_pool_locked()
         slot = self._alloc_dense_slot(row_id)
-        self._plane[slot] = bp.np_columns_to_row(offs)
+        self._tier_arrays = None
+        self._plane[slot] = bp.np_columns_to_row(offs, self._plane.shape[1])
         # Tier promotion rewrites a whole plane row — structural, not a
         # per-bit delta the scatter path can carry.
         if self._device is not None:
@@ -868,22 +949,6 @@ class Fragment:
             np.add.reduceat(ns, starts) if n_cont else np.zeros(0, np.int64)
         )
         order = np.argsort(-row_counts, kind="stable")
-        dense_rows = sorted(
-            int(uniq_rows[i]) for i in order[: self.dense_row_budget]
-        )
-        slot_of = {r: i for i, r in enumerate(dense_rows)}
-        plane = bp.empty_plane(bp.pad_rows(len(dense_rows)))
-        sparse: dict[int, np.ndarray] = {}
-
-        # Per-container slot (-1 = sparse tier), via the uniq_rows table.
-        slot_table = np.asarray(
-            [slot_of.get(int(r), -1) for r in uniq_rows], dtype=np.int64
-        )
-        cont_slots = (
-            slot_table[np.searchsorted(uniq_rows, rows_of)]
-            if n_cont
-            else np.zeros(0, np.int64)
-        )
 
         # One u32 view over the payload region (no copy; op-log records
         # after ops_offset are 13-byte and break 4-alignment, so the
@@ -892,6 +957,36 @@ class Fragment:
 
         amask = ns <= roaring.ARRAY_MAX_SIZE if n_cont else np.zeros(0, bool)
         bmask = ~amask if n_cont else amask
+
+        # The plane's row width, from the highest column a container
+        # holds: a bitmap container counts whole, an array container to
+        # its last (largest) value.
+        words = bp.MIN_ROW_WORDS
+        if n_cont:
+            cidx_all = (keys % cps).astype(np.int64)
+            top = np.flatnonzero(cidx_all == cidx_all.max())
+            last = np.where(
+                amask[top],
+                u32[np.minimum(offs[top] // 4 + ns[top] - 1, len(u32) - 1)],
+                cbits - 1,
+            )
+            words = bp.row_words(int(cidx_all.max()) * cbits + int(last.max()))
+
+        dense_rows = np.sort(uniq_rows[order[: self._dense_cap(words)]])
+        slot_of = dict(zip(dense_rows.tolist(), range(len(dense_rows))))
+        plane = bp.empty_plane(bp.pad_rows(len(dense_rows)), words)
+        sparse: dict[int, np.ndarray] = {}
+
+        # Per-container slot (-1 = sparse tier), via the uniq_rows table.
+        slot_table = np.full(len(uniq_rows), -1, dtype=np.int64)
+        slot_table[np.searchsorted(uniq_rows, dense_rows)] = np.arange(
+            len(dense_rows)
+        )
+        cont_slots = (
+            slot_table[np.searchsorted(uniq_rows, rows_of)]
+            if n_cont
+            else np.zeros(0, np.int64)
+        )
 
         # Sparse rows holding any BITMAP container are rebuilt
         # per-row below (two payload forms must interleave in key
@@ -1029,16 +1124,15 @@ class Fragment:
         # like the replaced decode path's np_count sweep).  Row-block
         # sweeps keep the popcount temp out of the open peak.
         counts: dict[int, int] = {}
-        if dense_rows:
+        if len(dense_rows):
+            step = max(256, (1 << 22) // words)  # 16 MiB of plane a sweep
             cnts = np.concatenate(
                 [
-                    bp.np_row_counts(plane[b : b + 256])
-                    for b in range(0, len(dense_rows), 256)
+                    bp.np_row_counts(plane[b : b + step])
+                    for b in range(0, len(dense_rows), step)
                 ]
             )
-            counts.update(
-                (r, int(cnts[slot])) for r, slot in slot_of.items()
-            )
+            counts.update(zip(dense_rows.tolist(), cnts.tolist()))
         counts.update((r, len(offs_r)) for r, offs_r in sparse.items())
 
         # ---- op-log replay over the freshly-built tiers.
@@ -1049,18 +1143,26 @@ class Fragment:
             row, offset = divmod(value, SLICE_WIDTH)
             slot = slot_of.get(row)
             if slot is None and row not in sparse:
-                if len(slot_of) < self.dense_row_budget:
+                if len(slot_of) < self._dense_cap(plane.shape[1]):
                     slot = slot_of[row] = len(slot_of)
                     if slot >= plane.shape[0]:
                         extra = np.zeros(
                             (bp.pad_rows(slot + 1) - plane.shape[0],
-                             bp.WORDS_PER_SLICE),
+                             plane.shape[1]),
                             np.uint32,
                         )
                         plane = np.vstack([plane, extra])
                 else:
                     sparse[row] = np.empty(0, np.uint32)
                 counts.setdefault(row, 0)
+            if slot is not None and offset >= plane.shape[1] * bp.WORD_BITS:
+                if typ != roaring.OP_ADD:
+                    continue  # nothing is set beyond the plane's columns
+                # the replayed twin of _relayout_locked (less its
+                # demotion: a row the log adds stays where it was put)
+                wide = bp.empty_plane(plane.shape[0], bp.row_words(offset))
+                wide[:, : plane.shape[1]] = plane
+                plane = wide
             if slot is not None:
                 if typ == roaring.OP_ADD:
                     changed = bp.np_set_bit(plane, slot * SLICE_WIDTH + offset)
@@ -1088,6 +1190,7 @@ class Fragment:
         self._slot_of = slot_of
         self._plane = plane
         self._sparse = sparse
+        self._tier_arrays = None
         self._sparse_dev.clear()
         self._payload_cache.clear()
         self._sync_sparse_pool_locked()
@@ -1118,18 +1221,32 @@ class Fragment:
             counts[row] = counts.get(row, 0) + len(vals)
 
         by_density = sorted(per_row, key=lambda r: (-counts[r], r))
-        dense_rows = sorted(by_density[: self.dense_row_budget])
-        sparse_rows = by_density[self.dense_row_budget :]
+        cbits = roaring.CONTAINER_BITS
+        words = bp.row_words(
+            max(
+                (
+                    cidx * cbits
+                    + (int(payload[-1]) if is_vals and len(payload) else cbits - 1)
+                    for parts in per_row.values()
+                    for cidx, payload, is_vals in parts
+                ),
+                default=0,
+            )
+        )
+        cap = self._dense_cap(words)
+        dense_rows = sorted(by_density[:cap])
+        sparse_rows = by_density[cap:]
 
         self._slot_of = {r: i for i, r in enumerate(dense_rows)}
-        plane = bp.empty_plane(bp.pad_rows(len(dense_rows)))
+        plane = bp.empty_plane(bp.pad_rows(len(dense_rows)), words)
         wpc = bp.WORDS_PER_CONTAINER
         for i, r in enumerate(dense_rows):
             for cidx, payload, is_vals in per_row[r]:
                 w = roaring.values_to_words(payload) if is_vals else payload
-                plane[i, cidx * wpc : (cidx + 1) * wpc] = (
-                    w.view("<u4").astype(np.uint32)
-                )
+                w = w.view("<u4").astype(np.uint32)
+                lo = cidx * wpc
+                hi = min(lo + wpc, words)  # an array container may end early
+                plane[i, lo:hi] = w[: hi - lo]
         self._plane = plane
 
         self._sparse = {}
@@ -1148,67 +1265,80 @@ class Fragment:
         self._sync_sparse_pool_locked()
 
         self._max_row_id = max(per_row) if per_row else 0
+        self._tier_arrays = None
         self._count_of = counts
         self._block_sums.clear()
         self._dirty_blocks.clear()
         self._invalidate_device()
         _bump_write_epoch()
 
-    def _containers_packed(
-        self,
-    ) -> tuple[np.ndarray, np.ndarray, dict[int, np.ndarray]]:
-        """Current storage as (dense keys, dense payloads, sparse value
-        arrays) for serialization — the dense tier packs into two
-        contiguous buffers with no per-container Python, and sparse rows
-        convert offsets->values directly, never materializing a plane
-        row.  Returns ``(keys u64 ascending, words2d u64[n, 1024],
-        arrays)`` for roaring.encode_packed."""
+    def _containers_packed(self) -> tuple[np.ndarray, np.ndarray, tuple]:
+        """Current storage for serialization, with no Python step a row
+        or a container in the dense tier: ``(keys u64 ascending,
+        words2d u64[n, 1024], (akeys, acounts, avalues))`` for
+        roaring.encode_packed — the containers held as bitmaps, and
+        those held as value arrays (keys ascending, each container's
+        count, every container's values one after another).  A plane
+        whose rows span whole containers packs them as bitmaps; one
+        whose rows are narrower than a container (a row is the head of
+        its container 0) gives each row's values straight from its set
+        words (``bp.np_plane_positions``): a million such rows as
+        bitmaps would be 8 KiB each.  Sparse rows convert offsets ->
+        values directly, never materializing a plane row."""
         wpc = bp.WORDS_PER_CONTAINER
         cps = bp.CONTAINERS_PER_SLICE
         cbits = roaring.CONTAINER_BITS
-        arrays: dict[int, np.ndarray] = {}
-        # Dense tier, fully vectorized (in row blocks to bound the
-        # transient gather copy): nonzero mask -> boolean-select both
-        # the keys and the payload rows; no per-container Python.
+        width = int(self._plane.shape[1])
+        ids, slots, _ = self._tier_key_arrays_locked()  # ids ascending
+        n = len(ids)
         key_blocks: list[np.ndarray] = []
         payload_blocks: list[np.ndarray] = []
-        if self._slot_of:
-            items = list(self._slot_of.items())
-            BLOCK = 256  # rows per sweep: 32 MiB transient
-            for b in range(0, len(items), BLOCK):
-                chunk_items = items[b : b + BLOCK]
-                rows_arr = np.asarray([r for r, _ in chunk_items], np.int64)
-                slots = np.asarray([s for _, s in chunk_items], dtype=np.intp)
-                # Bulk-import fragments allocate slots sequentially, so
-                # the common case is a contiguous ascending run — slice
-                # a VIEW instead of gather-copying 32 MiB per block.
-                if len(slots) and (
-                    slots[-1] - slots[0] == len(slots) - 1
-                    and (np.diff(slots) == 1).all()
-                ):
-                    sub = self._plane[slots[0] : slots[-1] + 1]
-                else:
-                    sub = np.ascontiguousarray(self._plane[slots])
-                sub = sub.reshape(len(chunk_items), cps, wpc)
-                # Nonzero test on the u64 view: half the elements.
-                nonzero = sub.view(np.uint64).any(axis=2)
-                if not nonzero.any():
-                    continue
-                key_blocks.append(
-                    (rows_arr[:, None] * cps + np.arange(cps)[None, :])[
-                        nonzero
-                    ].astype(np.uint64)
-                )
-                payload_blocks.append(sub[nonzero])
+        akeys: list[np.ndarray] = []
+        acounts: list[np.ndarray] = []
+        avalues: list[np.ndarray] = []
+        step = max(1, (32 << 20) // (width * 4))  # rows a sweep: 32 MiB
+        for b in range(0, n, step):
+            at = slots[b : b + step]
+            # Bulk-import fragments allocate slots in id order, so the
+            # common case is a contiguous ascending run — slice a VIEW
+            # instead of gather-copying the block.
+            if at[-1] - at[0] == len(at) - 1 and (np.diff(at) == 1).all():
+                sub = self._plane[at[0] : at[-1] + 1]
+            else:
+                sub = self._plane[at]
+            rows_arr = ids[b : b + step]
+            if width < wpc:
+                cnts = bp.np_row_counts(sub)
+                small = (cnts > 0) & (cnts <= roaring.ARRAY_MAX_SIZE)
+                if small.any():
+                    akeys.append((rows_arr[small] * cps).astype(np.uint64))
+                    acounts.append(cnts[small])
+                    avalues.append(
+                        bp.np_plane_positions(sub if small.all() else sub[small])
+                    )
+                big = cnts > roaring.ARRAY_MAX_SIZE
+                if big.any():
+                    padded = np.zeros((int(big.sum()), wpc), np.uint32)
+                    padded[:, :width] = sub[big]
+                    key_blocks.append((rows_arr[big] * cps).astype(np.uint64))
+                    payload_blocks.append(padded)
+                continue
+            held = width // wpc  # whole containers a plane row spans
+            sub = np.ascontiguousarray(sub).reshape(len(at), held, wpc)
+            # Nonzero test on the u64 view: half the elements.
+            nonzero = sub.view(np.uint64).any(axis=2)
+            if not nonzero.any():
+                continue
+            key_blocks.append(
+                (rows_arr[:, None] * cps + np.arange(held)[None, :])[
+                    nonzero
+                ].astype(np.uint64)
+            )
+            payload_blocks.append(sub[nonzero])
         if key_blocks:
             keys = np.concatenate(key_blocks)
-            payloads = np.concatenate(payload_blocks)  # (n, wpc) uint32
-            if len(keys) > 1 and not (np.diff(keys.view(np.int64)) > 0).all():
-                order = np.argsort(keys, kind="stable")
-                keys = keys[order]
-                payloads = payloads[order]
             words2d = (
-                np.ascontiguousarray(payloads)
+                np.ascontiguousarray(np.concatenate(payload_blocks))
                 .view(np.uint64)
                 .reshape(len(keys), wpc // 2)
             )
@@ -1223,13 +1353,16 @@ class Fragment:
             lens = np.asarray([len(self._sparse[r]) for r in sp_rows])
             rows_rep = np.repeat(np.asarray(sp_rows, dtype=np.int64), lens)
             offs_all = np.concatenate([self._sparse[r] for r in sp_rows])
-            keys_all = rows_rep * bp.CONTAINERS_PER_SLICE + offs_all // cbits
-            vals_all = (offs_all % cbits).astype(np.uint32)
+            keys_all = rows_rep * cps + offs_all // cbits
             uniq_keys, starts = np.unique(keys_all, return_index=True)
-            for j, k in enumerate(uniq_keys):
-                hi = starts[j + 1] if j + 1 < len(starts) else len(vals_all)
-                arrays[int(k)] = vals_all[starts[j] : hi]
-        return keys, words2d, arrays
+            akeys.append(uniq_keys.astype(np.uint64))
+            acounts.append(np.diff(np.append(starts, len(keys_all))))
+            avalues.append((offs_all % cbits).astype(np.uint32))
+        if not akeys:
+            return keys, words2d, ()
+        return keys, words2d, roaring.merge_packed_arrays(
+            np.concatenate(akeys), np.concatenate(acounts), np.concatenate(avalues)
+        )
 
     def _row_words_host(self, row_id: int) -> np.ndarray | None:
         """One row's words on host (copy), whichever tier holds it.
@@ -1239,7 +1372,7 @@ class Fragment:
         with self._mu:
             slot = self._slot_of.get(row_id)
             if slot is not None:
-                return self._plane[slot].copy()
+                return bp.widen_row(self._plane[slot])
             offs = self._sparse.get(row_id)
             if offs is None:
                 return None
@@ -1272,6 +1405,8 @@ class Fragment:
             offset = self.pos(row_id, column_id) % SLICE_WIDTH
             slot = self._slot_of.get(row_id)
             if slot is not None:
+                if offset >= self._plane.shape[1] * bp.WORD_BITS:
+                    return False
                 return bp.np_contains(self._plane, slot * SLICE_WIDTH + offset)
             offs = self._sparse.get(row_id)
             if offs is None:
@@ -1458,6 +1593,11 @@ class Fragment:
         plane's device mirror."""
         return int(self._plane.shape[0])
 
+    def plane_words(self) -> int:
+        """Words of a plane row (``bp.row_words`` of the highest column
+        the fragment holds): the word dimension of the same programs."""
+        return int(self._plane.shape[1])
+
     def mirror_is(self, plane) -> bool:
         """Whether ``plane`` is this fragment's CURRENT device mirror,
         the array the residency pool accounts for under the fragment's
@@ -1552,8 +1692,8 @@ class Fragment:
                     # directly keeps an ingest storm on OTHER rows from
                     # forcing a whole-plane sync onto every read.
                     device_mod.pool().touch(self._pool_key)
-                    return dev[slot]
-                return self.device_plane()[slot]
+                    return self._wide_device_row(dev[slot])
+                return self._wide_device_row(self.device_plane()[slot])
             ent = self._sparse_dev_entry_locked(row_id)
             if ent is None:
                 return None
@@ -1562,6 +1702,15 @@ class Fragment:
             # resident cache keeps only the compressed payload, so HBM
             # never holds a decompressed staging copy.
             return bp.expand_payload(fmt, dev)
+
+    @staticmethod
+    def _wide_device_row(row):
+        """A row of the mirror as the full-width leaf a query plan
+        takes: a narrow plane's row is padded with zeros on read."""
+        import jax.numpy as jnp
+
+        pad = bp.WORDS_PER_SLICE - int(row.shape[0])
+        return jnp.pad(row, (0, pad)) if pad else row
 
     def _sparse_dev_entry_locked(self, row_id: int):
         """The paged compressed-container entry ``(fmt, device_payload,
@@ -1616,9 +1765,10 @@ class Fragment:
         with self._mu:
             slot = self._slot_of.get(row_id)
             if slot is not None:
+                row = self._plane[slot]
                 return (
                     bp.FMT_DENSE,
-                    self._plane[slot],
+                    row if len(row) == bp.WORDS_PER_SLICE else bp.widen_row(row),
                     ROW_NBYTES,
                     self._count_of.get(row_id, 0),
                 )
@@ -1683,6 +1833,10 @@ class Fragment:
             grew = row_id > self._max_row_id
             slot = self._ensure_slot(row_id)
             if slot is not None:
+                self._ensure_width_locked(offset)
+                # a re-lay may have moved the row to the sparse tier
+                slot = self._slot_of.get(row_id)
+            if slot is not None:
                 changed = bp.np_set_bit(self._plane, slot * SLICE_WIDTH + offset)
                 if changed:
                     self._queue_device_update(slot, offset, 1)
@@ -1709,7 +1863,11 @@ class Fragment:
             offset = pos % SLICE_WIDTH
             slot = self._slot_of.get(row_id)
             if slot is not None:
-                changed = bp.np_clear_bit(self._plane, slot * SLICE_WIDTH + offset)
+                changed = offset < self._plane.shape[
+                    1
+                ] * bp.WORD_BITS and bp.np_clear_bit(
+                    self._plane, slot * SLICE_WIDTH + offset
+                )
                 if changed:
                     self._queue_device_update(slot, offset, 0)
             elif row_id in self._sparse:
@@ -1875,6 +2033,25 @@ class Fragment:
         with self._mu:
             self._flush_ops_locked()
 
+    def _slot_table_locked(self, uniq: np.ndarray):
+        """``(slots, absent)`` of the sorted distinct row ids ``uniq``:
+        each row's dense-tier slot, -1 for a sparse-tier row and for a
+        row the fragment does not hold, and which those last are — from
+        the tiers' sorted key arrays, no dict probe a row.  Callers
+        hold ``_mu``."""
+        slot_ids, slot_vals, sparse_ids = self._tier_key_arrays_locked()
+        slots = np.full(len(uniq), -1, dtype=np.int64)
+        absent = np.ones(len(uniq), dtype=bool)
+        if len(slot_ids) and len(uniq):
+            at = np.minimum(np.searchsorted(slot_ids, uniq), len(slot_ids) - 1)
+            hit = slot_ids[at] == uniq
+            slots[hit] = slot_vals[at[hit]]
+            absent &= ~hit
+        if len(sparse_ids) and len(uniq):
+            at = np.minimum(np.searchsorted(sparse_ids, uniq), len(sparse_ids) - 1)
+            absent &= sparse_ids[at] != uniq
+        return slots, absent
+
     def import_bulk(
         self,
         row_ids: Sequence[int],
@@ -1908,24 +2085,32 @@ class Fragment:
                 raise FragmentError("column out of bounds for slice")
             offs = cols % SLICE_WIDTH
             uniq = np.unique(rows)
-            # Pre-size the dense plane once for every row this import
-            # can add (one allocation, not O(log n) doubling copies).
-            n_new = sum(
-                1 for r in uniq
-                if int(r) not in self._slot_of and int(r) not in self._sparse
-            )
-            self._reserve_dense(
-                min(len(self._slot_of) + n_new, self.dense_row_budget)
-            )
-            slot_of = {int(r): self._ensure_slot(int(r)) for r in uniq}
-
-            # Per-row slot resolution through a per-UNIQUE-row table:
-            # O(unique) Python work + one vectorized gather, instead of
-            # a per-bit comprehension.
-            slot_table = np.asarray(
-                [-1 if slot_of[int(r)] is None else slot_of[int(r)] for r in uniq],
-                dtype=np.int64,
-            )
+            # Bit positions are u64 in the op-log (pos = row*2^20 +
+            # offset): reject before mutating state (_ensure_slot).
+            if len(uniq) and not 0 <= int(uniq[0]) <= int(uniq[-1]) < MAX_ROW_ID:
+                raise FragmentError(f"row id out of range: {int(uniq[-1])}")
+            if len(offs):
+                self._ensure_width_locked(int(offs.max()))
+            # Every row's slot through tables over the UNIQUE rows, and
+            # no Python step a row for a row the dense tier takes: a
+            # unit of a tall frame brings hundreds of thousands of new
+            # ones.  (-1: the sparse tier.)
+            slot_table, new = self._slot_table_locked(uniq)
+            fresh = uniq[new]
+            if len(fresh):
+                room = max(self._dense_cap() - len(self._slot_of), 0)
+                dense, sparse = fresh[:room], fresh[room:]
+                if len(dense):
+                    base = len(self._slot_of)
+                    # one allocation, not O(log n) doubling copies
+                    self._reserve_dense(base + len(dense))
+                    at = np.arange(base, base + len(dense))
+                    self._slot_of.update(zip(dense.tolist(), at.tolist()))
+                    slot_table[np.flatnonzero(new)[:room]] = at
+                for r in sparse.tolist():
+                    self._sparse[r] = np.empty(0, dtype=np.uint32)
+                self._max_row_id = max(self._max_row_id, int(fresh[-1]))
+                self._tier_arrays = None  # the tiers hold new rows
             slots_all = slot_table[np.searchsorted(uniq, rows)]
             dense_mask = slots_all >= 0
             imp_set_slots = imp_set_offs = None
@@ -1964,25 +2149,15 @@ class Fragment:
                 if ((c_cols < min_col) | (c_cols >= min_col + SLICE_WIDTH)).any():
                     raise FragmentError("column out of bounds for slice")
                 c_offs = c_cols % SLICE_WIDTH
-                for r in np.unique(c_rows):
-                    r = int(r)
-                    if r in slot_of:
-                        continue
-                    slot = self._slot_of.get(r)
-                    if slot is None and r not in self._sparse:
-                        continue  # clears never create rows
-                    slot_of[r] = slot
-                c_keep = np.asarray(
-                    [int(r) in slot_of for r in c_rows], dtype=bool
+                c_uniq = np.unique(c_rows)
+                c_table, absent = self._slot_table_locked(c_uniq)
+                c_slots = c_table[np.searchsorted(c_uniq, c_rows)]
+                # clears never create rows, and nothing is set beyond
+                # the columns the plane covers
+                c_keep = ~absent[np.searchsorted(c_uniq, c_rows)] & (
+                    (c_slots < 0) | (c_offs < self._plane.shape[1] * bp.WORD_BITS)
                 )
-                c_rows, c_offs = c_rows[c_keep], c_offs[c_keep]
-                c_slots = np.asarray(
-                    [
-                        -1 if slot_of[int(r)] is None else slot_of[int(r)]
-                        for r in c_rows
-                    ],
-                    dtype=np.int64,
-                )
+                c_rows, c_offs, c_slots = c_rows[c_keep], c_offs[c_keep], c_slots[c_keep]
                 dm = c_slots >= 0
                 if dm.any():
                     imp_clr_slots = c_slots[dm]
@@ -1995,7 +2170,8 @@ class Fragment:
                         self._sparse[int(r)] = np.setdiff1d(
                             self._sparse[int(r)], s_offs[s_rows == r]
                         ).astype(np.uint32)
-                uniq = np.union1d(uniq, np.unique(c_rows)).astype(np.int64)
+                uniq = np.union1d(uniq, c_uniq[~absent]).astype(np.int64)
+                slot_table = self._slot_table_locked(uniq)[0]
 
             self._version += 1
             _bump_write_epoch()
@@ -2006,22 +2182,26 @@ class Fragment:
             self._payload_cache.clear()
             self._sync_sparse_pool_locked()
             self._row_cache.clear()
-            self._dirty_blocks.update(int(r) // HASH_BLOCK_SIZE for r in uniq)
-            d_items = [(r, s) for r, s in slot_of.items() if s is not None]
-            if d_items:
-                cnts = bp.np_row_counts(
-                    self._plane[np.asarray([s for _, s in d_items])]
+            self._dirty_blocks.update(np.unique(uniq // HASH_BLOCK_SIZE).tolist())
+            # Recount the touched rows: the dense ones from the plane, a
+            # sweep of bounded size at a time.
+            in_plane = slot_table >= 0
+            d_ids, d_slots = uniq[in_plane], slot_table[in_plane]
+            if len(d_ids):
+                step = max(256, (1 << 22) // self._plane.shape[1])
+                cnts = np.concatenate(
+                    [
+                        bp.np_row_counts(self._plane[d_slots[b : b + step]])
+                        for b in range(0, len(d_slots), step)
+                    ]
                 )
-            for i, (r, _) in enumerate(d_items):
-                self._count_of[r] = int(cnts[i])
-                self.cache.bulk_add(r, int(cnts[i]))
-            for r, s in slot_of.items():
-                if s is None:
-                    n = len(self._sparse[r])
-                    self._count_of[r] = n
-                    self.cache.bulk_add(r, n)
-            for r in uniq:
-                self._maybe_promote(int(r))
+                self._count_of.update(zip(d_ids.tolist(), cnts.tolist()))
+                self.cache.bulk_add_many(d_ids, cnts)
+            for r in uniq[~in_plane].tolist():
+                n = len(self._sparse[r])
+                self._count_of[r] = n
+                self.cache.bulk_add(r, n)
+                self._maybe_promote(r)
             self.cache.invalidate()
             self.cache.recalculate()
             self.stats.count("ImportBit", len(row_ids))  # ref: fragment.go:969
@@ -2211,7 +2391,7 @@ class Fragment:
             # prepared slot indices.
             device_mod.pool().touch(self._pool_key)
             st.dev_counts = bp.top_counts(
-                sub_ref.plane[sub_ref.slots], src_words
+                sub_ref.plane[sub_ref.slots], src_words[: sub_ref.shape[1]]
             )
         return st
 
@@ -2345,7 +2525,7 @@ class Fragment:
         return SubRef(
             plane=self._mirror_locked(),
             slots=slots,
-            shape=(len(slots), bp.WORDS_PER_SLICE),
+            shape=(len(slots), int(self._plane.shape[1])),
             plane_rows=int(self._plane.shape[0]),
             device=bp.home_device(self.slice),
         )
@@ -2379,6 +2559,96 @@ class Fragment:
                 ids, cnts, *split, version=self._version, ranked=ranked
             )
             return lay
+
+    def rows_layout(self) -> RowsLayout | None:
+        """The ranked candidates by plane slot (RowsLayout), kept like
+        ``top_layout``'s while no write and no re-sort of the rank cache
+        has happened; None where a ranked row lives in the sparse tier
+        (no plane holds it: the general walk scores it on the host).
+        The counts go to the device once a layout, under a pool entry
+        of their own beside the mirror's."""
+        import jax
+
+        with self._mu:
+            ranked = self._top_candidates_arrays(None)
+            lay = self._rows_layout
+            if lay is not None and lay.version == self._version and lay.ranked is ranked:
+                device_mod.pool().touch(self._rows_pool_key)
+                return lay
+            ids, cnts = ranked
+            order = np.argsort(ids, kind="stable")
+            slots, _absent = self._slot_table_locked(ids[order])
+            if (slots < 0).any():
+                return None
+            by_slot = np.full(self._plane.shape[0], -1, dtype=np.int64)
+            by_slot[slots] = ids[order]
+            counts = np.zeros(self._plane.shape[0], dtype=np.int32)
+            counts[slots] = cnts[order]
+            dev = bp.home_device(self.slice)
+            device_mod.pool().admit(
+                self._rows_pool_key,
+                {dev: int(counts.nbytes)},
+                self._evict_rows_layout,
+                category="cache",
+                info=self._pool_info(),
+            )
+            lay = self._rows_layout = RowsLayout(
+                by_slot,
+                jax.device_put(counts, dev),
+                np.sort(cnts),
+                self._version,
+                ranked,
+            )
+            return lay
+
+    def _evict_rows_layout(self) -> bool:
+        """Residency-pool eviction hook for the layout's device counts
+        (rebuilt on the next ``rows_layout``)."""
+        if not self._mu.acquire(blocking=False):
+            return False
+        try:
+            self._rows_layout = None
+            return True
+        finally:
+            self._mu.release()
+
+    def rows_mirror(self, lay: RowsLayout, src_row: int):
+        """``(mirror snapshot, src slot)`` for a walk of ``lay``'s rows
+        against row ``src_row`` of this very plane, both read under one
+        hold of the lock; None where the layout no longer holds (a
+        write since) or the src is not a dense-tier row here."""
+        with self._mu:
+            slot = self._slot_of.get(src_row)
+            if slot is None or lay.version != self._version:
+                return None
+            return self._mirror_locked(), slot
+
+    def score_rows_host(self, lay: RowsLayout, src_slot: int, src_count: int,
+                        tanimoto: int, min_threshold: int):
+        """``bp.score_rows`` on the host's plane: the device-health
+        gate's fallback, same rules, same ``(slots, shared bits)``."""
+        with self._mu:
+            plane = self._plane
+            src = plane[src_slot].copy()
+        step = max(256, (1 << 22) // plane.shape[1])
+        c = np.concatenate(
+            [
+                bp.np_row_counts(plane[b : b + step] & src)
+                for b in range(0, plane.shape[0], step)
+            ]
+        )
+        cnts = np.asarray(lay.cnts).astype(np.int64)
+        s, t = src_count, tanimoto
+        if t > 0:
+            keep = (
+                (100 * cnts > s * t)
+                & (cnts * t < 100 * s)
+                & (100 * c > t * (cnts + s - c))
+            )
+        else:
+            keep = (cnts >= min_threshold) & (c >= min_threshold)
+        at = np.flatnonzero(keep & (cnts > 0) & (c > 0))
+        return at, c[at]
 
     def top_prepare_own_parts(
         self, lay: TopLayout, min_threshold: int, src_row: int | None

@@ -373,6 +373,11 @@ class Executor:
         # the degraded-mode data plane a quarantined device falls back
         # to, byte-identical by construction (exec/hosteval.py).
         self.hosteval = hosteval_mod.HostEvaluator(self)
+        # Candidate rows a TopN scored on the host (the sparse tier's
+        # probes, the host fallback): published at 0, so that a reader
+        # tells "none" from "not counted".
+        if getattr(holder, "stats", None) is not None:
+            holder.stats.count("topn.host_scored_rows", 0)
         self._pool = _DaemonPool(
             max_workers=16, stats=getattr(holder, "stats", None)
         )
@@ -1157,6 +1162,7 @@ class Executor:
                         plan.slice_bucket(n),
                         len(row_ids),
                         len(leaves),
+                        live[0].plane_words(),
                     )
                 )
         shapes -= self._gather_warmed
@@ -2451,7 +2457,8 @@ class Executor:
     def _agg_view_layout(self, view, row_ids: tuple, slices_key: tuple, frags):
         """Where ``row_ids`` lie in the planes of ``view``'s fragments
         over a slice set: ``{"slots": int32[n, k] (-1: not held),
-        "rows": each fragment's plane rows (0: none), "versions": the
+        "rows": each fragment's plane rows (0: none), "words": the words
+        of a row of that plane (0: none), "versions": the
         fragment versions the slots hold for, "sparse": a row lives in
         the sparse tier}``.  Kept across answers and validated as the
         batch cache validates (the write epoch, then the version
@@ -2463,6 +2470,7 @@ class Executor:
                 "versions": [0] * n,
                 "slots": np.full((n, len(row_ids)), -1, dtype=np.int32),
                 "rows": np.zeros(n, dtype=np.int32),
+                "words": np.zeros(n, dtype=np.int32),
                 "held": np.zeros(n, dtype=bool),
                 "sparse": False,
             }
@@ -2482,6 +2490,7 @@ class Executor:
                 return ent
         slots = np.full((n, len(row_ids)), -1, dtype=np.int32)
         rows = np.zeros(n, dtype=np.int32)
+        words = np.zeros(n, dtype=np.int32)
         versions = [0] * n
         serials: list = [None] * n
         sparse = False
@@ -2494,13 +2503,14 @@ class Executor:
                 break
             slots[i], versions[i] = got
             serials[i] = (f._serial, versions[i])
-            rows[i] = f.plane_rows()
+            rows[i], words[i] = f.plane_rows(), f.plane_words()
         ent = {
             "epoch": epoch,
             "serials": tuple(serials),
             "versions": versions,
             "slots": slots,
             "rows": rows,
+            "words": words,
             "held": slots.max(axis=1, initial=-1) >= 0,
             "sparse": sparse,
         }
@@ -2633,14 +2643,16 @@ class Executor:
         # fragment (an upload or a scatter on the way) with its slots
         tables = [lay["slots"] for lay in layouts]
         rows = [lay["rows"] for lay in layouts]
+        words = [lay["words"] for lay in layouts]
         for u, i in stale:
             ref = reads[u][1][i].gather_slots(reads[u][2])
             if ref is None:
                 return "sparse_tier"
             if tables[u] is layouts[u]["slots"]:
                 tables[u], rows[u] = tables[u].copy(), rows[u].copy()
+                words[u] = words[u].copy()
             planes[u][i], tables[u][i] = ref
-            rows[u][i] = 0 if ref[0] is None else int(ref[0].shape[0])
+            rows[u][i], words[u][i] = (0, 0) if ref[0] is None else ref[0].shape
         device_mod.pool().touch_many(
             [
                 frags[i]._pool_key
@@ -2668,7 +2680,11 @@ class Executor:
             "groups": self._agg_member_groups(
                 kept,
                 [[mine[i] for i in at] for mine in planes],
-                [r[at] for r in rows],
+                # a plane's shape as one number: rows and the words of a row
+                [
+                    r[at].astype(np.int64) << 16 | w[at]
+                    for r, w in zip(rows, words)
+                ],
                 table,
             ),
             "kept": kept,
@@ -2826,7 +2842,10 @@ class Executor:
         # locally (single node — the common and benchmarked shape), both
         # phases compute from ONE union scoring pass with ONE device
         # fetch; results are identical to the two-phase protocol below.
-        if not ids_arg and not opt.remote and len(slices) > 1:
+        # One slice too: an index whose rows are many and whose columns
+        # are one slice's (a molecule a row) is prepared, cached and
+        # scored as the many-slice ones are.
+        if not ids_arg and not opt.remote and slices:
             if self._all_slices_local(index, slices):
                 return self._execute_topn_folded(index, c, slices, opt)
 
@@ -2967,6 +2986,16 @@ class Executor:
         return self._launch_guarded(
             paths, mode, device_fn, retry_fn=device_fn, host_fn=host_fn
         )
+
+    def _count_host_scored(self, states) -> None:
+        """Count the candidate rows a build scored on the host: the
+        sparse tier's, probed a row at a time under the fragment's lock
+        (``Fragment._top_score_parts``)."""
+        n = sum(
+            len(st.sparse_pos) for st in states if st.sparse_pos is not None
+        )
+        if n:
+            self.holder.stats.count("topn.host_scored_rows", n)
 
     def _shared_fetch(self, arrays, sp):
         """Fetch device arrays to the host, batching the BLOCKING
@@ -3254,6 +3283,9 @@ class Executor:
             return {"empty": True}
         topt = self._topn_options(c)
         plain = topt.keeps_every_counted
+        rows = self._topn_rows_entry(index, c, view, frags, topt)
+        if rows is not None:
+            return rows
 
         # Pass 1 (host-only): per-fragment candidate (ids, cached counts)
         # arrays, WITHOUT evaluating the src tree yet — the union guard
@@ -3366,6 +3398,7 @@ class Executor:
         score = topn_stack.score_stack(
             [(st, ref, srcw, slot, frag) for frag, _, _, st, ref, srcw, slot in parts]
         )
+        self._count_host_scored(p[3] for p in parts)
         pins = tuple(p[0]._pool_key for p in parts)
         # The mirrors the parts read stay recent in the residency pool:
         # one hold of its lock a build, not one a fragment.
@@ -3386,6 +3419,113 @@ class Executor:
             "stack": topn_stack.stack_parts(parts, union, score),
             "pins": pins,
         }
+
+    def _topn_rows_entry(self, index: str, c: Call, view, frags, topt) -> dict | None:
+        """The prep entry of a TopN(src) over a view that is ONE
+        fragment here, whose src is a dense-tier row of that very plane:
+        ``{"rows": (fragment, RowsLayout, mirror, src slot, src count,
+        tanimoto, min threshold), ...}``.  Every ranked row is a row of
+        the one plane, so the scorer walks the plane (``bp.score_rows``)
+        and applies the count window and the similarity rule where the
+        counts are; with one fragment both protocol phases read the same
+        scores, and nothing here depends on how many rows there are: no
+        candidate list is copied, no union is sorted, no row is gathered
+        by slot.  None where this does not apply and the general build
+        does: more fragments, no src or another tree than a Bitmap of
+        the frame itself, ``ids=``, an attr filter, a ranked row in the
+        sparse tier (it is scored on the host)."""
+        if len(frags) != 1 or topt.row_ids or topt.filter_field:
+            return None
+        leaf = self._topn_src_leaf(index, c) if len(c.children) == 1 else None
+        if leaf is None or leaf[:2] != (view.frame, view.name):
+            return None
+        frag = frags[0]
+        lay = frag.rows_layout()
+        got = frag.rows_mirror(lay, leaf[2]) if lay is not None else None
+        if got is None:
+            return None
+        mirror, src_slot = got
+        # |src| is the row's own cardinality: what the general build's
+        # host copy of the row would count
+        s, t = frag.row_meta(leaf[2])[0], topt.tanimoto_threshold
+        if t > 0:
+            # cnt > s*t/100 and cnt < s*100/t, as whole numbers
+            lo, hi = s * t // 100 + 1, -(-100 * s // t)
+        else:
+            lo, hi = max(topt.min_threshold, 1), np.iinfo(np.int64).max
+        kept = max(
+            int(
+                np.searchsorted(lay.window, hi, "left")
+                - np.searchsorted(lay.window, lo, "left")
+            ),
+            0,
+        )
+        return {
+            "rows": (frag, lay, mirror, src_slot, s, t, topt.min_threshold),
+            "parts": (),
+            "union": kept,
+            "build": "rows",
+            "pins": (frag._pool_key, frag._rows_pool_key),
+        }
+
+    def _score_topn_rows(self, ent: dict):
+        """Score a rows entry (``_topn_rows_entry``): ONE launch of the
+        walked scorer over the fragment's mirror, dispatched without
+        waiting, and one fetch through the coalescer's lane of what it
+        kept: ``(slots, shared bits)`` of the kept rows.  The
+        ``topn.dispatch`` / ``topn.fetch`` spans and the device-health
+        gate as ``_score_topn_parts``; the host fallback walks the
+        authoritative plane by the same rules."""
+        frag, lay, mirror, src_slot, s, t, m = ent["rows"]
+        n_rows, words = (int(d) for d in mirror.shape)
+        n_bytes = perf_mod.plane_bytes(n_rows, words)
+
+        def host_fn():
+            self.holder.stats.count("topn.host_scored_rows", n_rows)
+            return self.hosteval.score_topn_rows(frag, lay, src_slot, s, t, m)
+
+        paths = self.device_health.device_paths()
+        mode = self.device_health.acquire(paths)
+        if mode == health_mod.MODE_DENY:
+            return host_fn()
+
+        def device_fn():
+            t0 = time.monotonic()
+            with self.tracer.span(
+                "topn.dispatch", groups=1, rows=n_rows, bytes=n_bytes
+            ) as sp:
+                self._fault_check_launch("topn")
+                hits, at, shared, every = bp.score_rows(
+                    mirror, lay.cnts, src_slot, s, t, m,
+                    first_call=plan.note_scorer_first_call,
+                )
+                sp.annotate(launches=1)
+            t_disp = time.monotonic()
+            with self.tracer.span("topn.fetch", arrays=3) as sp:
+                hits, at, shared = self._shared_fetch([hits, at, shared], sp)
+                hits = int(hits)
+                if hits > len(at):
+                    # more rows kept than a launch hands back compacted
+                    every = np.asarray(self._shared_fetch([every], sp)[0])
+                    at = np.flatnonzero(every)
+                    shared = every[at]
+                else:
+                    at, shared = np.asarray(at)[:hits], np.asarray(shared)[:hits]
+            if perf_mod.enabled():
+                perf_mod.record_launch(
+                    "topn",
+                    reduce="topn",
+                    rows=n_rows,
+                    n_bytes=n_bytes,
+                    dispatch_ms=(t_disp - t0) * 1e3,
+                    total_ms=(time.monotonic() - t0) * 1e3,
+                    trace_id=perf_mod.current_trace_id(),
+                )
+            return at, shared
+
+        return self._launch_guarded(
+            paths, mode, device_fn, retry_fn=device_fn, host_fn=host_fn
+        )
 
     def _execute_topn_folded(
         self, index: str, c: Call, slices: list[int], opt: ExecOptions
@@ -3420,6 +3560,14 @@ class Executor:
             )
             if how == "built" and "build" in ent:
                 sp.annotate(build=ent["build"])
+            if "rows" in ent:
+                # ``candidates``: the rows the text's count window keeps
+                # (upstream's filter on cached counts: a number of the
+                # semantics, whatever scores them); ``rows``: the rows
+                # of the plane the scorer walks
+                sp.annotate(
+                    candidates=ent["union"], rows=int(ent["rows"][2].shape[0])
+                )
         if ent.get("empty"):
             return []
         if ent.get("two_phase"):
@@ -3432,7 +3580,17 @@ class Executor:
         # exactly as long as the entry does: entry validation already
         # proved the scored fragments unchanged since build.  The
         # vector is the only state an answer adds to the entry's arrays.
-        with self.tracer.span("topn.score", parts=len(ent["parts"])) as sp:
+        with self.tracer.span(
+            "topn.score", parts=1 if "rows" in ent else len(ent["parts"])
+        ) as sp:
+            if "rows" in ent:
+                # the layout the scorer reads in place: the plane at the
+                # width the fragment keeps
+                words = int(ent["rows"][2].shape[1])
+                sp.annotate(
+                    layout="narrow" if words < bp.WORDS_PER_SLICE else "wide",
+                    stride_words=words,
+                )
             scores = None
             leader = False
             ev = None
@@ -3460,7 +3618,11 @@ class Executor:
                         self._topn_pool_key((index, str(c), tuple(slices))),
                         *ent["pins"],
                     ):
-                        scores = self._score_topn_parts(ent["score"])
+                        scores = (
+                            self._score_topn_rows(ent)
+                            if "rows" in ent
+                            else self._score_topn_parts(ent["score"])
+                        )
                     with self._batch_mu:
                         ent["scores"] = scores
                     sp.annotate(score_cache="computed")
@@ -3479,6 +3641,19 @@ class Executor:
         # call a part: ``way`` says so.  The ``topn.select`` span is
         # the host-winner-selection leg of the per-stage TopN(src)
         # breakdown (with topn.dispatch/topn.fetch).
+        if "rows" in ent:
+            # one fragment: its winners are the answer, and their
+            # counts are exact as scored
+            with self.tracer.span("topn.select", parts=1, way="rows"):
+                at, sums = scores
+                ids = ent["rows"][1].ids[at]
+                order = np.lexsort((ids, -sums.astype(np.int64)))
+                if n:
+                    order = order[:n]
+                return [
+                    Pair(i, cnt)
+                    for i, cnt in zip(ids[order].tolist(), sums[order].tolist())
+                ]
         with self.tracer.span(
             "topn.select", parts=len(ent["parts"]), way="stacked"
         ):
@@ -3514,6 +3689,7 @@ class Executor:
                 for s in local_slices
             ]
             states = [p for p in prepped if p is not None]
+            self._count_host_scored(part[0] for _, part in states)
             entries = [
                 (*self._attach_dev_src(index, c, frag, part), frag)
                 for frag, part in states

@@ -156,6 +156,28 @@ class HostEvaluator:
     # TopN scoring
     # ------------------------------------------------------------------
 
+    def score_topn_rows(self, frag, lay, src_slot, src_count, tanimoto, min_threshold):
+        """``bp.score_rows`` on the fragment's authoritative host plane:
+        ``(slots, shared bits)`` of the rows the text keeps, by the same
+        rules (``Fragment.score_rows_host``)."""
+        with self.ex.tracer.span("hosteval", kind="topn", parts=1):
+            self._count("topn")
+            t0 = time.monotonic()
+            out = frag.score_rows_host(
+                lay, src_slot, src_count, tanimoto, min_threshold
+            )
+            if perf_mod.enabled():
+                n_rows = frag.plane_rows()
+                perf_mod.record_launch(
+                    "hosteval",
+                    reduce="topn",
+                    rows=n_rows,
+                    n_bytes=perf_mod.plane_bytes(n_rows, frag.plane_words()),
+                    total_ms=(time.monotonic() - t0) * 1e3,
+                    trace_id=perf_mod.current_trace_id(),
+                )
+            return out
+
     def score_topn_parts(self, parts) -> None:
         """Fill each TopState's dense count vector HOST-side.
 

@@ -844,6 +844,9 @@ def scatter_apply(plane, slots, words, or_m, andnot_m):
     # The jit cache also keys on the plane's (pow2-classed) row count;
     # track its highwater so program_cache_bounds stays an invariant.
     _note_bucket("plan.scatter.rows", int(plane.shape[0]))
+    from pilosa_tpu.ops import bitplane as bp
+
+    bp._note_shape(plane_words=int(plane.shape[1]))
     return _compiled_scatter()(slots, words, or_m, andnot_m, plane)
 
 
@@ -1031,6 +1034,7 @@ def program_cache_stats() -> dict[str, int]:
         "bitplane.gatherPlanes": sum(
             _jit_cache_size(fn) for fn in bp.GATHER_PROGRAMS
         ),
+        "bitplane.scoreRows": _jit_cache_size(bp._score_rows_xla),
         "bitplane.aggregatePlanes": _jit_cache_size(bp._aggregate_planes_xla),
         "bitplane.fusedCount": _jit_cache_size(bp._fused_count_xla),
         "bitplane.topCounts": _jit_cache_size(bp._top_counts_xla),
@@ -1095,6 +1099,7 @@ def program_cache_bounds() -> dict[str, int]:
         "plan.scatter": (
             _compiled_scatter.cache_info().currsize
             * n_dev
+            * bp.word_classes()
             * bp.bucket_classes(
                 max(_BUCKET_HIGHWATER.get("plan.scatter", _scatter_floor()),
                     _scatter_floor()),
@@ -1108,12 +1113,23 @@ def program_cache_bounds() -> dict[str, int]:
         # log2(bp.SCORE_GROUP) + 1 whatever the slice count: larger
         # groups relaunch the SCORE_GROUP program) x plane-row classes
         # x candidate-slot classes — on each device
+        # ... x the planes' row-width classes (bp.row_words: a plane
+        # is as wide as its columns ask for), as every family below
+        # whose operands are plane mirrors
         "bitplane.scorePlanes": (
             2
             * n_dev
+            * bp.word_classes()
             * bp.bucket_classes(max(hw.get("score_frags", 1), 1))
             * bp.bucket_classes(max(hw.get("score_rows", rb), rb), rb)
             * bp.bucket_classes(max(hw.get("score_slots", rb), rb), rb)
+        ),
+        # the walked scorer: plane-row classes x row-width classes, on
+        # each device; the text's numbers are operands
+        "bitplane.scoreRows": (
+            n_dev
+            * bp.word_classes()
+            * bp.bucket_classes(max(hw.get("walk_rows", rb), rb), rb)
         ),
         # the gather: member classes (as the scorer's) x plane-row
         # classes x leaves of a run; its in-place writes: one a (block
@@ -1121,6 +1137,7 @@ def program_cache_bounds() -> dict[str, int]:
         # constant column's one a block — all on each device
         "bitplane.gatherPlanes": (
             n_dev
+            * bp.word_classes()
             * (
                 bp.bucket_classes(max(hw.get("gather_frags", 1), 1))
                 * bp.bucket_classes(max(hw.get("gather_rows", rb), rb), rb)
@@ -1144,10 +1161,13 @@ def program_cache_bounds() -> dict[str, int]:
             len(bp._AGG_SEEN)
             * n_dev
             * bp.bucket_classes(max(hw.get("agg_frags", 1), 1))
-            * bp.bucket_classes(max(hw.get("agg_rows", rb), rb), rb)
+            * (
+                bp.word_classes()
+                * bp.bucket_classes(max(hw.get("agg_rows", rb), rb), rb)
+            )
             ** max(hw.get("agg_units", 1), 1)
         ),
-        "bitplane.topCounts": n_dev * bp.bucket_classes(
+        "bitplane.topCounts": n_dev * bp.word_classes() * bp.bucket_classes(
             max(hw.get("top_rows", rb), rb), rb
         ),
         # (tree shape x container-format tuple) wrappers x slice-bucket
@@ -1214,6 +1234,7 @@ def clear_program_caches() -> None:
         bp._aggregate_planes_xla,
         bp._score_planes_self_src,
         bp._score_planes_host_src,
+        bp._score_rows_xla,
         bp._fused_count_xla,
         bp._top_counts_xla,
         bp._expand_sparse_xla,

@@ -34,7 +34,6 @@ from dataclasses import dataclass
 import numpy as np
 
 from pilosa_tpu.obs import perf as perf_mod
-from pilosa_tpu.ops import bitplane as bp
 
 NO_SCORES = np.empty(0, np.int32)  # of an entry with nothing to score
 _KEY_PAD = np.iinfo(np.int64).max
@@ -99,7 +98,7 @@ def score_stack(entries) -> ScoreStack:
         # streams its whole plane snapshot (a launch's pad repeats are
         # bucketing, not counted).
         rows += n * int(plane_rows)
-        n_bytes += n * perf_mod.plane_bytes(int(plane_rows), bp.WORDS_PER_SLICE)
+        n_bytes += n * perf_mod.plane_bytes(int(plane_rows), int(shape[1]))
         groups.append(
             ScoreGroup(
                 planes=tuple(m[1].plane for m in members),
@@ -115,7 +114,13 @@ def score_stack(entries) -> ScoreStack:
                     if host_src
                     else np.asarray([m[3] for m in members], dtype=np.int32)
                 ),
-                srcs=tuple(m[2] for m in members) if host_src else None,
+                # a host-snapshot src is a full-width row; the scorer
+                # reads as many words of it as the members' planes have
+                srcs=(
+                    tuple(m[2][: int(shape[1])] for m in members)
+                    if host_src
+                    else None
+                ),
             )
         )
         live.extend(members)

@@ -184,16 +184,16 @@ _TOPN_SHAPES = ((1, bp.ROW_BLOCK), (1, 2 * bp.ROW_BLOCK))
 _TOPN_SHAPES_MAX = 8
 
 
-def _view_shapes(holder) -> dict[tuple[int, int, int], int]:
-    """``(members, plane rows, block rows)`` of the programs that read
+def _view_shapes(holder) -> dict[tuple[int, int, int, int], int]:
+    """``(members, plane rows, block rows, row words)`` of the programs that read
     the holder's ranked views a home device at a time, each with the
     fragment count of the largest view that has it: the n fragments of
     a view that share a device are read ``bp.score_group_bucket(n)``
     members a launch whatever n is, at the pow2 row class of their
-    planes, and a leaf batch over them is a block of
-    ``plan.slice_bucket(n)`` rows."""
+    planes and at the width they keep (``bp.row_words``), and a leaf
+    batch over them is a block of ``plan.slice_bucket(n)`` rows."""
     n_dev = len(bp.participating_devices())
-    weight: dict[tuple[int, int, int], int] = {}
+    weight: dict[tuple[int, int, int, int], int] = {}
     for idx in holder.indexes().values():
         for frame in idx.frames().values():
             for name, view in frame.views().items():
@@ -205,8 +205,8 @@ def _view_shapes(holder) -> dict[tuple[int, int, int], int]:
                 if not frags:
                     continue
                 n = -(-len(frags) // n_dev)
-                for rows in {f.plane_rows() for f in frags}:
-                    key = (bp.score_group_bucket(n), rows, plan.slice_bucket(n))
+                for rows, words in {(f.plane_rows(), f.plane_words()) for f in frags}:
+                    key = (bp.score_group_bucket(n), rows, plan.slice_bucket(n), words)
                     weight[key] = max(weight.get(key, 0), len(frags))
     return weight
 
@@ -215,12 +215,14 @@ def _heaviest(weight: dict) -> list:
     return sorted(weight, key=lambda k: -weight[k])[:_TOPN_SHAPES_MAX]
 
 
-def topn_shapes(holder) -> list[tuple[int, int]]:
-    """The ``(members, plane rows)`` of the scorer programs the holder's
-    indexes will use, the views with the most fragments first."""
-    weight: dict[tuple[int, int], int] = {}
-    for (members, rows, _), w in _view_shapes(holder).items():
-        weight[members, rows] = max(weight.get((members, rows), 0), w)
+def topn_shapes(holder) -> list[tuple[int, int, int]]:
+    """The ``(members, plane rows, row words)`` of the scorer programs
+    the holder's indexes will use, the views with the most fragments
+    first."""
+    weight: dict[tuple[int, int, int], int] = {}
+    for (members, rows, _, words), w in _view_shapes(holder).items():
+        key = (members, rows, words)
+        weight[key] = max(weight.get(key, 0), w)
     return _heaviest(weight)
 
 
@@ -229,21 +231,22 @@ def topn_shapes(holder) -> list[tuple[int, int]]:
 _GATHER_LEAVES = 2
 
 
-def gather_shapes(holder) -> list[tuple[int, int, int, int, int]]:
-    """The ``(members, plane rows, block rows, k, leaves)`` of the
+def gather_shapes(holder) -> list[tuple[int, int, int, int, int, int]]:
+    """The ``(members, plane rows, block rows, k, leaves, row words)`` of the
     leaf-batch gather (``bp.gather_planes``) and of its in-place write
     over the holder's indexes, for a tree of ``_GATHER_LEAVES`` rows of
     one view, the views with the most fragments first."""
     return [
-        shape + (_GATHER_LEAVES, _GATHER_LEAVES)
+        shape[:3] + (_GATHER_LEAVES, _GATHER_LEAVES, shape[3])
         for shape in _heaviest(_view_shapes(holder))
     ]
 
 
 def prewarm_gather(shapes=(), devices=None) -> int:
     """Compile the leaf-batch gather of a batch-cache miss at each
-    ``(members, plane rows, block rows, k, leaves)`` of ``shapes`` —
-    ``k`` rows of one view in a tree of ``leaves`` — and the in-place
+    ``(members, plane rows, block rows, k, leaves[, row words])`` of
+    ``shapes`` — ``k`` rows of one view in a tree of ``leaves``, planes
+    of full width unless said — and the in-place
     write of a launch's output into the block where a launch does not
     fill it.  The jit keys hold no expression and no slice count, so
     one warm-up serves every operator.  On each of ``devices`` (a
@@ -252,10 +255,8 @@ def prewarm_gather(shapes=(), devices=None) -> int:
     import jax
     import jax.numpy as jnp
 
-    def warm(dev, members, rows, block_rows, k, n_leaves):
-        zero = jax.device_put(
-            np.zeros((rows, bp.WORDS_PER_SLICE), dtype=np.uint32), dev
-        )
+    def warm(dev, members, rows, block_rows, k, n_leaves, words=bp.WORDS_PER_SLICE):
+        zero = jax.device_put(np.zeros((rows, words), dtype=np.uint32), dev)
         out = next(
             bp.gather_planes(
                 [zero] * members,
@@ -263,7 +264,7 @@ def prewarm_gather(shapes=(), devices=None) -> int:
                 first_call=plan.note_gather_first_call,
             )
         )
-        if (block_rows, n_leaves) != (members, k):
+        if (block_rows, n_leaves, words) != (members, k, bp.WORDS_PER_SLICE):
             out = bp.place_rows(
                 jnp.zeros(
                     (block_rows, n_leaves, bp.WORDS_PER_SLICE),
@@ -290,13 +291,13 @@ def prewarm_gather(shapes=(), devices=None) -> int:
 
 
 def agg_shapes(holder) -> list[tuple]:
-    """The ``(expr, cols, units, plane rows, members)`` of the in-place
+    """The ``(expr, cols, units, plane shape, members)`` of the in-place
     BSI aggregate (``bp.aggregate_planes``) for a plain ``Sum`` of each
     integer field the holder's indexes hold, the fields with the most
     fragments first.  The expression and the leaf layout are the
     executor's own (``Executor.bsi_agg_call``, ``_agg_columns``); the
-    holder adds what the fragments decide of the program: the row class
-    of the field's planes, and ``bp.agg_members`` for the n fragments
+    holder adds what the fragments decide of the program: the shape
+    (row class, row words) of the field's planes, and ``bp.agg_members`` for the n fragments
     that share a device.  A filtered aggregate's program holds its
     filter tree and compiles on its first call.  No mirror is uploaded
     for it."""
@@ -315,23 +316,21 @@ def agg_shapes(holder) -> list[tuple]:
                 cols, units = Executor._agg_columns(leaves)
                 n = -(-len(frags) // n_dev)
                 k = sum(c[0] == "row" for c in cols)
-                for rows in {f.plane_rows() for f in frags}:
-                    key = (expr, cols, units, rows, bp.agg_members(n, rows + k))
+                for shape in {(f.plane_rows(), f.plane_words()) for f in frags}:
+                    key = (expr, cols, units, shape, bp.agg_members(n, shape[0] + k))
                     weight[key] = max(weight.get(key, 0), len(frags))
     return _heaviest(weight)
 
 
 def prewarm_agg(shapes=(), devices=None) -> int:
     """Compile the in-place BSI aggregate at each ``(expr, cols, units,
-    plane rows, members)`` of ``shapes`` (:func:`agg_shapes`), on each
+    plane shape, members)`` of ``shapes`` (:func:`agg_shapes`), on each
     of ``devices`` (a compiled program is a device's own), side by
     side; the first device alone unless given."""
     import jax
 
-    def warm(dev, expr, cols, units, rows, members):
-        zero = jax.device_put(
-            np.zeros((rows, bp.WORDS_PER_SLICE), dtype=np.uint32), dev
-        )
+    def warm(dev, expr, cols, units, shape, members):
+        zero = jax.device_put(np.zeros(shape, dtype=np.uint32), dev)
         for out in bp.aggregate_planes(
             plan._eval_expr,
             expr,
@@ -357,11 +356,48 @@ def prewarm_agg(shapes=(), devices=None) -> int:
     return len(todo)
 
 
+def rows_shapes(holder) -> list[tuple[int, int]]:
+    """The ``(plane rows, row words)`` of the walked scorer
+    (``bp.score_rows``) the holder's indexes will use: a ranked view
+    that is ONE fragment is scored by a walk of its plane, at the row
+    class and the width the fragment keeps.  The fullest planes first."""
+    weight: dict[tuple[int, int], int] = {}
+    for idx in holder.indexes().values():
+        for frame in idx.frames().values():
+            for name, view in frame.views().items():
+                if not name.startswith((VIEW_STANDARD, VIEW_INVERSE)):
+                    continue
+                frags = view.fragments()
+                if len(frags) == 1:
+                    key = (frags[0].plane_rows(), frags[0].plane_words())
+                    weight[key] = max(weight.get(key, 0), frags[0].plane_nbytes)
+    return _heaviest(weight)
+
+
+def prewarm_rows(shapes=()) -> int:
+    """Compile the walked TopN scorer at each ``(plane rows, row
+    words)`` of ``shapes`` (:func:`rows_shapes`), placed as slice 0's
+    mirror is."""
+    import jax
+
+    dev = bp.home_device(0)
+    for rows, words in shapes:
+        outs = bp.score_rows(
+            jax.device_put(np.zeros((rows, words), dtype=np.uint32), dev),
+            jax.device_put(np.zeros(rows, dtype=np.int32), dev),
+            0, 0, 0, 0,
+            first_call=plan.note_scorer_first_call,
+        )
+        outs[0].block_until_ready()
+    return len(shapes)
+
+
 def prewarm_topn(shapes=_TOPN_SHAPES) -> int:
     """Compile the fused TopN scorer — the self-src variant of
     ``bp.score_planes`` (the common ``TopN(Bitmap(frame=f), frame=f)``
-    shape) — at each ``(members, plane rows)`` of ``shapes``, with as
-    many candidate slots as plane rows (every row a candidate).  Every
+    shape) — at each ``(members, plane rows[, row words])`` of
+    ``shapes`` (full-width planes unless said), with as many candidate
+    slots as plane rows (every row a candidate).  Every
     dimension of the scorer's jit key is pow2-bucketed and the member
     count is bounded by ``bp.SCORE_GROUP`` (ops/bitplane.py), so these
     are exactly the programs the first TopN queries hit, however many
@@ -371,11 +407,11 @@ def prewarm_topn(shapes=_TOPN_SHAPES) -> int:
     import jax
 
     warmed = 0
-    for members, rows in shapes:
+    for members, rows, *width in shapes:
         # Placed as a fragment's mirror is (the jit key holds the
         # placement): slice 0's home device.
         zero = jax.device_put(
-            np.zeros((rows, bp.WORDS_PER_SLICE), dtype=np.uint32),
+            np.zeros((rows, *(width or (bp.WORDS_PER_SLICE,))), dtype=np.uint32),
             bp.home_device(0),
         )
         planes = [zero] * members
@@ -391,13 +427,14 @@ def prewarm_topn(shapes=_TOPN_SHAPES) -> int:
 
 def prewarm(
     buckets=(1, 2, 4, 8), exprs=_STANDARD_EXPRS, coalesce=False, topn=(),
-    gather=(), agg=(),
+    gather=(), agg=(), rows=(),
 ) -> int:
     """Compile the standard (tree shape x slice bucket) programs, the
     TopN scorer at its standard shapes and at ``topn`` (the ``(members,
     plane rows)`` of :func:`topn_shapes`), the leaf-batch gather at
     ``gather`` (:func:`gather_shapes`) and the in-place BSI aggregate at
-    ``agg`` (:func:`agg_shapes`).
+    ``agg`` (:func:`agg_shapes`) and the walked TopN scorer at ``rows``
+    (:func:`rows_shapes`).
 
     Triggers real compilations by calling each program on a zero batch
     of the bucketed shape — with the persistent cache enabled this both
@@ -449,6 +486,7 @@ def prewarm(
     )
     warmed += prewarm_gather(gather)
     warmed += prewarm_agg(agg)
+    warmed += prewarm_rows(rows)
     if coalesce:
         warmed += prewarm_coalesce()
         warmed += prewarm_fuse()
@@ -456,7 +494,7 @@ def prewarm(
 
 
 def prewarm_async(
-    logger=None, coalesce=False, topn=(), gather=(), agg=()
+    logger=None, coalesce=False, topn=(), gather=(), agg=(), rows=()
 ) -> threading.Thread:
     """Run :func:`prewarm` on a daemon thread (server open must not
     block on compiles) and return the thread, which carries the
@@ -467,7 +505,7 @@ def prewarm_async(
     def run():
         try:
             t.programs = prewarm(
-                coalesce=coalesce, topn=topn, gather=gather, agg=agg
+                coalesce=coalesce, topn=topn, gather=gather, agg=agg, rows=rows
             )
         except Exception as e:  # noqa: BLE001 — recorded, not swallowed
             t.error = e

@@ -526,6 +526,7 @@ class Server:
                 topn=warmup.topn_shapes(self.holder),
                 gather=warmup.gather_shapes(self.holder),
                 agg=warmup.agg_shapes(self.holder),
+                rows=warmup.rows_shapes(self.holder),
             )
 
         # Start HTTP listener first so ":0" resolves to the real port

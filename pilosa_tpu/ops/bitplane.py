@@ -50,6 +50,13 @@ CONTAINERS_PER_SLICE = SLICE_WIDTH // CONTAINER_BITS  # 16
 # shapes the schema produces.
 ROW_BLOCK = 8
 
+# Words of the narrowest plane row: 4,096 columns, one lane row of a TPU
+# tile (8 such rows a tile).  A fragment's plane is as wide as the
+# columns it holds ask for (``row_words``), so a frame whose rows are
+# many and whose columns are few (a fingerprint a row) pays 512 B a row
+# and not 128 KiB.
+MIN_ROW_WORDS = 128
+
 
 def row_shape() -> tuple[int]:
     return (WORDS_PER_SLICE,)
@@ -59,8 +66,24 @@ def empty_row() -> np.ndarray:
     return np.zeros(WORDS_PER_SLICE, dtype=np.uint32)
 
 
-def empty_plane(rows: int) -> np.ndarray:
-    return np.zeros((rows, WORDS_PER_SLICE), dtype=np.uint32)
+def empty_plane(rows: int, words: int = WORDS_PER_SLICE) -> np.ndarray:
+    return np.zeros((rows, words), dtype=np.uint32)
+
+
+def row_words(max_offset: int) -> int:
+    """Words of a plane row that holds in-slice column ``max_offset``:
+    the smallest power of two that covers it, never under MIN_ROW_WORDS
+    nor over WORDS_PER_SLICE — a pow2 class like the rows', so the word
+    axis adds log2(256) + 1 shapes to a program family at most."""
+    return min(pow2_bucket((int(max_offset) >> 5) + 1, MIN_ROW_WORDS), WORDS_PER_SLICE)
+
+
+def widen_row(words: np.ndarray) -> np.ndarray:
+    """A row of a narrow plane as the full-width row every reader but
+    the TopN scorers takes (a copy; a full-width row is copied too)."""
+    row = empty_row()
+    row[: words.shape[-1]] = words
+    return row
 
 
 def pow2_bucket(n: int, floor: int = 1) -> int:
@@ -124,8 +147,8 @@ def np_contains(plane: np.ndarray, bit: int) -> bool:
 def np_set_bulk(plane: np.ndarray, rows: np.ndarray, offsets: np.ndarray) -> None:
     """Bulk set: vectorized scatter-OR for imports (reference:
     fragment.go:936-1004 bulk Import path)."""
-    words = offsets // WORD_BITS
-    masks = (np.uint32(1) << (offsets % WORD_BITS).astype(np.uint32)).astype(np.uint32)
+    words = offsets >> 5
+    masks = np.uint32(1) << (offsets & 31).astype(np.uint32)
     np.bitwise_or.at(plane, (rows, words), masks)
 
 
@@ -133,8 +156,8 @@ def np_clear_bulk(plane: np.ndarray, rows: np.ndarray, offsets: np.ndarray) -> N
     """Bulk clear: vectorized scatter-ANDNOT — the overwrite half of a
     columnar BSI value import (a re-imported column must drop the stale
     bits of its previous value)."""
-    words = offsets // WORD_BITS
-    masks = (np.uint32(1) << (offsets % WORD_BITS).astype(np.uint32)).astype(np.uint32)
+    words = offsets >> 5
+    masks = np.uint32(1) << (offsets & 31).astype(np.uint32)
     np.bitwise_and.at(plane, (rows, words), ~masks)
 
 
@@ -148,9 +171,10 @@ def np_row_to_columns(row_words: np.ndarray) -> np.ndarray:
     return positions.astype(np.uint64)
 
 
-def np_columns_to_row(offsets: np.ndarray) -> np.ndarray:
-    """Inverse of np_row_to_columns: bit offsets (within slice) -> row words."""
-    row = empty_row()
+def np_columns_to_row(offsets: np.ndarray, words: int = WORDS_PER_SLICE) -> np.ndarray:
+    """Inverse of np_row_to_columns: bit offsets (within slice) -> row
+    words (``words`` of them: the caller's plane covers the offsets)."""
+    row = np.zeros(words, dtype=np.uint32)
     if len(offsets) == 0:
         return row
     offsets = np.asarray(offsets, dtype=np.uint64)
@@ -158,6 +182,31 @@ def np_columns_to_row(offsets: np.ndarray) -> np.ndarray:
     masks = (np.uint32(1) << (offsets % WORD_BITS).astype(np.uint32)).astype(np.uint32)
     np.bitwise_or.at(row, words, masks)
     return row
+
+
+def np_plane_positions(block: np.ndarray) -> np.ndarray:
+    """The set bits of ``block`` (uint32[n, words]) as uint32 in-row
+    positions, row after row, ascending within a row: the values of a
+    row's roaring array containers, for every row at once.  Works a set
+    WORD at a time, not a bit: a plane row of few columns is mostly
+    zero words, and a set word has one or two bits (``np.unpackbits``
+    over a million 512 B rows writes 7 GB of bytes to find 80M)."""
+    flat = block.reshape(-1)
+    nz = np.flatnonzero(flat)
+    v = flat[nz]
+    pc = np_word_counts(v)
+    at = np.cumsum(pc) - pc  # where a word's first bit goes
+    # (a plane's row is a power of two of words)
+    base = ((nz & (block.shape[1] - 1)) << 5).astype(np.uint32)
+    out = np.empty(int(pc.sum()), dtype=np.uint32)
+    one = np.uint32(1)
+    while len(v):
+        low = v & (~v + one)  # the lowest set bit
+        out[at] = base + np_word_counts(low - one).astype(np.uint32)
+        v = v ^ low
+        more = v != 0
+        v, at, base = v[more], at[more] + 1, base[more]
+    return out
 
 
 if hasattr(np, "bitwise_count"):  # numpy >= 2.0
@@ -172,6 +221,10 @@ if hasattr(np, "bitwise_count"):  # numpy >= 2.0
         """Host per-row popcounts (cache maintenance without a device trip)."""
         return np.bitwise_count(plane).sum(axis=-1, dtype=np.int64)
 
+    def np_word_counts(words: np.ndarray) -> np.ndarray:
+        """Host popcount of each word."""
+        return np.bitwise_count(words).astype(np.int64)
+
 else:  # pragma: no cover - numpy 1.x fallback
 
     def np_count(words: np.ndarray) -> int:
@@ -182,6 +235,9 @@ else:  # pragma: no cover - numpy 1.x fallback
             np.unpackbits(np.ascontiguousarray(plane).view(np.uint8), axis=-1)
             .sum(axis=-1, dtype=np.int64)
         )
+
+    def np_word_counts(words: np.ndarray) -> np.ndarray:
+        return np_row_counts(np.ascontiguousarray(words)[:, None])
 
 
 # ---------------------------------------------------------------------------
@@ -648,6 +704,16 @@ def shape_highwater() -> dict[str, int]:
     return dict(_SHAPE_HIGHWATER)
 
 
+def word_classes() -> int:
+    """The row-width classes of the plane mirrors the plane-shaped
+    program families have been called with (``row_words``: pow2, floor
+    MIN_ROW_WORDS): a factor of each such family's bound."""
+    return bucket_classes(
+        max(_SHAPE_HIGHWATER.get("plane_words", MIN_ROW_WORDS), MIN_ROW_WORDS),
+        MIN_ROW_WORDS,
+    )
+
+
 def top_counts(plane, src_row):
     """Per-row |row AND src| -> int32[rows]: the batched TopN(Src=...) scorer.
 
@@ -656,7 +722,7 @@ def top_counts(plane, src_row):
     every row in one fused batched kernel and select on the host — same
     results, hardware-shaped loop structure.
     """
-    _note_shape(top_rows=int(plane.shape[0]))
+    _note_shape(top_rows=int(plane.shape[0]), plane_words=int(plane.shape[1]))
     return _top_counts_xla(plane, src_row)
 
 
@@ -775,6 +841,7 @@ def score_planes(planes, slots, src_slots=None, srcs=None, first_call=None) -> l
         score_frags=bucket,
         score_rows=max(int(p.shape[0]) for p in planes),
         score_slots=int(slots.shape[-1]),
+        plane_words=int(planes[0].shape[1]),
     )
     fn, src = (
         (_score_planes_self_src, src_slots)
@@ -797,6 +864,74 @@ def score_planes(planes, slots, src_slots=None, srcs=None, first_call=None) -> l
             _first_call(fn, shape, first_call, group, slots[idx], src[idx])
         )
     return outs
+
+
+# Hits a walked score hands back compacted (``score_rows``): the rows a
+# text keeps are few (a screen's answer is tens to thousands of pairs)
+# beside the rows it scores (millions), so what crosses to the host is
+# this many (slot, shared bits) pairs and a count, not a vector as long
+# as the plane.  A text that keeps more fetches the vector as well.
+ROW_HITS = 1 << 14
+
+
+@jax.jit
+def _score_rows_xla(plane, cnts, q):
+    src = jax.lax.dynamic_index_in_dim(plane, q[0], axis=0, keepdims=False)
+    c = jnp.sum(
+        jax.lax.population_count(plane & src[None, :]).astype(jnp.int32), axis=-1
+    )
+    s, t, m = q[1], q[2], q[3]
+    # upstream's rules in integers (each side under 2^31 while a row has
+    # at most 2^20 columns): the window on cached counts
+    # cnt > s*t/100 and cnt < s*100/t, and ceil(100c / (cnt+s-c)) > t
+    windowed = (100 * cnts > s * t) & (cnts * t < 100 * s)
+    similar = 100 * c > t * (cnts + s - c)
+    keep = (cnts > 0) & (c > 0) & jnp.where(
+        t > 0, windowed & similar, (cnts >= m) & (c >= m)
+    )
+    # The slots of the first k kept rows, in two steps of bounded size (a
+    # binary search over all the rows is 21 rounds of gathers from HBM,
+    # and took longer than the walk itself: my chip run, PR 36): which
+    # block of ``lanes`` rows holds the j-th kept row, by the blocks' own
+    # counts; then which row of that block, by a prefix sum along it.
+    rows = plane.shape[0]
+    k, lanes = min(ROW_HITS, rows), min(128, rows)
+    blocks = keep.reshape(rows // lanes, lanes).astype(jnp.int32)
+    ends = jnp.cumsum(blocks.sum(axis=1))
+    j = jnp.arange(1, k + 1, dtype=jnp.int32)
+    b = jnp.minimum(jnp.searchsorted(ends, j), rows // lanes - 1)
+    mine = blocks[b]
+    need = j - (ends[b] - mine.sum(axis=1))
+    lane = jnp.sum(jnp.cumsum(mine, axis=1) < need[:, None], axis=1)
+    at = (b * lanes + jnp.minimum(lane, lanes - 1)).astype(jnp.int32)
+    return jnp.sum(keep.astype(jnp.int32)), at, c[at], jnp.where(keep, c, 0)
+
+
+def score_rows(plane, cnts, src_slot: int, src_count: int, tanimoto: int,
+               min_threshold: int, first_call=None):
+    """The TopN scorer of ONE fragment whose candidates are every row
+    of its plane (rows: molecules, say): ``plane`` uint32[rows, words],
+    the mirror snapshot at whatever width the fragment keeps, is walked
+    once — AND with row ``src_slot`` of itself, popcount, a lane reduce
+    a row — and filtered where the counts are: ``cnts`` int32[rows], the
+    ranked cache's count of the row in each slot (0: no candidate),
+    resident beside the plane, gives the tanimoto count window and the
+    similarity rule (or, without ``tanimoto``, ``min_threshold``), as
+    ``Fragment._filter_arrays`` and ``top_score_arrays`` apply them.
+
+    Dispatched without waiting; returns device arrays ``(hits, slots,
+    shared, every)``: how many rows are kept, the slots of the first
+    ROW_HITS of them in slot order with their shared-bit counts, and
+    int32[rows] with a kept row's shared bits and 0 elsewhere, which a
+    caller fetches only when ``hits`` is over ROW_HITS.  The text's
+    numbers are operands: the jit key is the plane's shape and its
+    device, so the programs are the row classes x the word classes,
+    whatever the row count, the src or the threshold."""
+    _note_shape(walk_rows=int(plane.shape[0]), plane_words=int(plane.shape[1]))
+    dev = device_of(plane)
+    shape = ("rows", tuple(plane.shape), str(dev))
+    q = np.asarray([src_slot, src_count, tanimoto, min_threshold], dtype=np.int32)
+    return _first_call(_score_rows_xla, shape, first_call, plane, cnts, q)
 
 
 @jax.jit
@@ -872,7 +1007,10 @@ def gather_planes(planes, slots, first_call=None):
     slots = np.asarray(slots, dtype=np.int32).reshape(n, -1)
     k = int(slots.shape[1])
     _note_shape(
-        gather_frags=bucket, gather_rows=int(planes[0].shape[0]), gather_leaves=k
+        gather_frags=bucket,
+        gather_rows=int(planes[0].shape[0]),
+        gather_leaves=k,
+        plane_words=int(planes[0].shape[1]),
     )
     dev = device_of(planes[0])
     shape = ("gather", bucket, tuple(planes[0].shape), k, str(dev))
@@ -1010,6 +1148,28 @@ def _aggregate_planes_xla(evaluate, expr, cols, units, interpret, planes, table,
         ],
         axis=1,
     )
+    # A plane narrower than a slice (``row_words``) is widened here, on
+    # read: the DMA stage and the expression take full-width rows.  Of a
+    # "tile" unit only the tile is (a narrow plane may hold millions of
+    # rows); it then stands at tile 0 of its own eight rows.
+    n_units = len(units)
+    narrow = [int(p.shape[1]) < WORDS_PER_SLICE for p in planes[:n_units]]
+    if any(narrow):
+        planes = list(planes)
+        for i, p in enumerate(planes):
+            f, u = divmod(i, n_units)
+            if not narrow[u]:
+                continue
+            if units[u] == "tile":
+                p = jax.lax.dynamic_slice_in_dim(
+                    p, tiles[f, u] * TILE_ROWS, TILE_ROWS, axis=0
+                )
+            planes[i] = jnp.pad(p, ((0, 0), (0, WORDS_PER_SLICE - p.shape[1])))
+        tiles = jnp.where(
+            jnp.asarray([n and unit == "tile" for n, unit in zip(narrow, units)]),
+            0,
+            tiles,
+        )
     block, offsets, unit_rows = _copy_units(units, planes, tiles, interpret)
     # Every leaf row, picked out of the block by the member's own slot
     # (of a tile: the slot within it) in ONE gather a launch: where the
@@ -1128,11 +1288,12 @@ def aggregate_planes(evaluate, expr, cols, units, planes, slots, preds, first_ca
         agg_frags=bucket,
         agg_units=n_units,
         agg_rows=max(int(p.shape[0]) for p in planes[:n_units]),
+        plane_words=max(int(p.shape[1]) for p in planes[:n_units]),
     )
     dev = device_of(planes[0])
     shape = (
         "agg", expr, cols, units,
-        tuple(int(p.shape[0]) for p in planes[:n_units]), bucket, str(dev),
+        tuple(tuple(p.shape) for p in planes[:n_units]), bucket, str(dev),
     )
     interpret = dev.platform != "tpu"
     preds = jax.device_put(

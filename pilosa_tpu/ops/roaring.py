@@ -431,22 +431,121 @@ def encode(containers: dict[int, np.ndarray]) -> bytes:
     return encode_tiered(containers, {})
 
 
+def merge_packed_arrays(
+    keys: np.ndarray, counts: np.ndarray, values: np.ndarray
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Array containers as three arrays — ``keys`` (distinct), each
+    container's ``counts`` and every container's ``values`` one after
+    another — put in ascending key order (``encode_packed``'s form)."""
+    counts = np.asarray(counts, dtype=np.int64)
+    if len(keys) < 2 or (np.diff(keys.view(np.int64)) > 0).all():
+        return keys, counts, values
+    order = np.argsort(keys, kind="stable")
+    starts = np.cumsum(counts) - counts
+    moved = counts[order]
+    new_starts = np.cumsum(moved) - moved
+    take = np.repeat(starts[order] - new_starts, moved) + np.arange(len(values))
+    return keys[order], moved, values[take]
+
+
+def _encode_packed_arrays(keys, words2d, akeys, acounts, avalues) -> bytes:
+    """``encode_tiered`` over packed inputs, with no Python step a
+    container: the payload form follows the same n <= ARRAY_MAX_SIZE
+    rule whatever form a container was handed over in (the reader
+    decides a payload's form by its n), empty containers are dropped,
+    keys ascend."""
+    from pilosa_tpu.ops import bitplane as bp
+
+    acounts = np.asarray(acounts, dtype=np.int64)
+    w32 = (
+        np.ascontiguousarray(words2d)
+        .view(np.uint32)
+        .reshape(len(keys), CONTAINER_BITS // 32)
+    )
+    wcounts = bp.np_row_counts(w32) if len(keys) else np.zeros(0, np.int64)
+    low = (wcounts > 0) & (wcounts <= ARRAY_MAX_SIZE)
+    if low.any():  # bitmaps the file holds as arrays
+        akeys, acounts, avalues = merge_packed_arrays(
+            np.concatenate([akeys, keys[low]]),
+            np.concatenate([acounts, wcounts[low]]),
+            np.concatenate([avalues, bp.np_plane_positions(w32[low])]),
+        )
+    big = wcounts > ARRAY_MAX_SIZE
+    bkeys, bwords, bcounts = keys[big], w32[big], wcounts[big]
+    over = acounts > ARRAY_MAX_SIZE
+    if over.any():  # arrays the file holds as bitmaps (rare: a row each)
+        starts = np.cumsum(acounts) - acounts
+        extra = [
+            values_to_words(avalues[a : a + c]).view(np.uint32)
+            for a, c in zip(starts[over].tolist(), acounts[over].tolist())
+        ]
+        bkeys = np.concatenate([bkeys, akeys[over]])
+        bwords = np.concatenate([bwords, np.stack(extra)])
+        bcounts = np.concatenate([bcounts, acounts[over]])
+        keep = np.repeat(~over, acounts)
+        akeys, acounts, avalues = akeys[~over], acounts[~over], avalues[keep]
+    live = acounts > 0
+    if not live.all():
+        akeys, acounts = akeys[live], acounts[live]
+
+    n_b, n_a = len(bkeys), len(akeys)
+    all_keys = np.concatenate([bkeys, akeys]).astype(np.uint64)
+    order = np.argsort(all_keys, kind="stable")
+    if len(all_keys) > 1 and (np.diff(all_keys[order].view(np.int64)) == 0).any():
+        raise ValueError("a container key is present in both tiers")
+    ns = np.concatenate([bcounts, acounts])[order]
+    is_b = (order < n_b)
+    plens = np.where(is_b, 8 * (CONTAINER_BITS // 64), 4 * ns)
+    base = HEADER_SIZE + 16 * len(order)
+    offs = base + np.cumsum(plens) - plens
+    out = np.zeros((base + int(plens.sum())) // 4, dtype="<u4")
+    out[:2] = (COOKIE, len(order))
+    ktab = np.zeros(len(order), dtype=np.dtype([("key", "<u8"), ("n1", "<u4")]))
+    ktab["key"] = all_keys[order]
+    ktab["n1"] = ns - 1
+    head = out.view(np.uint8)
+    head[HEADER_SIZE : HEADER_SIZE + 12 * len(order)] = np.frombuffer(
+        ktab.tobytes(), np.uint8
+    )
+    out[2 + 3 * len(order) : 2 + 4 * len(order)] = offs.astype("<u4")
+    # Payloads: the arrays keep their relative order under the stable
+    # sort, so their values land run after run; a bitmap a row of words.
+    if n_a and not n_b:
+        out[base // 4 :] = avalues  # nothing but arrays: one run
+    elif n_a:
+        a_at = offs[~is_b] // 4
+        out[
+            np.repeat(a_at - (np.cumsum(acounts) - acounts), acounts)
+            + np.arange(len(avalues))
+        ] = avalues
+    if n_b:
+        b_at = offs[is_b] // 4
+        out[b_at[:, None] + np.arange(bwords.shape[1])[None, :]] = bwords[
+            order[is_b]
+        ]
+    return out.tobytes()
+
+
 def encode_packed(
     keys: np.ndarray,
     words2d: np.ndarray,
-    arrays: dict[int, np.ndarray] | None = None,
+    arrays=None,
 ) -> bytes:
     """Serialize a PACKED dense tier — ``keys`` ascending container
     keys, ``words2d[i]`` the 1024-u64 payload of ``keys[i]`` — plus an
-    optional sparse-arrays tier.  The all-dense case hands the buffers
-    straight to the C++ codec with no per-container Python; mixed or
-    native-less cases fall back to the general dict path."""
+    optional arrays tier: a dict ``{key: values}``, or packed as
+    ``(akeys ascending, acounts, avalues)`` (``merge_packed_arrays``).
+    The all-dense case hands the buffers straight to the C++ codec with
+    no per-container Python; the packed arrays go through numpy alone;
+    a dict falls back to the general dict path."""
     from pilosa_tpu import native
 
-    if not arrays:
+    if arrays is None or len(arrays) == 0:
         res = native.encode_packed(keys, words2d)
         if res is not None:
             return res
+    if isinstance(arrays, tuple) and arrays:
+        return _encode_packed_arrays(keys, words2d, *arrays)
     words = {int(k): words2d[i] for i, k in enumerate(keys)}
     return encode_tiered(words, arrays or {})
 

@@ -255,8 +255,8 @@ def test_prewarm_warms_the_plain_sum_of_the_holders_own_fields(one_chip, table):
     # d (depth 4) and q (depth 7) share the depth-8 bucket, so one
     # expression and one layout: exists, sign, 8 magnitude leaves of which
     # the pads are zeros; d's planes have 8 rows, q's 16
-    assert sorted((len(e) - 2, [c[0] for c in cols].count("row"), units, rows, m)
-                  for e, cols, units, rows, m in shapes) == [
+    assert sorted((len(e) - 2, [c[0] for c in cols].count("row"), units, shape[0], m)
+                  for e, cols, units, shape, m in shapes) == [
         (10, 6, ("whole",), 8, members), (10, 9, ("whole",), 16, members)]
     plan.clear_program_caches()
     assert warmup.prewarm_agg(shapes) == 2

@@ -155,7 +155,9 @@ def fill_fragments(holder, n_frags, rows_per_frag=2):
     for s in range(n_frags):
         for r in range(rows_per_frag):
             f.set_bit("standard", r, s * bp.SLICE_WIDTH + r + 1)
-            f.set_bit("standard", r, s * bp.SLICE_WIDTH + 100 + r)
+            # a column at the slice's end: the planes are of full width,
+            # as wide as the rows of the leaf batches the budgets hold
+            f.set_bit("standard", r, (s + 1) * bp.SLICE_WIDTH - 100 + r)
     return f
 
 
@@ -197,7 +199,7 @@ class TestFragmentResidency:
         for frag in frags:
             row = np.asarray(frag.device_row(0))
             cols = bp.np_row_to_columns(row).tolist()
-            assert cols == [1, 100]
+            assert cols == [1, bp.SLICE_WIDTH - 100]
 
     def test_pending_point_write_survives_eviction(self, holder, fresh_pool):
         """Regression: point writes queued against a live mirror, then
@@ -213,7 +215,7 @@ class TestFragmentResidency:
         assert frag._evict_mirror()
         assert frag._device is None and not frag._device_pending
         cols = bp.np_row_to_columns(np.asarray(frag.device_row(0))).tolist()
-        assert cols == [1, 7, 100]
+        assert cols == [1, 7, bp.SLICE_WIDTH - 100]
         # And the same through pool pressure instead of a direct call:
         frag.device_plane()
         frag.set_bit(0, 9)
@@ -225,7 +227,7 @@ class TestFragmentResidency:
         assert frag._device is None, "budget pressure should evict the mirror"
         assert not frag._device_pending
         cols = bp.np_row_to_columns(np.asarray(frag.device_row(0))).tolist()
-        assert cols == [1, 7, 9, 100]
+        assert cols == [1, 7, 9, bp.SLICE_WIDTH - 100]
 
     def test_pinned_mirror_survives_pressure(self, holder, fresh_pool):
         fill_fragments(holder, 1)

@@ -816,7 +816,9 @@ def test_topn_single_slice_skips_phase2(ex, holder, monkeypatch):
     monkeypatch.setattr(Executor, "_execute_topn_slices", spy)
     (pairs,) = q(ex, "i", "TopN(Bitmap(rowID=0, frame=f), frame=f, n=2)")
     assert [(p.id, p.count) for p in pairs] == [(0, 8), (1, 4)]
-    assert len(calls) == 1  # no phase-2 pass
+    # no phase-2 pass, and since PR 36 no per-slice pass either: one slice
+    # is folded as many are (both phases from one scoring pass)
+    assert calls == []
 
 
 def test_topn_inverse_orientation(ex, holder):

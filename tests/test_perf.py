@@ -522,7 +522,8 @@ class TestPerfEndpoint:
 
     def test_byte_accounting_matches_hbm_plane_geometry(self, perf_server):
         s = perf_server
-        f = _populate(s, rows=1, cols=(1, 7))
+        # a column at the slice's end: the plane is of full width
+        f = _populate(s, rows=1, cols=(1, 7, SLICE_WIDTH - 2))
         c = InternalClient(s.host, timeout=30.0)
         c.execute_pql("i", 'Bitmap(frame="f", rowID=1)')
         sites = perf.registry().snapshot()["sites"]

@@ -72,6 +72,11 @@ def corpus(tmp_path_factory):
         for r in rows:
             for col in rng.integers(0, SW, 3):
                 f.set_bit("standard", r, s * SW + int(col))
+        # a column at the slice's end: every plane is of full width, as a
+        # filled index's are (a plane is as wide as its columns ask for,
+        # and a width class is a program of its own)
+        if rows:
+            f.set_bit("standard", rows[0], s * SW + SW - 1)
     assert h.fragment("i", "f", "standard", 5).plane_rows() == bp.ROW_BLOCK == 8
     yield h
     h.close()

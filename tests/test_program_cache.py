@@ -141,7 +141,11 @@ class TestChurnySchemaCardinality:
         # >= 32 distinct fragment shapes -> <= 4 programs per family.
         _assert_bounded()
         stats = plan.program_cache_stats()
-        assert stats["bitplane.scorePlanes"] >= 1  # the scorer DID run
+        # the scorer DID run: since PR 36 a one-slice TopN(src) walks its
+        # fragment's rows (bitplane.scoreRows), bounded as the rest
+        assert stats["bitplane.scorePlanes"] + stats["bitplane.scoreRows"] >= 1
+        assert stats["bitplane.scoreRows"] <= min(
+            4, plan.program_cache_bounds()["bitplane.scoreRows"])
 
     def test_coalesced_path(self, churny):
         holder, bits = churny

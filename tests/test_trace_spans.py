@@ -207,6 +207,10 @@ def _populate(s, slices):
         for r in (1, 2):
             f.set_bit("standard", r, sl * SLICE_WIDTH + 5)
         f.set_bit("standard", 1, sl * SLICE_WIDTH + 9)
+        # a column at the slice's end, in a row no text asks about: planes
+        # of full width, the shapes of a filled index (narrow ones are
+        # tests/test_narrow_planes.py's)
+        f.set_bit("standard", 7, (sl + 1) * SLICE_WIDTH - 9)
 
 
 def _stage_mirrors(s):
@@ -393,8 +397,9 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
     assert len(first["spans"]) <= 24
     prep = _span(first, "topn.prep")
     # every fragment ranks the union itself and holds the src in its
-    # plane: no fragment walked, no host copy of the src (``build``)
-    assert prep["tags"] == {"slices": slices, "prep_cache": "built", "union": 2,
+    # plane: no fragment walked, no host copy of the src (``build``);
+    # the union is rows 1, 2 and the row that holds the far column
+    assert prep["tags"] == {"slices": slices, "prep_cache": "built", "union": 3,
                             "build": "direct"}
     disp = _span(first, "topn.dispatch")
     launches = -(-slices // bp.SCORE_GROUP)
