@@ -3506,11 +3506,15 @@ class Executor:
                 hits = int(hits)
                 if hits > len(at):
                     # more rows kept than a launch hands back compacted
+                    handback = "vector"
                     every = np.asarray(self._shared_fetch([every], sp)[0])
                     at = np.flatnonzero(every)
                     shared = every[at]
                 else:
+                    # the steps the device took to locate them
+                    handback = "one" if hits <= bp.row_step(len(at)) else "more"
                     at, shared = np.asarray(at)[:hits], np.asarray(shared)[:hits]
+                sp.annotate(hits=hits, handback=handback)
             if perf_mod.enabled():
                 perf_mod.record_launch(
                     "topn",

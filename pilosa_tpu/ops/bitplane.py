@@ -873,6 +873,23 @@ def score_planes(planes, slots, src_slots=None, srcs=None, first_call=None) -> l
 # as the plane.  A text that keeps more fetches the vector as well.
 ROW_HITS = 1 << 14
 
+# Kept rows one step of the hand-back locates.  A step costs the device
+# by the rows it locates, whatever the plane's size, so the steps taken
+# follow the rows a text keeps and not ROW_HITS: a median screen answer
+# (tens of pairs) takes one, its largest (a few thousand) four.  At
+# [2^21, 128] a step is 0.042 ms beside a walk of 1.49 (my chip run,
+# PR 37: 256 / 512 / 2,048 read 0.016 / 0.025 / 0.074 a step, and an
+# answer of 3,300 rows 1.71 / 1.66 / 1.64 / 1.64 ms at 256 ... 2,048);
+# the program's compile time grows with it (1.6 s at 256, 4.0 at 1,024,
+# 14 at 2,048, 45 at 4,096).
+ROW_STEP = 1 << 10
+
+
+def row_step(k: int) -> int:
+    """Kept rows a step of the hand-back locates when a launch hands
+    back ``k`` at most (``len(slots)`` of ``score_rows``)."""
+    return min(ROW_STEP, k)
+
 
 @jax.jit
 def _score_rows_xla(plane, cnts, q):
@@ -889,22 +906,45 @@ def _score_rows_xla(plane, cnts, q):
     keep = (cnts > 0) & (c > 0) & jnp.where(
         t > 0, windowed & similar, (cnts >= m) & (c >= m)
     )
-    # The slots of the first k kept rows, in two steps of bounded size (a
-    # binary search over all the rows is 21 rounds of gathers from HBM,
-    # and took longer than the walk itself: my chip run, PR 36): which
-    # block of ``lanes`` rows holds the j-th kept row, by the blocks' own
-    # counts; then which row of that block, by a prefix sum along it.
+    hits = jnp.sum(keep.astype(jnp.int32))
+    # The slots of the first k kept rows, ``step`` of them a trip of a
+    # loop that runs ceil(min(hits, k) / step) times, each in two stages
+    # of bounded size: which block of ``lanes`` rows holds the j-th kept
+    # row, by counting the blocks whose running count is under j (a fused
+    # compare-and-count over [step, blocks]: a binary search's probes are
+    # serial loads from HBM, 0.145 ms a step where this takes 0.042); then
+    # which row of that block, by a prefix sum along it.  All k = 16,384
+    # at once, by binary search, took 2.4 ms an answer of twenty pairs
+    # beside a walk of 1.5 (my chip runs, PR 36, PR 37).
     rows = plane.shape[0]
     k, lanes = min(ROW_HITS, rows), min(128, rows)
+    step = row_step(k)
     blocks = keep.reshape(rows // lanes, lanes).astype(jnp.int32)
     ends = jnp.cumsum(blocks.sum(axis=1))
-    j = jnp.arange(1, k + 1, dtype=jnp.int32)
-    b = jnp.minimum(jnp.searchsorted(ends, j), rows // lanes - 1)
-    mine = blocks[b]
-    need = j - (ends[b] - mine.sum(axis=1))
-    lane = jnp.sum(jnp.cumsum(mine, axis=1) < need[:, None], axis=1)
-    at = (b * lanes + jnp.minimum(lane, lanes - 1)).astype(jnp.int32)
-    return jnp.sum(keep.astype(jnp.int32)), at, c[at], jnp.where(keep, c, 0)
+    first = jnp.arange(1, step + 1, dtype=jnp.int32)
+
+    def locate(i, out):
+        at, shared = out
+        j = i * step + first
+        b = jnp.minimum(
+            jnp.searchsorted(ends, j, method="compare_all"), rows // lanes - 1
+        )
+        mine = blocks[b]
+        need = j - (ends[b] - mine.sum(axis=1))
+        lane = jnp.sum(jnp.cumsum(mine, axis=1) < need[:, None], axis=1)
+        mine_at = (b * lanes + jnp.minimum(lane, lanes - 1)).astype(jnp.int32)
+        return (
+            jax.lax.dynamic_update_slice(at, mine_at, (i * step,)),
+            jax.lax.dynamic_update_slice(shared, c[mine_at], (i * step,)),
+        )
+
+    # (k is a whole number of steps: a plane's row class and the two
+    # sizes are powers of two)
+    zeros = jnp.zeros(k, dtype=jnp.int32)
+    at, shared = jax.lax.fori_loop(
+        0, (jnp.minimum(hits, k) + step - 1) // step, locate, (zeros, zeros)
+    )
+    return hits, at, shared, jnp.where(keep, c, 0)
 
 
 def score_rows(plane, cnts, src_slot: int, src_count: int, tanimoto: int,
@@ -923,10 +963,15 @@ def score_rows(plane, cnts, src_slot: int, src_count: int, tanimoto: int,
     shared, every)``: how many rows are kept, the slots of the first
     ROW_HITS of them in slot order with their shared-bit counts, and
     int32[rows] with a kept row's shared bits and 0 elsewhere, which a
-    caller fetches only when ``hits`` is over ROW_HITS.  The text's
-    numbers are operands: the jit key is the plane's shape and its
-    device, so the programs are the row classes x the word classes,
-    whatever the row count, the src or the threshold."""
+    caller fetches only when ``hits`` is over ROW_HITS; entries of
+    ``slots`` and ``shared`` past ``hits`` are unspecified.  Beyond the
+    walk, the device's time follows the rows kept: they are located
+    ``row_step`` at a time, so a text that keeps a handful pays one
+    step and not the ROW_HITS serial probes a round that locating them
+    all at once cost every answer (PERF.md, PR 36, PR 37).  The text's
+    numbers and ``hits`` are operands: the jit key is the plane's shape
+    and its device, so the programs are the row classes x the word
+    classes, whatever the row count, the src or the threshold."""
     _note_shape(walk_rows=int(plane.shape[0]), plane_words=int(plane.shape[1]))
     dev = device_of(plane)
     shape = ("rows", tuple(plane.shape), str(dev))

@@ -150,8 +150,49 @@ def test_the_answers_took_the_walked_scorer_over_the_plane_in_place(served, kind
     assert score["stride_words"] == frag.plane_words()
     assert spans["topn.dispatch"]["tags"]["launches"] == 1
     assert spans["topn.dispatch"]["tags"]["bytes"] == frag.plane_nbytes
+    fetch = spans["topn.fetch"]["tags"]
+    assert fetch["handback"] == "one"
+    assert fetch["hits"] == len(ref.answer(("TopN", q, 2_000_000, t)))
     assert spans["topn.select"]["tags"] == {"parts": 1, "way": "rows"}
     assert "hosteval" not in spans and tracer is not None
+
+
+@pytest.fixture
+def tiny_handback(monkeypatch):
+    """A step of 4 rows and a launch of 16, so that a test's answers
+    reach past each; the walked scorer's programs are traced anew under
+    them and again after."""
+    monkeypatch.setattr(bp, "ROW_STEP", 4)
+    monkeypatch.setattr(bp, "ROW_HITS", 16)
+    bp._score_rows_xla.clear_cache()
+    bp._SCORE_SEEN.clear()
+    yield
+    bp._score_rows_xla.clear_cache()
+    bp._SCORE_SEEN.clear()
+
+
+@pytest.mark.parametrize("handback, pairs", [
+    ("one", range(1, 5)), ("more", range(5, 17)), ("vector", range(17, 3000)),
+])
+def test_the_fetch_says_how_the_kept_rows_were_handed_back(
+    served, kind, tiny_handback, handback, pairs
+):
+    """``topn.fetch`` tags what the device's hand-back took: ``one``
+    step, ``more`` steps, or the whole ``vector`` for a text that keeps
+    more rows than a launch compacts; ``hits`` is the reference's pair
+    count (``n`` never trims)."""
+    c, ref, cfg, _ = served
+    # molecules no other test here asks about: a score is memoized 10 s
+    q, t, want = next(
+        (q, t, want) for t in (90, 70, 1) for q in range(1000, 1200)
+        if len(want := ref.answer(("TopN", q, 2_000_000, t))) in pairs
+    )
+    assert kind.normalise(asked(c, cfg, q, t)) == want
+    fetch = next(sp for sp in json.loads(
+        c._request("GET", "/debug/traces")[1])["traces"][-1]["spans"]
+        if sp["name"] == "topn.fetch")["tags"]
+    assert fetch["handback"] == handback and fetch["hits"] == len(want)
+    assert fetch["arrays"] == 3
 
 
 def test_more_hits_than_a_launch_compacts_are_all_returned(served, kind, monkeypatch):
@@ -280,12 +321,15 @@ def test_a_traced_rehearsal_is_correct_and_reads_every_listed_metric(
     assert {"exec.tanimoto_prep_ms", "exec.tanimoto_select_ms",
             "device.tanimoto_dispatch_ms", "device.tanimoto_fetch_ms",
             "exec.tanimoto_narrow_share", "exec.tanimoto_host_scored_rows",
-            "device.tanimoto_roofline"} <= listed
+            "device.tanimoto_roofline",
+            "device.tanimoto_handback_one_share"} <= listed
     assert not any("topn_" in name or "bsi_" in name for name in listed)
     assert set(line["metrics"]) == listed - DEVICE_ONLY
     m = {k: v["value"] for k, v in line["metrics"].items()}
     # every text is new and every answer walked the narrow rows in place
     assert m["exec.tanimoto_narrow_share"] == 100.0
     assert m["exec.tanimoto_host_scored_rows"] == 0
+    # a test's 4,000 molecules keep far fewer rows than a step locates
+    assert m["device.tanimoto_handback_one_share"] == 100.0
     assert m["device.window_new_programs"] == 0 and m["device.window_compile_ms"] == 0
     assert m["exec.tanimoto_prep_ms"] > 0 and m["device.tanimoto_dispatch_ms"] > 0

@@ -313,6 +313,31 @@ def test_the_leaf_batch_gather_compiles_for_the_chip_at_the_cells_own_width(
     assert mem.temp_size_in_bytes < G * 2 * row
 
 
+def test_the_walked_scorer_compiles_for_the_chip_at_the_cells_own_width(one_v5e_chip):
+    """What every answer of ``chembl-tanimoto.screen`` launches:
+    ``bp.score_rows`` over the 2^21-row plane of 128-word rows and the
+    cached counts beside it.  The one loop of the program is the
+    hand-back's, a step of ``bp.ROW_STEP`` kept rows a trip: a search
+    by rounds of probes inside it would be a second.  (Here beside the
+    scorer's: the tests that describe the chip stay in one file.)"""
+    import jax
+    import jax.numpy as jnp
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e_chip)
+
+    rows, words = 1 << 21, 128
+    compiled = bp._score_rows_xla.lower(
+        shape((rows, words), jnp.uint32), shape((rows,), jnp.int32),
+        shape((4,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    # the plane is read where it lies; out come the count, ROW_HITS
+    # (slot, shared) pairs and the masked vector
+    assert mem.argument_size_in_bytes < rows * words * 4 + rows * 4 + (1 << 16)
+    assert mem.output_size_in_bytes < rows * 4 + 2 * bp.ROW_HITS * 4 + (1 << 16)
+    assert compiled.as_text().count(" while(") == 1
+
+
 def test_the_in_place_aggregate_compiles_for_the_chip_at_the_cells_own_width(
     one_v5e_chip, tmp_path
 ):
