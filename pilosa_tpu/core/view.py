@@ -30,6 +30,7 @@ from pilosa_tpu.core.fragment import (
     FragmentRetiredError,
     write_epoch,
 )
+from pilosa_tpu.obs import trace
 from pilosa_tpu.obs.stats import NopStatsClient
 from pilosa_tpu.ops.bitplane import SLICE_WIDTH
 
@@ -143,7 +144,14 @@ class View:
         it 954 times each; once one of them was descheduled inside it
         the queue never cleared (a lock convoy: PERF.md, PR 27)."""
         hydrator = self.hydrator
-        with self._mu:
+        # a query takes this lock once a leaf: where it has to wait, the
+        # wait is time blocked on purpose (kind ``lock`` in its spans)
+        wait = None
+        if not self._mu.acquire(blocking=False):
+            wait = trace.blocked("lock").begin()
+            self._mu.acquire()
+            wait.stop()
+        try:
             out = [self._fragments.get(s) for s in slices]
             if hydrator is None:
                 return out
@@ -153,6 +161,10 @@ class View:
                     hydrator.touch(self, s)
                 elif s in self._cold:
                     cold.append(i)
+        finally:
+            self._mu.release()
+            if wait is not None:
+                wait.settle()
         for i in cold:  # store I/O outside the view lock, as fragment()
             out[i] = hydrator.hydrate(self, slices[i])
         return out

@@ -113,6 +113,11 @@ class PlanePool:
         # plane_nbytes x writes here, vs one upload with scatter on.
         self._restage_uploads = 0
         self._restage_bytes = 0
+        # Contended acquires of ``_mu`` by a request's touches and pin
+        # leases (``_wait_for_mu``), and the ms they waited: the process-wide
+        # lock eight request threads and the dispatcher meet at.
+        self._lock_waits = 0
+        self._lock_wait_ms = 0.0
         # 0 = auto (env -> detect); > 0 = explicit bytes.
         self._budget = int(budget_bytes or 0)
         self._detected: int | None = None
@@ -238,7 +243,36 @@ class PlanePool:
             self.stats.count("device.overBudget")
         self._publish(gauges)
 
+    def _wait_for_mu(self) -> "trace.blocked":
+        """The contended half of a request's ``touch_many`` or pin
+        lease (``if not self._mu.acquire(blocking=False)``): block for
+        ``_mu`` and time the wait, which is time the thread is blocked
+        on purpose (kind ``lock`` in its spans) and is counted here.
+        Under the lock it costs one clock read and two additions; the
+        caller settles the rest after its release (``trace.blocked``).
+        A free lock never comes here and reads no clock."""
+        wait = trace.blocked("lock", untraced=True).begin()
+        self._mu.acquire()
+        wait.stop()
+        self._lock_waits += 1
+        self._lock_wait_ms += wait.ms
+        return wait
+
+    def gauges(self) -> dict:
+        """``pool.lockWaits`` / ``pool.lockWaitMs`` for a ``/metrics``
+        scrape."""
+        with self._mu:
+            return {
+                "pool.lockWaits": self._lock_waits,
+                "pool.lockWaitMs": round(self._lock_wait_ms, 3),
+            }
+
     def touch(self, key: tuple) -> None:
+        # Not timed: a distinct Count's miss path comes here once a
+        # fragment (``Fragment.gather_slots``, 1,908 times a miss), and
+        # a lock taken that often by eight threads stands at the edge of
+        # a convoy (PERF.md, PR 38): nothing is added around it.  What
+        # waits for those touches shows at the O(1) sites below.
         with self._mu:
             if key in self._entries:
                 self._entries.move_to_end(key)
@@ -247,11 +281,18 @@ class PlanePool:
         """``touch`` each of ``keys`` under one hold of the lock: a
         query that reads hundreds of resident mirrors keeps them recent
         without taking the pool's lock once a mirror."""
-        with self._mu:
+        wait = None
+        if not self._mu.acquire(blocking=False):
+            wait = self._wait_for_mu()
+        try:
             entries = self._entries
             for key in keys:
                 if key in entries:
                     entries.move_to_end(key)
+        finally:
+            self._mu.release()
+            if wait is not None:
+                wait.settle()
 
     def resize(
         self, key: tuple, bytes_by_device: dict, info: dict | None = None
@@ -324,7 +365,10 @@ class PlanePool:
         whole drained batch — per-key lock round trips would scale the
         pool's hottest lock with batch occupancy."""
         held = []
-        with self._mu:
+        wait = None
+        if not self._mu.acquire(blocking=False):
+            wait = self._wait_for_mu()
+        try:
             for k in keys:
                 if k is None:
                     continue
@@ -336,10 +380,17 @@ class PlanePool:
                     for d, n in ent.bytes_by_device.items():
                         self._pinned[d] = self._pinned.get(d, 0) + n
                 held.append(k)
+        finally:
+            self._mu.release()
+            if wait is not None:
+                wait.settle()
         return held
 
     def unpin_many(self, keys) -> None:
-        with self._mu:
+        wait = None
+        if not self._mu.acquire(blocking=False):
+            wait = self._wait_for_mu()
+        try:
             for k in keys:
                 ent = self._entries.get(k)
                 if ent is None or ent.pins == 0:
@@ -350,6 +401,10 @@ class PlanePool:
                         self._pinned[d] = max(
                             0, self._pinned.get(d, 0) - n
                         )
+        finally:
+            self._mu.release()
+            if wait is not None:
+                wait.settle()
 
     class _PinLease:
         def __init__(self, pool: "PlanePool", keys):

@@ -69,7 +69,15 @@ the executor's per-query ``coalesce`` trace span carries the launch's
 occupancy and row stats (and through it the slow-query log's batch
 stats).  The dispatcher times each launch once as a ``launch`` span
 (trace.SharedSpan) and records it under every waiter's ``coalesce``
-span, so that span's self time is the queue wait alone.
+span, so that span's self time is the queue wait alone.  The two
+hand-overs that flank the launch are spans too, children of each
+waiter's ``launch``: ``handoff.queue`` (from ``submit`` to the start of
+the launch that serves the item; ``dispatcher`` says whether it stood
+idle at the submit) and ``handoff.wake`` (from just before
+``set_result`` to the return of the waiter's ``result()``:
+:func:`await_result`).  The dispatcher's own life is counted as
+``idle`` / ``launch`` / ``host`` seconds and ``cycles``
+(``exec.dispatcher.*`` at every scrape, :meth:`CoalesceScheduler.gauges`).
 """
 
 from __future__ import annotations
@@ -166,6 +174,25 @@ def consume_abandoned(stats):
     return _cb
 
 
+def await_result(fut: Future, timeout: float | None):
+    """``fut.result(timeout)`` for a future of :meth:`submit` /
+    :meth:`submit_fetch`, as the waiter's trace should see it: the wait
+    is time blocked on purpose (kind ``queue``), and the interval from
+    the dispatcher's ``set_result`` to this thread running again is the
+    ``handoff.wake`` child of the waiter's ``launch`` — with the result
+    ready, how long the waiter needed to get the GIL back."""
+    with trace.blocked("queue") as wait:
+        out = fut.result(timeout=timeout)
+    handoff = getattr(fut, "handoff", None)
+    if handoff is not None and wait.t1 is not None:
+        launch_span, resolved, waiters = handoff
+        launch_span.add_child(
+            "handoff.wake", trace.wall(resolved),
+            (wait.t1 - resolved) * 1e3, leaf=True, waiters=waiters,
+        )
+    return out
+
+
 @dataclass
 class _Item:
     batch: object
@@ -183,6 +210,20 @@ class _Item:
     # and the ``launch`` span's parent ride the item.
     trace_id: str = ""
     span: "trace.Span | None" = None
+    # The hand-over's stamps: when the item was queued (monotonic),
+    # whether the dispatcher stood in ``_cv.wait()`` at that moment, and
+    # this waiter's copy of the ``launch`` span that served it.
+    submitted: float = 0.0
+    dispatcher_idle: bool = False
+    launch_span: "trace.Span | None" = None
+
+    def resolve(self, value, waiters: int) -> None:
+        """``set_result``, stamped just before for ``handoff.wake``."""
+        if self.launch_span is not None:
+            self.future.handoff = (
+                self.launch_span, time.monotonic(), waiters
+            )
+        self.future.set_result(value)
 
 
 def _placement(batch) -> tuple:
@@ -261,6 +302,17 @@ class CoalesceScheduler:
         self._fuse_fallbacks = 0
         self._fetch_launches = 0
         self._fetch_arrays = 0
+        # The dispatcher's life, in seconds by what it was doing (plain
+        # attributes the loop alone adds to): idle in ``_cv.wait()``,
+        # inside a ``launch`` span, and host — all the rest of a cycle.
+        self._idle_s = 0.0
+        self._launch_s = 0.0
+        self._host_s = 0.0
+        self._cycles = 0
+        # monotonic time the dispatcher went idle, None while it works;
+        # when its loop began and ended
+        self._idle_since: float | None = None
+        self._life: tuple = (None, None)
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="exec-coalesce"
         )
@@ -289,14 +341,7 @@ class CoalesceScheduler:
             trace_id=perf_mod.current_trace_id(),
             span=trace.current_span(),
         )
-        with self._cv:
-            if self._closed:
-                raise CoalesceClosed("coalescer closed")
-            q = self._queues.get(key)
-            if q is None:
-                q = self._queues[key] = deque()
-            q.append(item)
-            self._cv.notify()
+        self._enqueue(key, item)
         return fut
 
     def submit_fetch(self, arrays) -> Future:
@@ -314,15 +359,20 @@ class CoalesceScheduler:
             trace_id=perf_mod.current_trace_id(),
             span=trace.current_span(),
         )
+        self._enqueue(_FETCH_KEY, item)
+        return fut
+
+    def _enqueue(self, key, item: _Item) -> None:
+        item.submitted = time.monotonic()
         with self._cv:
             if self._closed:
                 raise CoalesceClosed("coalescer closed")
-            q = self._queues.get(_FETCH_KEY)
+            item.dispatcher_idle = self._idle_since is not None
+            q = self._queues.get(key)
             if q is None:
-                q = self._queues[_FETCH_KEY] = deque()
+                q = self._queues[key] = deque()
             q.append(item)
             self._cv.notify()
-        return fut
 
     def close(self) -> None:
         with self._cv:
@@ -366,7 +416,37 @@ class CoalesceScheduler:
                 ),
                 "fetch_launches": self._fetch_launches,
                 "fetch_arrays": self._fetch_arrays,
+                "dispatcher": self._dispatcher_locked(),
             }
+
+    def _dispatcher_locked(self) -> dict:
+        """The dispatcher's life so far in ms (callers hold ``_mu``).
+        A wait in progress counts up to now; the cycle in progress
+        lands when it ends."""
+        idle = self._idle_s
+        if self._idle_since is not None:
+            idle += time.monotonic() - self._idle_since
+        born, died = self._life
+        life = 0.0 if born is None else (died or time.monotonic()) - born
+        return {
+            "idle_ms": round(idle * 1e3, 3),
+            "launch_ms": round(self._launch_s * 1e3, 3),
+            "host_ms": round(self._host_s * 1e3, 3),
+            "cycles": self._cycles,
+            "life_ms": round(life * 1e3, 3),
+        }
+
+    def gauges(self) -> dict:
+        """``exec.dispatcher.*`` for a ``/metrics`` scrape: a dispatcher
+        that is never idle with a low launch share is host-bound."""
+        with self._mu:
+            d = self._dispatcher_locked()
+        return {
+            "exec.dispatcher.idleMs": d["idle_ms"],
+            "exec.dispatcher.launchMs": d["launch_ms"],
+            "exec.dispatcher.hostMs": d["host_ms"],
+            "exec.dispatcher.cycles": d["cycles"],
+        }
 
     # ------------------------------------------------------------------
     # dispatcher
@@ -386,11 +466,24 @@ class CoalesceScheduler:
             self._queues.move_to_end(key)
 
     def _loop(self) -> None:
+        # ``mark``: up to where the thread's life is accounted for.
+        mark = time.monotonic()
+        self._life = (mark, None)
         while True:
             with self._cv:
-                while not self._closed and not self._queues:
-                    self._cv.wait()
+                if not self._closed and not self._queues:
+                    now = time.monotonic()
+                    self._host_s += now - mark
+                    self._idle_since = now
+                    while not self._closed and not self._queues:
+                        self._cv.wait()
+                    mark = time.monotonic()
+                    self._idle_s += mark - now
+                    self._idle_since = None
                 if self._closed:
+                    now = time.monotonic()
+                    self._host_s += now - mark
+                    self._life = (self._life[0], now)
                     return
                 key = next(iter(self._queues))
                 items: list = []
@@ -431,6 +524,7 @@ class CoalesceScheduler:
                         self._drain_locked(k2, its)
                         if its:
                             extra.append((k2, its))
+            launched = self._launch_s
             try:
                 # The launch (dispatch + fetch) runs HERE, on the
                 # dispatcher thread — while it is in flight, new
@@ -443,6 +537,12 @@ class CoalesceScheduler:
                 for it in items + [it for _, its in extra for it in its]:
                     if not it.future.done():
                         it.future.set_exception(exc)
+            # The cycle's launch spans have added themselves to
+            # ``_launch_s`` (_publish_launch); the rest of it is host.
+            now = time.monotonic()
+            self._host_s += (now - mark) - (self._launch_s - launched)
+            self._cycles += 1
+            mark = now
 
     def _run_collective(self, fn):
         """One collective-bearing dispatch+fetch: watchdogged through
@@ -491,15 +591,26 @@ class CoalesceScheduler:
             site=site, queries=len(items), rows=rows,
         )
 
-    @staticmethod
-    def _publish_launch(ls, items: list, dispatch_ms: float) -> None:
+    def _publish_launch(self, ls, items: list, dispatch_ms: float) -> None:
         """Record the finished launch under every waiter's span —
         BEFORE the futures resolve: a waiter that has its result may
-        finish its trace, and a span for a final trace is dropped."""
+        finish its trace, and a span for a final trace is dropped —
+        and under each waiter's copy its ``handoff.queue``: from its
+        ``submit`` to the start of this launch."""
+        self._launch_s += ls.duration_ms / 1e3
         ls.annotate(
             dispatch_ms=round(dispatch_ms, 3), first_call=bool(ls.children)
         )
-        ls.publish(it.span for it in items)
+        started = ls.opened
+        for it, sp in zip(items, ls.publish([it.span for it in items])):
+            if sp is None:
+                continue
+            it.launch_span = sp
+            sp.add_child(
+                "handoff.queue", trace.wall(it.submitted),
+                (started - it.submitted) * 1e3, leaf=True,
+                dispatcher="idle" if it.dispatcher_idle else "busy",
+            )
 
     def _launch(self, key, items: list, extra=()) -> None:
         if key == _FETCH_KEY:
@@ -569,8 +680,7 @@ class CoalesceScheduler:
             mesh = None
         pins = {k for it in items for k in it.pin_keys}
         site = "collective" if mesh is not None else "total"
-        t0 = time.monotonic()
-        t_disp = [t0]  # set when the async dispatch returns (pre-fetch)
+        t_disp = [0.0]  # set when the async dispatch returns (pre-fetch)
         with self._launch_span(
             site, items, int(batch.shape[0])
         ) as ls, device_mod.pool().pinned(*pins):
@@ -593,9 +703,8 @@ class CoalesceScheduler:
                 out = plan.compiled_total_count(expr, mesh)(batch)
                 t_disp[0] = time.monotonic()
                 res = np.asarray(jax.device_get(out))
-        t1 = time.monotonic()
-        launch_ms = (t1 - t0) * 1e3
-        self._publish_launch(ls, items, (t_disp[0] - t0) * 1e3)
+        dispatch_ms = (t_disp[0] - ls.opened) * 1e3
+        self._publish_launch(ls, items, dispatch_ms)
         if perf_mod.enabled():
             perf_mod.record_launch(
                 site,
@@ -605,8 +714,8 @@ class CoalesceScheduler:
                 n_bytes=perf_mod.plane_bytes(
                     int(batch.shape[0]), int(np.prod(batch.shape[1:]))
                 ),
-                dispatch_ms=(t_disp[0] - t0) * 1e3,
-                total_ms=launch_ms,
+                dispatch_ms=dispatch_ms,
+                total_ms=ls.duration_ms,
                 trace_id=items[0].trace_id,
             )
         with self._mu:
@@ -626,10 +735,9 @@ class CoalesceScheduler:
             "batch_segments": 1,
             "batch_rows": int(batch.shape[0]),
             "pad_rows": 0,
-            "launch_ms": round(launch_ms, 3),
         }
         for it in items:
-            it.future.set_result((res, info))
+            it.resolve((res, info), len(items))
 
     def _launch_concat(self, expr, reduce, items: list) -> None:
         # Identity dedup: one segment per DISTINCT batch array.
@@ -686,16 +794,14 @@ class CoalesceScheduler:
                 parts.append(self._pad_zeros(pad, segs[0]))
             dev_in = jnp.concatenate(parts, axis=0)
         pins = {k for it in items for k in it.pin_keys}
-        t0 = time.monotonic()
         with self._launch_span(
             "coalesce", items, total
         ) as ls, device_mod.pool().pinned(*pins):
             out = plan.compiled_batched(expr, reduce)(dev_in)
             t_disp = time.monotonic()
             res = np.asarray(jax.device_get(out))
-        t1 = time.monotonic()
-        launch_ms = (t1 - t0) * 1e3
-        self._publish_launch(ls, items, (t_disp - t0) * 1e3)
+        dispatch_ms = (t_disp - ls.opened) * 1e3
+        self._publish_launch(ls, items, dispatch_ms)
         # Logical bytes are the PRE-pad rows: pad rows are bucketing
         # overhead, not useful plane traffic.
         if perf_mod.enabled():
@@ -707,8 +813,8 @@ class CoalesceScheduler:
                 n_bytes=perf_mod.plane_bytes(
                     total, int(np.prod(segs[0].shape[1:]))
                 ),
-                dispatch_ms=(t_disp - t0) * 1e3,
-                total_ms=launch_ms,
+                dispatch_ms=dispatch_ms,
+                total_ms=ls.duration_ms,
                 trace_id=items[0].trace_id,
             )
         with self._mu:
@@ -730,14 +836,13 @@ class CoalesceScheduler:
             "batch_segments": len(segs),
             "batch_rows": total,
             "pad_rows": pad,
-            "launch_ms": round(launch_ms, 3),
         }
         start = 0
         for rows, sub in zip(n_rows, seg_items):
             seg_res = res[start : start + rows]
             start += rows
             for it in sub:
-                it.future.set_result((seg_res, info))
+                it.resolve((seg_res, info), len(items))
 
     # ------------------------------------------------------------------
     # multi-query fusion (plane-major interpreter launches)
@@ -938,8 +1043,7 @@ class CoalesceScheduler:
                 sharded = False
             site = "collective" if (reduce == "total" and sharded) else "interp"
             fused_items = [it for it, _reg in fused]
-            t0 = time.monotonic()
-            t_disp = [t0]
+            t_disp = [0.0]
             with self._launch_span(
                 site, fused_items, n_rows * l_union
             ) as ls, device_mod.pool().pinned(*pins):
@@ -960,9 +1064,8 @@ class CoalesceScheduler:
                     out = plan.interp_exec(reduce, combined, prog, out_idx)
                     t_disp[0] = time.monotonic()
                     res = np.asarray(jax.device_get(out))
-            t1 = time.monotonic()
-            launch_ms = (t1 - t0) * 1e3
-            self._publish_launch(ls, fused_items, (t_disp[0] - t0) * 1e3)
+            dispatch_ms = (t_disp[0] - ls.opened) * 1e3
+            self._publish_launch(ls, fused_items, dispatch_ms)
             # Logical bytes: the deduped union leaf set (streamed once
             # per pass), pad leaves excluded.
             if perf_mod.enabled():
@@ -974,8 +1077,8 @@ class CoalesceScheduler:
                     n_bytes=perf_mod.plane_bytes(
                         n_rows * l_union, int(combined.shape[-1])
                     ),
-                    dispatch_ms=(t_disp[0] - t0) * 1e3,
-                    total_ms=launch_ms,
+                    dispatch_ms=dispatch_ms,
+                    total_ms=ls.duration_ms,
                     trace_id=fused[0][0].trace_id,
                 )
             with self._mu:
@@ -1014,10 +1117,9 @@ class CoalesceScheduler:
                 "leaf_rows": l_union,
                 "shared_leaves": l_tot - l_union,
                 "pad_leaves": l_bucket - l_union,
-                "launch_ms": round(launch_ms, 3),
-            }
+                }
             for it, reg in fused:
-                it.future.set_result((res[:, pos_of_reg[reg]], info))
+                it.resolve((res[:, pos_of_reg[reg]], info), len(fused))
 
         self._fallback_by_key(reduce, fallback)
 
@@ -1063,10 +1165,8 @@ class CoalesceScheduler:
             arrs = it.batch
             spans.append((len(arrays), len(arrs)))
             arrays.extend(arrs)
-        t0 = time.monotonic()
         with self._launch_span("fetch", items, 0) as ls:
             fetched = jax.device_get(arrays)
-        fetch_ms = (time.monotonic() - t0) * 1e3
         self._publish_launch(ls, items, 0.0)
         if perf_mod.enabled():
             perf_mod.record_launch(
@@ -1074,7 +1174,7 @@ class CoalesceScheduler:
                 reduce="fetch",
                 queries=len(items),
                 n_bytes=sum(int(getattr(a, "nbytes", 0) or 0) for a in arrays),
-                total_ms=fetch_ms,
+                total_ms=ls.duration_ms,
                 trace_id=items[0].trace_id,
             )
         with self._mu:
@@ -1083,14 +1183,9 @@ class CoalesceScheduler:
             n = self._fetch_launches
         self.stats.count("exec.interp.fetchLaunches")
         self.stats.count("exec.interp.fetchedArrays", len(arrays))
-        info = {
-            "fetch_launch": n,
-            "fetch_items": len(items),
-            "fetch_arrays": len(arrays),
-            "fetch_ms": round(fetch_ms, 3),
-        }
+        info = {"fetch_launch": n}
         for it, (lo, cnt) in zip(items, spans):
-            it.future.set_result((fetched[lo : lo + cnt], info))
+            it.resolve((fetched[lo : lo + cnt], info), len(items))
 
     def _pad_zeros(self, pad: int, like):
         """Cached all-zero pad rows on ``like``'s device — the pad set

@@ -65,6 +65,14 @@ from pilosa_tpu.testing import faults
 from pilosa_tpu.ops import bitplane as bp
 from pilosa_tpu.pql.parser import Call, Query
 
+
+def _device_get(x):
+    """``jax.device_get`` on a request's own thread (a launch that does
+    not ride the coalescer): time blocked on purpose, kind ``device``."""
+    with trace.blocked("device"):
+        return jax.device_get(x)
+
+
 # Absent-row stand-in for anchored count leaf batches: an all-sentinel
 # sparse payload at the bucket floor (membership False on every real
 # position).  Read-only module constant.
@@ -1695,7 +1703,7 @@ class Executor:
             if dl is not None:
                 timeout = dl.clamp(timeout)
             try:
-                res, info = fut.result(timeout=timeout)
+                res, info = coalesce_mod.await_result(fut, timeout)
             except FuturesTimeoutError:
                 sp.annotate(deadline="expired")
                 # The detached waiter will never call result() again,
@@ -1781,7 +1789,7 @@ class Executor:
                         ent["batch"]
                     )
                     t_disp = time.monotonic()
-                    res = jax.device_get(out_dev)
+                    res = _device_get(out_dev)
                 else:
                     res = plan.compiled_batched(ent["expr"], reduce)(
                         ent["batch"]
@@ -1793,7 +1801,7 @@ class Executor:
                         # the WHOLE batch in ONE transfer — per-slice lazy
                         # slices would each pay a device round trip when
                         # coerced.
-                        res = np.asarray(res)
+                        res = np.asarray(_device_get(res))
                 t1 = time.monotonic()
                 self._record_direct_launch(ent, reduce, t0, t_disp, t1)
                 return res
@@ -2063,7 +2071,7 @@ class Executor:
             [jnp.asarray(a) for a in payload_np],
         )
         t_disp = time.monotonic()
-        res = jax.device_get(out)
+        res = _device_get(out)
         t1 = time.monotonic()
         if perf_mod.enabled():
             perf_mod.record_launch(
@@ -2191,7 +2199,12 @@ class Executor:
                             return res
 
                         try:
-                            limbs = health.run_collective(_collective_body)
+                            # the body may run on the watchdog's
+                            # thread: this one waits for it
+                            with trace.blocked("device"):
+                                limbs = health.run_collective(
+                                    _collective_body
+                                )
                             return plan.recombine_count_limbs(limbs)
                         except (
                             health_mod.LaunchWatchdogTimeout,
@@ -2203,7 +2216,7 @@ class Executor:
                         ent["batch"]
                     )
                     t_disp = time.monotonic()
-                    res = jax.device_get(out)
+                    res = _device_get(out)
                     self._record_direct_launch(
                         ent, "count", t0, t_disp, time.monotonic()
                     )
@@ -2220,7 +2233,7 @@ class Executor:
                         ent["batch"]
                     )
                     t_disp = time.monotonic()
-                    limbs = jax.device_get(limbs)
+                    limbs = _device_get(limbs)
                     self._record_direct_launch(
                         ent, "total", t0, t_disp,
                         time.monotonic(), site="total",
@@ -2229,7 +2242,7 @@ class Executor:
                 t0 = time.monotonic()
                 res = plan.compiled_batched(ent["expr"], "count")(ent["batch"])
                 t_disp = time.monotonic()
-                res = jax.device_get(res)
+                res = _device_get(res)
                 self._record_direct_launch(
                     ent, "count", t0, t_disp, time.monotonic()
                 )
@@ -2422,7 +2435,7 @@ class Executor:
             ), self.tracer.span("exec.device", reduce="agg"):
                 self._fault_check_launch("direct")
                 return np.asarray(
-                    jax.device_get(
+                    _device_get(
                         plan.compiled_batched(ent["expr"], "agg")(ent["batch"])
                     )
                 )
@@ -3017,7 +3030,7 @@ class Executor:
                 if dl is not None:
                     timeout = dl.clamp(timeout)
                 try:
-                    res, info = fut.result(timeout=timeout)
+                    res, info = coalesce_mod.await_result(fut, timeout)
                 except FuturesTimeoutError:
                     sp.annotate(deadline="expired")
                     # Same abandoned-waiter contract as _coalesce_eval:
@@ -3033,7 +3046,7 @@ class Executor:
                     raise
                 sp.annotate(**info)
                 return res
-        return jax.device_get(arrays)
+        return _device_get(arrays)
 
     def _topn_src_leaf(self, index: str, c: Call):
         """``(frame, view, row id)`` of a TopN src that is ONE plain
@@ -3609,7 +3622,8 @@ class Executor:
                 # A leader is scoring right now; its fetched vector
                 # arrives with the event.  A failed leader leaves
                 # scores unset — fall through and score directly.
-                ev.wait(timeout=coalesce_mod.RESULT_TIMEOUT_S)
+                with trace.blocked("queue"):
+                    ev.wait(timeout=coalesce_mod.RESULT_TIMEOUT_S)
                 with self._batch_mu:
                     scores = ent.get("scores")
             if scores is None:
@@ -4034,8 +4048,9 @@ class Executor:
             self._pool.submit(self._exec_remote, n, index, q, None, opt)
             for n in others
         ]
-        for fut in futures:
-            fut.result()
+        with trace.blocked("map"):
+            for fut in futures:
+                fut.result()
 
     # ------------------------------------------------------------------
     # map/reduce over the cluster (reference: executor.go:1131-1283)
@@ -4200,9 +4215,13 @@ class Executor:
                     raise resilience.DeadlineExceeded(
                         "deadline exceeded awaiting map responses"
                     )
-            done, _ = wait(
-                list(inflight), timeout=timeout, return_when=FIRST_COMPLETED
-            )
+            # the request thread stands still for its own mappers, on
+            # this node's pool or remote: blocked on purpose, not the GIL
+            with trace.blocked("map"):
+                done, _ = wait(
+                    list(inflight), timeout=timeout,
+                    return_when=FIRST_COMPLETED,
+                )
             if not done:
                 raise resilience.DeadlineExceeded(
                     "deadline exceeded awaiting map responses"

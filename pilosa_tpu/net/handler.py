@@ -1949,6 +1949,7 @@ class Handler:
                 snap = {}
         self._inject_program_cache_gauges(snap)
         self._inject_device_memory_gauges(snap)
+        self._inject_host_wait_gauges(snap)
         if self.admission is not None:
             # Scrape-time admission gauges (active/queued/concurrency/
             # EWMA per class) — like the program-cache gauges, they
@@ -2046,6 +2047,24 @@ class Handler:
         except Exception:  # noqa: BLE001 — stats must not fail the scrape
             pass
 
+    def _inject_host_wait_gauges(self, snap: dict) -> None:
+        """Scrape-time account of where the host waits: the coalescer's
+        dispatcher by what it was doing (``exec.dispatcher.idleMs`` /
+        ``launchMs`` / ``hostMs`` / ``cycles``, which add up to its
+        life) and the residency pool's contended lock
+        (``pool.lockWaits`` / ``pool.lockWaitMs``).  Plain attributes
+        the hot path adds to, read here."""
+        try:
+            gauges = snap.setdefault("gauges", {})
+            co = getattr(self.executor, "coalescer", None)
+            if co is not None and hasattr(co, "gauges"):
+                gauges.update(co.gauges())
+            from pilosa_tpu import device as device_mod
+
+            gauges.update(device_mod.pool().gauges())
+        except Exception:  # noqa: BLE001 — stats must not fail the scrape
+            pass
+
     def handle_get_perf(self, req: Request) -> Response:
         """The launch-telemetry table (obs/perf.py): per-site
         launches, logical bytes streamed, host-clock GB/s, p50/p99
@@ -2065,12 +2084,18 @@ class Handler:
         wedge-diagnosis companion to the PR-15 launch watchdog: when a
         device call hangs, this shows WHERE every thread is stuck
         without attaching a debugger.  (Alias of the pprof "goroutine"
-        dump under a first-class route.)"""
+        dump under a first-class route.)  Each thread's line carries
+        its CPU seconds so far, read only here: a thread that opens no
+        span and holds the GIL (a rank-cache re-sort, the WAL, a
+        compile) shows between two calls."""
         frames = sys._current_frames()
         out = io.StringIO()
         out.write(f"{len(frames)} threads\n\n")
         for t in threading.enumerate():
-            out.write(f"thread {t.name} id={t.ident} (daemon={t.daemon})\n")
+            out.write(
+                f"thread {t.name} id={t.ident} (daemon={t.daemon})"
+                f"{_thread_cpu(t.ident)}\n"
+            )
             fr = frames.get(t.ident)
             if fr is not None:
                 out.write("".join(traceback.format_stack(fr)))
@@ -2217,6 +2242,16 @@ class Handler:
                 self.broadcaster.send_sync(msg)
             except Exception as e:  # noqa: BLE001 — broadcast is best-effort
                 self.logger(f"broadcast error: {e}")
+
+
+def _thread_cpu(ident) -> str:
+    """`` cpu=<seconds>s`` of the thread ``ident`` so far, empty where
+    the platform has no per-thread CPU clock or the thread has gone."""
+    try:
+        clock = time.pthread_getcpuclockid(ident)
+        return f" cpu={time.clock_gettime(clock):.3f}s"
+    except (AttributeError, OSError, TypeError):
+        return ""
 
 
 def _jax_profiler():

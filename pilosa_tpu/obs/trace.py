@@ -5,9 +5,14 @@ query gets a trace; the last ``capacity`` finished traces are retained in
 a ring buffer served as JSON by ``GET /debug/traces``.
 
 A trace is a tree of :class:`Span` objects sharing one ``trace_id``.
-Spans time with ``time.monotonic()`` (and the opening thread's CPU time
-with ``time.thread_time()``: wall less CPU is time off the processor —
-the GIL, a lock, a blocking fetch) and link parent→child two ways:
+Spans time with ``time.monotonic()`` and say where their wall time went
+on the opening thread: ``cpu_ms`` on the processor
+(``time.thread_time()``), ``blocked_ms`` blocked on purpose (a wait the
+code chose, by kind: :class:`blocked`), and what is left
+is a thread that wanted to run and did not — the GIL, the OS run queue,
+a blocking call nobody wrapped.  Their wall-clock ``start`` is the
+monotonic stamp moved by one process-wide offset (:func:`wall`).  They
+link parent→child two ways:
 
 * in-process via a ``contextvars.ContextVar`` holding the active span —
   crossing threads works because the executor's pool captures the
@@ -38,6 +43,7 @@ from __future__ import annotations
 
 import contextvars
 import json
+import random
 import threading
 import time
 import uuid
@@ -62,13 +68,119 @@ _current_span: "contextvars.ContextVar[Span | None]" = contextvars.ContextVar(
 # live, else None: outside a session a span pays one global read.
 _annotation = None
 
+_randbits = random.getrandbits
+
+# The one clock every interval here is read from (tests patch it).
+_now = time.monotonic
+
+# Wall clock less monotonic clock: the one place a monotonic stamp
+# becomes the wall-clock ``start`` the spans and the device trace share.
+# Taken at import and again as a /debug/profile session opens, so the
+# spans of a profile stand on its clock however long the process has
+# lived (the wall clock is disciplined, the monotonic one is not).
+_wall_offset = time.time() - _now()
+
+
+def wall(t_mono: float) -> float:
+    """The wall-clock time of the monotonic stamp ``t_mono``."""
+    return t_mono + _wall_offset
+
 
 def set_profiling(annotation) -> None:
     """The /debug/profile handler brackets its session with this, under
     its single-flight lock: the profiler's ``TraceAnnotation`` class at
     the start, None at the stop."""
-    global _annotation
+    global _annotation, _wall_offset
+    if annotation is not None:
+        _wall_offset = time.time() - _now()
     _annotation = annotation
+
+
+# ---------------------------------------------------------------------------
+# time blocked on purpose
+# ---------------------------------------------------------------------------
+
+# Why a thread stood still because the code told it to: ``queue`` (a
+# coalescer future, a single-flight leader), ``map`` (the request
+# thread's wait for its own mappers), ``device`` (a device_get a request
+# thread makes itself), ``lock`` (a contended process- or view-wide lock).
+BLOCKED_KINDS = ("queue", "map", "device", "lock")
+_SLOT = {k: i for i, k in enumerate(BLOCKED_KINDS, 1)}
+_ZERO = (0.0,) * (1 + len(BLOCKED_KINDS))
+
+
+class _Blocked(threading.local):
+    # This thread's running total of ms blocked, ``(sum, *by kind)``:
+    # a tuple replaced at every addition, so a span notes it at open and
+    # at close by reference — no copy, and ``is`` says nothing was added.
+    total = _ZERO
+
+
+_blocked = _Blocked()
+
+
+def _add_blocked(kind: str, ms: float) -> None:
+    b = list(_blocked.total)
+    b[0] += ms
+    b[_SLOT[kind]] += ms
+    _blocked.total = tuple(b)
+
+
+class blocked:
+    """``with trace.blocked("queue"): fut.result()`` — the block is a
+    wait the code chose, and its wall time joins the thread's total of
+    that kind, which every span open on the thread reads at its close
+    (``blocked_ms``).  ``t1`` is the monotonic time of the exit and
+    ``ms`` the wait.  Outside any span (a process without a tracer)
+    nothing is timed, unless the caller counts the wait itself
+    (``untraced``).
+
+    A lock is wrapped only where it is process- or view-wide, and only
+    the acquire that has to wait; and what the wait costs is paid
+    OUTSIDE the lock — ``begin`` before the blocking acquire, ``stop``
+    (one clock read) once it is held, ``settle`` after the release::
+
+        wait = None
+        if not mu.acquire(blocking=False):
+            wait = trace.blocked("lock").begin()
+            mu.acquire()
+            wait.stop()
+        try: ...
+        finally:
+            mu.release()
+            if wait is not None:
+                wait.settle()
+
+    Every microsecond added under a lock that eight threads meet at
+    makes the next acquire likelier to wait too (PERF.md, PR 38)."""
+
+    __slots__ = ("kind", "untraced", "t0", "t1", "ms")
+
+    def __init__(self, kind: str, untraced: bool = False):
+        self.kind = kind
+        self.untraced = untraced
+        self.t0 = self.t1 = None
+        self.ms = 0.0
+
+    def begin(self) -> "blocked":
+        if self.untraced or _current_span.get() is not None:
+            self.t0 = _now()
+        return self
+
+    def stop(self) -> None:
+        if self.t0 is not None and self.t1 is None:
+            self.t1 = _now()
+            self.ms = (self.t1 - self.t0) * 1000.0
+
+    def settle(self) -> None:
+        if self.t1 is not None:
+            _add_blocked(self.kind, self.ms)
+
+    __enter__ = begin
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.stop()
+        self.settle()
 
 
 def current_span() -> "Span | None":
@@ -83,7 +195,23 @@ def new_trace_id() -> str:
 
 
 def new_span_id() -> str:
-    return uuid.uuid4().hex[:16]  # 8 bytes hex
+    # 8 bytes hex.  ``uuid4`` is a third of a span's cost (a
+    # ``getrandom`` system call, which RELEASES THE GIL), and is kept on
+    # purpose: drawn from the Mersenne Twister instead, the cells of
+    # cached and light requests gained 8-11 % and the two cells whose
+    # requests sweep 1,908 fragment locks under eight threads lost 60 %
+    # (PERF.md, PR 38).  A span's open is where this server's request
+    # threads hand the GIL over while they hold no lock; without it
+    # every switch is a forced one, a third of them inside a lock.
+    return uuid.uuid4().hex[:16]
+
+
+def _leaf_span_id() -> str:
+    # For a recorded interval that is nobody's parent (a hand-over):
+    # 8 bytes hex with no system call, so that the two spans PR 38
+    # added add no GIL hand-over of their own — on the dispatcher
+    # between a launch's end and its ``set_result``, least of all.
+    return "%016x" % _randbits(64)
 
 
 class Span:
@@ -101,11 +229,13 @@ class Span:
         "span_id",
         "parent_id",
         "start",
-        "_t0",
+        "opened",
         "_cpu0",
+        "_blocked0",
         "_thread",
         "duration_ms",
         "cpu_ms",
+        "blocked_ms",
         "tags",
         "_token",
         "_anno",
@@ -118,14 +248,18 @@ class Span:
         self.trace_id = trace_id
         self.span_id = new_span_id()
         self.parent_id = parent_id
-        self.start = time.time()
-        self._t0 = time.monotonic()
+        # monotonic; ``start`` is the same moment on the wall clock
+        self.opened = t0 = _now()
+        self.start = t0 + _wall_offset
         self._cpu0 = time.thread_time()
+        self._blocked0 = _blocked.total
         self._thread = threading.get_ident()
         self.duration_ms: float | None = None
-        # CPU time of the opening thread over the span; None when the
-        # span finished on (or was recorded for) another thread.
+        # CPU time of the opening thread over the span, and the time it
+        # stood blocked on purpose (a parent's includes its children's);
+        # None when the span finished on another thread.
         self.cpu_ms: float | None = None
+        self.blocked_ms: float | None = None
         self.tags = dict(tags) if tags else {}
         self._token = None
         self._anno = None
@@ -142,9 +276,19 @@ class Span:
         _current_span.reset(token)
 
     def _stop_clocks(self) -> None:
-        self.duration_ms = (time.monotonic() - self._t0) * 1000.0
+        self.duration_ms = (_now() - self.opened) * 1000.0
         if threading.get_ident() == self._thread:
             self.cpu_ms = (time.thread_time() - self._cpu0) * 1000.0
+            b0, b1 = self._blocked0, _blocked.total
+            if b1 is b0:
+                self.blocked_ms = 0.0
+            else:
+                self.blocked_ms = b1[0] - b0[0]
+                self.tags["blocked"] = {
+                    k: round(b1[i] - b0[i], 3)
+                    for k, i in _SLOT.items()
+                    if b1[i] != b0[i]
+                }
 
     def finish(self) -> None:
         if self.duration_ms is None:
@@ -186,6 +330,9 @@ class Span:
             "cpu_ms": round(self.cpu_ms, 3)
             if self.cpu_ms is not None
             else None,
+            "blocked_ms": round(self.blocked_ms, 3)
+            if self.blocked_ms is not None
+            else None,
             "tags": self.tags,
         }
 
@@ -196,7 +343,8 @@ class SharedSpan(Span):
     is that thread's current span and belongs to no trace; children
     recorded under it (a ``compile``) are kept, and :meth:`publish`
     records it and them under each waiter's span, so every waiter's
-    trace shows the one launch at the same wall-clock ``start``."""
+    trace shows the one launch at the same wall-clock ``start``, with
+    the ``cpu_ms`` and ``blocked_ms`` of the thread that ran it."""
 
     __slots__ = ("children",)
 
@@ -213,15 +361,23 @@ class SharedSpan(Span):
     def add_child(self, name: str, start: float, duration_ms: float, **tags):
         self.children.append((name, start, duration_ms, tags))
 
-    def publish(self, parents) -> None:
+    def publish(self, parents) -> list:
+        """The span recorded under each of ``parents``, in their order
+        (None for a parent that is None): a caller hangs what belongs
+        to one waiter alone under that waiter's copy."""
+        out = []
         for parent in parents:
             if parent is None:
+                out.append(None)
                 continue
             sp = parent.add_child(
-                self.name, self.start, self.duration_ms, **self.tags
+                self.name, self.start, self.duration_ms,
+                cpu_ms=self.cpu_ms, blocked_ms=self.blocked_ms, **self.tags
             )
             for name, start, ms, tags in self.children:
                 sp.add_child(name, start, ms, **tags)
+            out.append(sp)
+        return out
 
 
 class Tracer:
@@ -265,16 +421,30 @@ class Tracer:
 
     def add_span(
         self, parent: Span, name: str, start: float, duration_ms: float,
-        **tags,
+        cpu_ms: float | None = None, blocked_ms: float | None = None,
+        leaf: bool = False, **tags,
     ) -> Span:
         """Record a FINISHED child of ``parent`` with an explicit
         wall-clock ``start`` and duration — work another thread did for
-        this trace (the coalescer's dispatcher has no current span).
-        It carries no ``cpu_ms``; once the trace is final it is dropped
-        like any late span."""
-        span = Span(self, name, parent.trace_id, parent.span_id, tags)
+        this trace (the coalescer's dispatcher has no current span), or
+        an interval between two threads (a hand-over).  ``cpu_ms`` and
+        ``blocked_ms`` are that thread's where one thread did all of it,
+        else None; ``leaf`` says nothing will be recorded under it (its
+        id is then drawn without a system call: ``_leaf_span_id``); once
+        the trace is final it is dropped like any late span."""
+        # No clock of this thread belongs to the interval: the fields a
+        # record and a parent need, and no read of any.
+        span = Span.__new__(Span)
+        span.tracer = self
+        span.name = name
+        span.trace_id = parent.trace_id
+        span.span_id = _leaf_span_id() if leaf else new_span_id()
+        span.parent_id = parent.span_id
         span.start = start
         span.duration_ms = duration_ms
+        span.cpu_ms = cpu_ms
+        span.blocked_ms = blocked_ms
+        span.tags = tags
         self._record(span)
         return span
 

@@ -142,7 +142,10 @@ def test_two_queries_on_one_launch_each_get_the_same_launch_span(rng):
             assert launch["tags"]["queries"] == 2
             assert launch["tags"]["first_call"] is True
             assert launch["tags"]["dispatch_ms"] <= launch["duration_ms"]
-            assert launch["cpu_ms"] is None
+            # the dispatcher opened and closed it on one thread: its CPU
+            # time and its blocked time are carried into every copy
+            assert 0 <= launch["cpu_ms"] <= launch["duration_ms"] + 1
+            assert launch["blocked_ms"] is not None
             # the launch lies inside the wait for it
             assert launch["duration_ms"] <= by_name["coalesce"]["duration_ms"]
             compile_ = by_name["compile"]
@@ -234,6 +237,15 @@ def _children(t, name):
     return [s for s in t["spans"] if s["parent_id"] == parent["span_id"]]
 
 
+def _tags(span):
+    """A span's tags less ``blocked``, which says how long its thread
+    stood waiting on purpose and is no tag of the work."""
+    return {k: v for k, v in span["tags"].items() if k != "blocked"}
+
+
+HANDOFFS = ["handoff.queue", "handoff.wake"]
+
+
 INTERSECT = 'Count(Intersect(Bitmap(frame="f", rowID=1), Bitmap(frame="f", rowID=2)))'
 UNION = 'Count(Union(Bitmap(frame="f", rowID=1), Bitmap(frame="f", rowID=2)))'
 
@@ -310,7 +322,8 @@ def test_a_miss_over_cold_planes_fills_on_the_host(server, monkeypatch):
 
 MISS_SPANS = sorted([
     "query", "parse", "admission", "execute", "call.Count", "map.local",
-    "anchored.prepass", "plan", *PLAN_STAGES, "coalesce", "launch"])
+    "anchored.prepass", "plan", *PLAN_STAGES, "coalesce", "launch",
+    "handoff.queue", "handoff.wake"])
 
 
 @pytest.mark.parametrize("slices", [2, 70])
@@ -365,9 +378,10 @@ def test_no_span_sits_in_a_loop_over_slices(server, slices):
     _populate(server, slices)
     c = InternalClient(server.host, timeout=60.0)
     assert c.execute_pql("i", INTERSECT) == slices
-    assert len(_last_trace(c)["spans"]) <= 24
+    # 24 until the two hand-overs of a launch became spans (PR 38)
+    assert len(_last_trace(c)["spans"]) <= 26
     assert c.execute_pql("i", INTERSECT) == slices
-    assert len(_last_trace(c)["spans"]) <= 24
+    assert len(_last_trace(c)["spans"]) <= 26
 
 
 TOPN_SRC = 'TopN(Bitmap(frame="f", rowID=1), frame="f", n=10)'
@@ -394,7 +408,7 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
     want = [(1, 2 * slices), (2, slices)]
     assert topn(TOPN_SRC) == want
     first = _last_trace(c)
-    assert len(first["spans"]) <= 24
+    assert len(first["spans"]) <= 26
     prep = _span(first, "topn.prep")
     # every fragment ranks the union itself and holds the src in its
     # plane: no fragment walked, no host copy of the src (``build``);
@@ -433,10 +447,11 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
     assert _span(other, "topn.score")["tags"]["score_cache"] == "computed"
     assert _span(other, "topn.prep")["tags"]["build"] == "direct"
     assert "compile" not in {s["name"] for s in other["spans"]}
-    # a TopN(src) that is scored has these spans whatever the slice count
+    # a TopN(src) that is scored has these 13 spans whatever the slice count
     assert sorted(s["name"] for s in other["spans"]) == sorted([
         "query", "parse", "admission", "execute", "call.TopN", "topn.prep",
-        "topn.score", "topn.dispatch", "topn.fetch", "launch", "topn.select"])
+        "topn.score", "topn.dispatch", "topn.fetch", "launch", *HANDOFFS,
+        "topn.select"])
 
 
 SUM_FILTERED = 'Sum(Intersect(Bitmap(frame="f", rowID=1), Range(frame="v", q >< [2, 6])), frame="v", field="q")'
@@ -465,14 +480,14 @@ def test_the_stages_of_a_served_sum_and_no_span_in_a_loop_over_slices(
 
     assert ask(SUM_FILTERED) == [(3 * slices, slices)]
     first = _last_trace(c)
-    assert len(first["spans"]) <= 24
+    assert len(first["spans"]) <= 26
     agg = _span(first, "bsi.agg")
     launches = -(-slices // bp.agg_members(slices, bp.TILE_ROWS + 8 + 9))
     # exists + 3 magnitude rows of q read twice (the Sum's and the
     # Range's leaves) and the filter's row: 9 rows of planes a slice
-    assert agg["tags"] == {"slices": slices, "way": "in_place", "planes": 9 * slices,
-                           "bytes": 9 * slices * bp.WORDS_PER_SLICE * 4,
-                           "launches": launches}
+    assert _tags(agg) == {"slices": slices, "way": "in_place", "planes": 9 * slices,
+                          "bytes": 9 * slices * bp.WORDS_PER_SLICE * 4,
+                          "launches": launches}
     assert [s["name"] for s in _children(first, "bsi.agg")] == [
         "bsi.prep", "bsi.dispatch", "bsi.fetch", "bsi.decode"]
     assert _span(first, "bsi.prep")["tags"] == {"slices": slices, "cold": 0}
@@ -486,6 +501,7 @@ def test_the_stages_of_a_served_sum_and_no_span_in_a_loop_over_slices(
     assert plan.program_cache_compile_ms()["bsi.agg"] >= compiles[0]["duration_ms"]
     assert _span(first, "bsi.fetch")["tags"]["arrays"] == launches
     assert [s["name"] for s in _children(first, "bsi.fetch")] == ["launch"]
+    assert [s["name"] for s in _children(first, "launch")] == HANDOFFS
     assert _span(first, "bsi.decode")["tags"] == {"vectors": slices}
 
     # other constants and another row: the program that is there
@@ -494,10 +510,86 @@ def test_the_stages_of_a_served_sum_and_no_span_in_a_loop_over_slices(
     assert ask(SUM_FILTERED.replace("[2, 6]", "[4, 7]")) == [(7 * slices, slices)]
     other = _last_trace(c)
     assert plan.program_cache_stats()["bitplane.aggregatePlanes"] == 1
-    # an in-place Sum has these spans whatever the slice count
+    # an in-place Sum has these 14 spans whatever the slice count
     assert sorted(s["name"] for s in other["spans"]) == sorted([
         "query", "parse", "admission", "execute", "call.Sum", "map.local",
-        "bsi.agg", "bsi.prep", "bsi.dispatch", "bsi.fetch", "launch", "bsi.decode"])
+        "bsi.agg", "bsi.prep", "bsi.dispatch", "bsi.fetch", "launch", *HANDOFFS,
+        "bsi.decode"])
+
+
+# ---------------------------------------------------------------------------
+# the hand-overs to and from the dispatcher
+# ---------------------------------------------------------------------------
+
+
+def _served_counts(server, c):
+    _populate(server, 3)
+    return [INTERSECT] * 6, "coalesce", "coalesce"
+
+
+def _served_topns(server, c):
+    _populate(server, 3)
+    # a text each, or the score memo answers and nothing is fetched
+    return [TOPN_SRC.replace("n=10", f"n={n}") for n in (10, 9, 8, 7, 6, 5)], \
+        "topn.fetch", "fetch"
+
+
+def _served_sums(server, c):
+    _populate(server, 3)
+    v = server.holder.index("i").create_frame_if_not_exists("v")
+    v.set_options(range_enabled=True)
+    v.create_field("q", 0, 7)
+    for sl in range(3):
+        v.import_value("q", [sl * SLICE_WIDTH + 5, sl * SLICE_WIDTH + 9], [3, 7])
+    server.holder.warm_device_mirrors()  # cold ones take the leaf batch
+    return [SUM_FILTERED.replace("[2, 6]", f"[{lo}, 6]") for lo in range(6)], \
+        "bsi.fetch", "fetch"
+
+
+@pytest.mark.parametrize("served", [_served_counts, _served_topns, _served_sums])
+def test_a_waiters_launch_is_flanked_by_its_two_handovers(one_chip, server, served):
+    """Every launch a request waited on carries exactly one
+    ``handoff.queue`` and one ``handoff.wake``, children of the waiter's
+    copy of the ``launch``; the three lie end to end and cover the
+    waiter's ``result()`` (its blocked time of kind ``queue``) to 1 ms;
+    a lone request finds the dispatcher idle."""
+    c = InternalClient(server.host, timeout=120.0)
+    texts, waits_in, site = served(server, c)
+    c.execute_pql("i", texts.pop())  # compile
+    left_over = []
+    found = []
+    for text in texts:
+        c.execute_pql("i", text)
+        t = _last_trace(c)
+        launches = [s for s in t["spans"] if s["name"] == "launch"]
+        assert len(launches) == 1 and launches[0]["tags"]["site"] == site
+        launch = launches[0]
+        waiter = _span(t, waits_in)
+        assert launch["parent_id"] == waiter["span_id"]
+        kids = _children(t, "launch")
+        assert [s["name"] for s in kids] == HANDOFFS
+        assert len([s for s in t["spans"] if s["name"] in HANDOFFS]) == 2
+        queue, wake = kids
+        assert queue["tags"].keys() == {"dispatcher"}
+        found.append(queue["tags"]["dispatcher"])
+        assert wake["tags"] == {"waiters": 1}
+        assert queue["duration_ms"] >= 0 and wake["duration_ms"] >= 0
+        # the launch carries the dispatcher's own CPU time
+        assert launch["cpu_ms"] is not None
+        waited = waiter["tags"]["blocked"]["queue"]
+        left_over.append(waited - (queue["duration_ms"] + launch["duration_ms"]
+                                   + wake["duration_ms"]))
+        # the wait is inside the span that made it
+        assert waited <= waiter["duration_ms"] + 0.01
+    # between the launch's end and set_result the dispatcher publishes the
+    # span and counts the launch: the median request's is under 1 ms
+    # a lone request finds the dispatcher in its wait (on a crowded
+    # machine the dispatcher can lose the processor for 5 ms on its way
+    # there, and the next request then finds it busy: tests/
+    # test_host_account.py holds both states to a launch the test holds)
+    assert found.count("idle") >= len(found) - 2, found
+    left_over.sort()
+    assert abs(left_over[len(left_over) // 2]) < 1.0, left_over
 
 
 # ---------------------------------------------------------------------------
