@@ -2687,6 +2687,13 @@ class Fragment:
         )
         return st, sub_ref, None
 
+    def dense_rows(self) -> tuple[np.ndarray, np.ndarray]:
+        """``(row ids ascending, their slots)`` of the dense tier: where
+        a src row lies in the plane, for every row at once (the
+        executor's kept TopN stack looks a text's src up in them)."""
+        with self._mu:
+            return self._tier_key_arrays_locked()[:2]
+
     def top_score_arrays(
         self, st: "TopState"
     ) -> tuple[np.ndarray, np.ndarray, np.ndarray, bool]:

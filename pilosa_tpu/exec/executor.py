@@ -401,6 +401,9 @@ class Executor:
         # walks, union assembly, and gather prep cached per (query,
         # slice set), validated like _batch_cache entries.
         self._topn_cache: "OrderedDict[tuple, dict]" = OrderedDict()
+        # What a direct folded-TopN build makes that no text changes,
+        # kept per view, slice set and options (see _topn_kept_text).
+        self._topn_kept: "OrderedDict[tuple, dict]" = OrderedDict()
         # Slot layouts of the in-place BSI aggregate (see
         # _agg_view_layout): host integers, no device bytes.
         self._agg_layouts: "OrderedDict[tuple, dict]" = OrderedDict()
@@ -424,12 +427,16 @@ class Executor:
         with self._batch_mu:
             batch_keys = list(self._batch_cache)
             topn_keys = list(self._topn_cache)
+            kept_keys = list(self._topn_kept)
             self._batch_cache.clear()
             self._topn_cache.clear()
+            self._topn_kept.clear()
         for k in batch_keys:
             pool.remove(self._batch_pool_key(k))
         for k in topn_keys:
             pool.remove(self._topn_pool_key(k))
+        for k in kept_keys:
+            pool.remove(self._topn_kept_pool_key(k))
 
     def _drop_closed_fragment(self, frag) -> None:
         with self._batch_mu:
@@ -440,8 +447,17 @@ class Executor:
             ]
             for k in stale:
                 del self._topn_cache[k]
+            dead = [
+                k
+                for k, e in self._topn_kept.items()
+                if any(p[0] is frag for p in e["parts"])
+            ]
+            for k in dead:
+                del self._topn_kept[k]
         for k in stale:
             device_mod.pool().remove(self._topn_pool_key(k))
+        for k in dead:
+            device_mod.pool().remove(self._topn_kept_pool_key(k))
 
     # ------------------------------------------------------------------
     # HBM residency-pool tenancy (device/pool.py): both device-holding
@@ -454,6 +470,9 @@ class Executor:
 
     def _topn_pool_key(self, key: tuple) -> tuple:
         return ("exec", id(self), "topn", key)
+
+    def _topn_kept_pool_key(self, key: tuple) -> tuple:
+        return ("exec", id(self), "topn_kept", key)
 
     def _register_cache_entry(self, pool_key, arrays, info, evict):
         """Admit a cache entry's device arrays to the residency pool;
@@ -469,8 +488,9 @@ class Executor:
         )
         return pool_key
 
-    def _evict_batch_key(self, key: tuple) -> bool:
-        """Pool eviction hook for a batch-cache entry.  Non-blocking:
+    def _evict_cache_key(self, cache, key: tuple) -> bool:
+        """Pool eviction hook for an entry of ``cache``: the batch
+        cache, the TopN prep LRU or the kept stacks.  Non-blocking:
         the pool invokes this under ITS lock while request threads
         hold ``_batch_mu`` around cache reads/inserts (pool tenancy
         itself is registered outside ``_batch_mu`` — see
@@ -481,19 +501,31 @@ class Executor:
         if not self._batch_mu.acquire(blocking=False):
             return False
         try:
-            self._batch_cache.pop(key, None)
+            cache.pop(key, None)
             return True
         finally:
             self._batch_mu.release()
 
-    def _evict_topn_key(self, key: tuple) -> bool:
-        if not self._batch_mu.acquire(blocking=False):
-            return False
-        try:
-            self._topn_cache.pop(key, None)
-            return True
-        finally:
-            self._batch_mu.release()
+    def _topn_admit(self, cache, cap: int, key, ent, pool_key_of, replaced, info):
+        """Insert ``ent`` as the newest entry of ``cache`` (the TopN prep
+        LRU or the kept stacks, capped at ``cap``), drop the pool
+        accounts of the entries it displaces, and account in the pool for
+        ``replaced``: the plane snapshots that the entry alone keeps
+        alive (``_replaced_planes``).  An entry with none clears any
+        account left under its key."""
+        displaced = []
+        with self._batch_mu:
+            cache[key] = ent
+            cache.move_to_end(key)
+            while len(cache) > cap:
+                displaced.append(cache.popitem(last=False)[0])
+        pool = device_mod.pool()
+        for k in displaced:
+            pool.remove(pool_key_of(k))
+        pool_key = pool_key_of(key)
+        evict = functools.partial(self._evict_cache_key, cache, key)
+        if self._register_cache_entry(pool_key, replaced, info, evict) is None:
+            pool.remove(pool_key)
 
     # ------------------------------------------------------------------
     # entry point (reference: executor.go:65-151)
@@ -1110,7 +1142,7 @@ class Executor:
                     self._batch_pool_key(key),
                     [ent["batch"]],
                     {"cache": "batch", "index": index, "query": str(c)},
-                    functools.partial(self._evict_batch_key, key),
+                    functools.partial(self._evict_cache_key, self._batch_cache, key),
                 )
                 gs.annotate(displaced=len(displaced))
         return ent
@@ -2941,8 +2973,13 @@ class Executor:
 
         def host_fn():
             # The stack's states are shared by every query of a prep
-            # entry: the host scorer fills clones.
-            live = [(replace(e[0]), *e[1:]) for e in stack.live]
+            # entry: the host scorer fills clones, told the text's src
+            # row where the states were made for another text.
+            row = stack.src_row
+            live = [
+                (replace(e[0]) if row is None else replace(e[0], src_row=row), *e[1:])
+                for e in stack.live
+            ]
             self.hosteval.score_topn_parts(live)
             return topn_stack.host_scores(stack, [e[0].counts for e in live])
 
@@ -3126,6 +3163,13 @@ class Executor:
     # working set of a hot dashboard is a handful of repeated queries.
     _TOPN_CACHE_CAP = 8
 
+    @staticmethod
+    def _frag_versions(frags) -> list:
+        return [
+            None if frag is None else (frag._serial, frag._version)
+            for frag in frags
+        ]
+
     def _topn_versions(self, index: str, c: Call, slices: list[int]):
         """Validity vector for a folded-TopN prep entry: the TopN
         frame's fragment versions over the ORIGINAL slice list (a
@@ -3134,10 +3178,7 @@ class Executor:
         resolve to (the src rows were host-evaluated at prep time)."""
         view = self._topn_view(index, c)
         frags = view.fragments_at(slices) if view is not None else [None] * len(slices)
-        out: list = [
-            None if frag is None else (frag._serial, frag._version)
-            for frag in frags
-        ]
+        out = self._frag_versions(frags)
         if len(c.children) == 1:
             try:
                 _, leaves = plan.decompose(
@@ -3162,9 +3203,17 @@ class Executor:
 
         Attr-filtered queries (filterField) are NOT cached: the attr
         store has no version vector, so a SetRowAttrs would serve stale
-        candidates."""
+        candidates.
+
+        A miss whose call may take the direct way is served from its
+        view's kept stack where one stands (``_topn_kept_text``), and an
+        entry that holds a kept stack (``"kept_stack"``: served from it,
+        or built beside it) is validated through it and lives no longer
+        than it does."""
         key = (index, str(c), tuple(slices))
         cacheable = not self._topn_parsed_args(c)[3]  # "" = no filterField
+        kept_key = self._topn_kept_key(index, c, slices)
+        cur_versions = None
         if cacheable:
             now = time.monotonic()
             with self._batch_mu:
@@ -3188,16 +3237,27 @@ class Executor:
             # the candidate walk this cache skips — without the expiry a
             # hot read-only query would freeze its candidate counts
             # forever instead of the old path's <= 10 s of staleness.
-            cur_versions = None
             if ent is not None and (
                 time.monotonic() - ent["built_at"]
                 < cache_mod.RECALCULATE_INTERVAL_S
             ):
-                epoch = fragment_mod.write_epoch()
-                if ent["epoch"] != epoch:
-                    cur_versions = self._topn_versions(index, c, slices)
-                if ent["epoch"] == epoch or ent["versions"] == cur_versions:
-                    ent["epoch"] = epoch
+                kept = ent.get("kept_stack")
+                if kept is not None:
+                    # its src is a row of the very fragments the kept
+                    # stack stands for
+                    stands = self._topn_kept_stands(
+                        kept_key, kept, index, c, slices
+                    )
+                else:
+                    epoch = fragment_mod.write_epoch()
+                    if ent["epoch"] != epoch:
+                        cur_versions = self._topn_versions(index, c, slices)
+                    stands = (
+                        ent["epoch"] == epoch or ent["versions"] == cur_versions
+                    )
+                    if stands:
+                        ent["epoch"] = epoch
+                if stands:
                     with self._batch_mu:
                         if key in self._topn_cache:
                             self._topn_cache.move_to_end(key)
@@ -3217,25 +3277,25 @@ class Executor:
         # predates the build, which is exactly the conservative bar.
         epoch = fragment_mod.write_epoch()
         versions = None
-        if cacheable:
-            versions = (
-                cur_versions
-                if cur_versions is not None
-                else self._topn_versions(index, c, slices)
-            )
-        ent = self._topn_folded_build(index, c, slices)
+        ent = (
+            self._topn_kept_text(kept_key, index, c, slices)
+            if kept_key is not None
+            else None
+        )
+        if ent is None:
+            if cacheable:
+                versions = (
+                    cur_versions
+                    if cur_versions is not None
+                    else self._topn_versions(index, c, slices)
+                )
+            ent = self._topn_folded_build(index, c, slices)
         ent["epoch"] = epoch
         ent["versions"] = versions
-        ent["built_at"] = time.monotonic()
+        kept = ent.get("kept_stack")
+        # An entry of a kept stack is as old as the layouts it reads.
+        ent["built_at"] = time.monotonic() if kept is None else kept["built_at"]
         if cacheable:
-            displaced = []
-            with self._batch_mu:
-                self._topn_cache[key] = ent
-                while len(self._topn_cache) > self._TOPN_CACHE_CAP:
-                    displaced.append(self._topn_cache.popitem(last=False)[0])
-            pool = device_mod.pool()
-            for k in displaced:
-                pool.remove(self._topn_pool_key(k))
             # Byte-account the HBM plane snapshots that this entry ALONE
             # keeps alive.  A SubRef's plane is the fragment's mirror as
             # it stood at prepare time; while it still IS the mirror the
@@ -3244,18 +3304,146 @@ class Executor:
             # read as 16 and evict the very mirrors the scorer reads.
             # Only a snapshot that a later write has replaced is this
             # entry's to account for (it dies with the entry, within
-            # RECALCULATE_INTERVAL_S).
-            self._register_cache_entry(
-                self._topn_pool_key(key),
-                [
-                    p[4].plane
-                    for p in ent.get("parts", ())
-                    if p[4] is not None and not p[0].mirror_is(p[4].plane)
-                ],
+            # RECALCULATE_INTERVAL_S).  An entry of a kept stack holds
+            # the kept stack's snapshots, which it accounts for.
+            self._topn_admit(
+                self._topn_cache, self._TOPN_CACHE_CAP, key, ent,
+                self._topn_pool_key,
+                self._replaced_planes(ent.get("parts", ())) if kept is None else [],
                 {"cache": "topn", "index": index, "query": str(c)},
-                functools.partial(self._evict_topn_key, key),
             )
         return ent, "built"
+
+    @staticmethod
+    def _replaced_planes(parts) -> list:
+        """The plane snapshots of ``parts`` that are no longer their
+        fragment's mirror: what an entry alone keeps alive."""
+        return [
+            p[4].plane
+            for p in parts
+            if p[4] is not None and not p[0].mirror_is(p[4].plane)
+        ]
+
+    # Kept stacks, one a (view, slice set, options): the handful of
+    # ranked views a deployment serves TopN over.
+    _TOPN_KEPT_CAP = 8
+
+    def _topn_kept_key(self, index: str, c: Call, slices: list[int]):
+        """The key of the kept stack a call's build may take the direct
+        way with (``_topn_kept_text``): ``(index, frame, view, slices,
+        min threshold, has src)`` where the filters keep every counted
+        row and the src, if any, is one plain Bitmap leaf; None for any
+        other call (``ids=``, a count window, an attr filter, another
+        src tree), which builds the general way."""
+        frame, view, _n, _fld, row_ids, _min, _filters, tanimoto = (
+            self._topn_parsed_args(c)
+        )
+        if tanimoto or row_ids:
+            return None
+        topt = self._topn_options(c)
+        if not topt.keeps_every_counted:
+            return None
+        has_src = len(c.children) == 1
+        if has_src and (c.children[0].name != "Bitmap" or c.children[0].children):
+            return None
+        return (index, frame, view, tuple(slices), topt.min_threshold, has_src)
+
+    def _topn_kept_stands(self, kept_key, kept: dict, index, c, slices) -> bool:
+        """Whether ``kept`` is still the kept stack of ``kept_key`` and
+        may serve: younger than the rank caches' re-sort throttle
+        (RECALCULATE_INTERVAL_S, whose re-sort happens in the layout
+        calls a kept stack skips), and no write since it was made —
+        the write epoch in O(1), then the fragments' version vector.
+        One that fails is dropped at once."""
+        if time.monotonic() - kept["built_at"] < cache_mod.RECALCULATE_INTERVAL_S:
+            with self._batch_mu:
+                current = self._topn_kept.get(kept_key) is kept
+                if current:
+                    self._topn_kept.move_to_end(kept_key)
+            if current:
+                epoch = fragment_mod.write_epoch()
+                if kept["epoch"] == epoch:
+                    return True
+                view = self._topn_view(index, c)
+                if view is not None and kept["versions"] == self._frag_versions(
+                    view.fragments_at(slices)
+                ):
+                    kept["epoch"] = epoch
+                    return True
+        with self._batch_mu:
+            dropped = self._topn_kept.get(kept_key) is kept
+            if dropped:
+                del self._topn_kept[kept_key]
+        if dropped:
+            device_mod.pool().remove(self._topn_kept_pool_key(kept_key))
+        return False
+
+    def _topn_kept_text(self, kept_key, index: str, c: Call, slices: list[int]):
+        """A direct build served from the kept stack of ``kept_key``
+        where one stands: of all a build makes only what depends on the
+        text, the src row's slot in every part's plane, looked up in the
+        stack's ``SrcTable``; the layouts, the union, the parts, the
+        scorer's planes and slots and the ``TopStack`` are the kept
+        stack's.  No fragment is asked anything and no fragment lock
+        taken; one hold of the pool's lock keeps the mirrors recent.
+        None where no kept stack stands or the src row is not in every
+        part's dense tier: the caller builds in full."""
+        with self._batch_mu:
+            kept = self._topn_kept.get(kept_key)
+        if kept is None or not self._topn_kept_stands(
+            kept_key, kept, index, c, slices
+        ):
+            return None
+        score = kept["score"]
+        if kept["src"] is not None:
+            leaf = self._topn_src_leaf(index, c)
+            if leaf is None or leaf[:2] != kept_key[1:3]:
+                return None
+            slots = topn_stack.src_slots(kept["src"], leaf[2])
+            if slots is None:
+                return None
+            score = topn_stack.for_src_row(score, slots, leaf[2])
+        device_mod.pool().touch_many(kept["pins"])
+        return {
+            "parts": kept["parts"],
+            "union": len(kept["union"]),
+            "build": "direct",
+            "stack_way": "kept",
+            "score": score,
+            "stack": kept["stack"],
+            "pins": kept["pins"],
+            "kept_stack": kept,
+        }
+
+    def _topn_keep(self, kept_key, index, epoch, versions, per, parts, union,
+                   score, stack, pins) -> dict:
+        """Keep what a direct build made that no text changes (see
+        ``_topn_kept_text``), as the kept stack of ``kept_key``:
+        ``epoch`` and ``versions`` as they stood before the build read
+        any layout.  Its snapshots that a write has replaced already are
+        its own to account for, as a prep entry's are."""
+        kept = {
+            "layouts": tuple(p[1] for p in per),
+            "parts": parts,
+            "union": union,
+            "score": score,
+            "stack": stack,
+            "pins": pins,
+            "src": (
+                topn_stack.src_table([p[0].dense_rows() for p in parts])
+                if kept_key[5]
+                else None
+            ),
+            "epoch": epoch,
+            "versions": versions,
+            "built_at": time.monotonic(),
+        }
+        self._topn_admit(
+            self._topn_kept, self._TOPN_KEPT_CAP, kept_key, kept,
+            self._topn_kept_pool_key, self._replaced_planes(parts),
+            {"cache": "topn_kept", "index": index, "frame": kept_key[1]},
+        )
+        return kept
 
     def _topn_folded_build(self, index: str, c: Call, slices: list[int]) -> dict:
         """Build a folded-TopN prep entry (see _topn_folded_entry for
@@ -3281,19 +3469,27 @@ class Executor:
         copy of a src row that the scorer reads from the plane.  Any
         other fragment is walked (``top_prepare_union_parts``, with the
         src's host words).  ``build`` says which: ``"direct"`` when no
-        fragment was walked."""
+        fragment was walked.
+
+        A direct build keeps all of that for the next text of its view
+        and options (``_topn_keep``; the entry says ``"stack_way":
+        "made"`` and holds it as ``"kept_stack"``): what a build does
+        for a text served from it is ``_topn_kept_text``'s."""
         has_src = len(c.children) == 1
 
         # Only slices whose fragment exists can contribute; one sweep of
         # the view finds them, and the call's arguments are parsed once.
+        # A call that may go the direct way keeps what it makes for the
+        # next text (_topn_keep), valid for the write epoch and versions
+        # that stand before any layout is read.
+        kept_key = self._topn_kept_key(index, c, slices)
+        epoch = fragment_mod.write_epoch()
         view = self._topn_view(index, c)
-        frags = (
-            [f for f in view.fragments_at(slices) if f is not None]
-            if view is not None
-            else []
-        )
+        at = view.fragments_at(slices) if view is not None else []
+        frags = [f for f in at if f is not None]
         if not frags:
             return {"empty": True}
+        versions = self._frag_versions(at) if kept_key is not None else None
         topt = self._topn_options(c)
         plain = topt.keeps_every_counted
         rows = self._topn_rows_entry(index, c, view, frags, topt)
@@ -3416,6 +3612,13 @@ class Executor:
         # The mirrors the parts read stay recent in the residency pool:
         # one hold of its lock a build, not one a fragment.
         device_mod.pool().touch_many(pins)
+        stack = topn_stack.stack_parts(parts, union, score)
+        kept = None
+        if kept_key is not None and not walk:
+            kept = self._topn_keep(
+                kept_key, index, epoch, versions, per, parts, union, score,
+                stack, pins,
+            )
         # "scores" memoizes the fetched score vector for as long as
         # the ENTRY validates (fragments unchanged since build =>
         # scores unchanged); "score_event" single-flights the fused
@@ -3424,14 +3627,17 @@ class Executor:
         # so a 32-query storm of one TopN shape pays ONE
         # dispatch+fetch, not 32 — the topn.fetch residual ROADMAP 5
         # names.
-        return {
+        ent = {
             "parts": parts,
             "union": len(union),
             "build": "walked" if walk else "direct",
             "score": score,
-            "stack": topn_stack.stack_parts(parts, union, score),
+            "stack": stack,
             "pins": pins,
         }
+        if kept is not None:
+            ent.update(stack_way="made", kept_stack=kept)
+        return ent
 
     def _topn_rows_entry(self, index: str, c: Call, view, frags, topt) -> dict | None:
         """The prep entry of a TopN(src) over a view that is ONE
@@ -3577,6 +3783,10 @@ class Executor:
             )
             if how == "built" and "build" in ent:
                 sp.annotate(build=ent["build"])
+                if "stack_way" in ent:
+                    # ``stack``: a direct build served from its view's
+                    # kept stack (``kept``), or one that made it
+                    sp.annotate(stack=ent["stack_way"])
             if "rows" in ent:
                 # ``candidates``: the rows the text's count window keeps
                 # (upstream's filter on cached counts: a number of the

@@ -1027,9 +1027,8 @@ def program_cache_stats() -> dict[str, int]:
             _jit_cache_size(bp._expand_sparse_xla)
             + _jit_cache_size(bp._expand_rle_xla)
         ),
-        "bitplane.scorePlanes": (
-            _jit_cache_size(bp._score_planes_self_src)
-            + _jit_cache_size(bp._score_planes_host_src)
+        "bitplane.scorePlanes": sum(
+            _jit_cache_size(fn) for fn in bp.SCORE_PROGRAMS
         ),
         "bitplane.gatherPlanes": sum(
             _jit_cache_size(fn) for fn in bp.GATHER_PROGRAMS
@@ -1230,10 +1229,8 @@ def clear_program_caches() -> None:
     bp._SHAPE_HIGHWATER.clear()
     bp._SCORE_SEEN.clear()
     bp._AGG_SEEN.clear()
-    for fn in bp.GATHER_PROGRAMS + (
+    for fn in bp.GATHER_PROGRAMS + bp.SCORE_PROGRAMS + (
         bp._aggregate_planes_xla,
-        bp._score_planes_self_src,
-        bp._score_planes_host_src,
         bp._score_rows_xla,
         bp._fused_count_xla,
         bp._top_counts_xla,

@@ -19,6 +19,12 @@ entry stacks, once a build, what those walks read:
   which one, which candidates are a part's own (phase 1 ranks those
   only), and the thresholds a row keeps by.
 
+Neither depends on the text where every part's src is a row of its own
+plane: a direct build's stacks are kept across texts
+(``Executor._topn_kept_text``), and a text looks its src row's slots up
+in a ``SrcTable`` (``src_slots``) and takes the ``ScoreStack`` with
+them (``for_src_row``).
+
 ``select`` is both protocol phases over those arrays: no call a part.
 Whether a part came from its fragment's kept layout, was walked, is
 tanimoto-filtered or short-circuited is in the arrays (masks, lengths,
@@ -29,7 +35,7 @@ the fetched score vector is the only state an answer owns.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 import numpy as np
 
@@ -56,7 +62,12 @@ class ScoreStack:
     starts in the flat score vector (-1: nothing of it is scored on the
     device), as long as the entry's slot vector; ``live`` are the
     scored entries themselves, group after group, which the host
-    fallback walks, and ``live_base`` their bases."""
+    fallback walks, and ``live_base`` their bases.  ``members[g]`` are
+    the indices of group ``g``'s entries among the entries the stack was
+    made from.  ``src_row``: the row every entry without src words
+    reads its src from, where that is not the row its TopState names (a
+    stack that serves another text than the one its states were made
+    for, ``for_src_row``); None: each state's own."""
 
     groups: tuple
     live: tuple
@@ -65,6 +76,8 @@ class ScoreStack:
     size: int
     rows: int
     n_bytes: int
+    members: tuple = ()
+    src_row: int | None = None
 
     def hand_out(self, entries, scores: np.ndarray) -> None:
         """Give each entry's TopState its row of ``scores`` (the
@@ -87,7 +100,7 @@ def score_stack(entries) -> ScoreStack:
                 (ref.shape, ref.plane_rows, ref.device, entry[3] is None), []
             ).append(i)
     base = np.full(len(entries), -1, np.int64)
-    groups, live, order = [], [], []
+    groups, live, order, in_group = [], [], [], []
     size = rows = n_bytes = 0
     for (shape, plane_rows, _dev, host_src), idx in keyed.items():
         members = [entries[i] for i in idx]
@@ -125,8 +138,74 @@ def score_stack(entries) -> ScoreStack:
         )
         live.extend(members)
         order.extend(idx)
+        in_group.append(np.asarray(idx, np.intp))
     return ScoreStack(
-        tuple(groups), tuple(live), base[order], base, size, rows, n_bytes
+        tuple(groups),
+        tuple(live),
+        base[order],
+        base,
+        size,
+        rows,
+        n_bytes,
+        tuple(in_group),
+    )
+
+
+@dataclass(frozen=True, eq=False)
+class SrcTable:
+    """Where every dense-tier row of a stack's parts lies in each part's
+    plane, as one listing sorted by row id: ``slot[k]`` is the slot of
+    row ``ids[k]`` in the plane of part ``part[k]``.  A row is held once
+    a part at most, so it is held by every part where it is listed
+    ``parts`` times."""
+
+    ids: np.ndarray  # int64, ascending
+    part: np.ndarray  # intp
+    slot: np.ndarray  # int32
+    parts: int
+
+
+def src_table(tiers) -> SrcTable:
+    """The ``SrcTable`` of ``tiers``, each part's dense tier as ``(row
+    ids ascending, their slots)``."""
+    lens = np.fromiter((len(t[0]) for t in tiers), np.int64, len(tiers))
+    ids = np.concatenate([t[0] for t in tiers])
+    order = np.argsort(ids, kind="stable")
+    return SrcTable(
+        ids[order],
+        np.repeat(np.arange(len(tiers)), lens)[order],
+        np.concatenate([t[1] for t in tiers]).astype(np.int32)[order],
+        len(tiers),
+    )
+
+
+def src_slots(table: SrcTable, row: int) -> np.ndarray | None:
+    """``int32[parts]``: row ``row``'s slot in each part's plane, in a
+    fixed number of numpy calls; None where some part does not hold it
+    in its dense tier."""
+    lo = int(np.searchsorted(table.ids, row))
+    hi = lo + table.parts
+    # listed once a part at most: ``parts`` listings from the first are
+    # all of the row's where the last of them is the row's
+    if hi > len(table.ids) or table.ids[hi - 1] != row:
+        return None
+    out = np.empty(table.parts, np.int32)
+    out[table.part[lo:hi]] = table.slot[lo:hi]
+    return out
+
+
+def for_src_row(stack: ScoreStack, slots: np.ndarray, row: int) -> ScoreStack:
+    """``stack`` for the src row ``row`` of the parts' own planes: each
+    group's src slots taken from ``slots`` (a part's slot, in the order
+    of the entries ``stack`` was made from), and the host fallback told
+    to read that row.  Planes, candidate slots and bases are shared."""
+    return replace(
+        stack,
+        groups=tuple(
+            replace(g, src_slots=slots[m])
+            for g, m in zip(stack.groups, stack.members)
+        ),
+        src_row=row,
     )
 
 

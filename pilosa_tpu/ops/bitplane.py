@@ -741,6 +741,103 @@ def _score_planes_self_src(planes, slots, src_slots):
     return jnp.stack(outs)
 
 
+# The scorer kernel's lanes, the words of a plane it counts at a time
+# and the most rows it takes: a [64, 512] block is 32 of the core's 64
+# vector registers, and two whole planes of 64 rows x 32,768 words are
+# 16 MiB of its fast memory.
+SCORE_LANES = 128
+SCORE_CHUNK_WORDS = 512
+SCORE_KERNEL_ROWS = 64
+
+
+def kernel_scores(plane_shape: tuple, n_slots: int) -> bool:
+    """Whether the TPU scores planes of ``plane_shape`` by the kernel
+    (``_score_planes_kernel``): planes no taller than their candidate
+    slots (the slots are then the plane's rows, padded by repeats, and
+    the plane is read whole either way), of whole register tiles.  Any
+    other plane gathers its candidates (``_score_planes_self_src``)."""
+    rows, words = plane_shape
+    return (
+        rows <= min(n_slots, SCORE_KERNEL_ROWS)
+        and rows % 8 == 0
+        and words % SCORE_LANES == 0
+        and words % min(words, SCORE_CHUNK_WORDS) == 0
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _score_planes_kernel(planes, slots, src_slots, interpret=False):
+    """The self-src scorer as ONE kernel a launch: each member's plane is
+    copied whole into fast memory, the next member's copy running while
+    this one is counted, and every row's ``|row AND src|`` is summed
+    from the src row where it lies in the same buffer.  The candidates'
+    counts are picked after, by ``slots``.  Same bytes from HBM as the
+    fused XLA program, in a handful of device operations a launch where
+    that one has four a member: what a profile of the busy TopN cell
+    holds, and what reading it costs, is per operation."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    members = len(planes)
+    rows, words = planes[0].shape
+    chunk = min(SCORE_CHUNK_WORDS, words)
+
+    def kernel(src_ref, *refs):
+        mirrors, out_ref, buf, sem = refs[:members], refs[members], refs[-2], refs[-1]
+
+        def copy(f):
+            return pltpu.make_async_copy(mirrors[f], buf.at[f % 2], sem.at[f % 2])
+
+        copy(0).start()
+        for f in range(members):
+            if f + 1 < members:
+                copy(f + 1).start()
+            copy(f).wait()
+            at, src = f % 2, src_ref[f]
+
+            def count(c, acc, at=at, src=src):
+                cols = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+                ones = jax.lax.population_count(
+                    buf[at, :, cols] & buf[at, pl.ds(src, 1), cols]
+                ).astype(jnp.int32)
+                for k in range(0, chunk, SCORE_LANES):
+                    acc = acc + ones[:, k:k + SCORE_LANES]
+                return acc
+
+            out_ref[f] = jax.lax.fori_loop(
+                0, words // chunk, count, jnp.zeros((rows, SCORE_LANES), jnp.int32)
+            )
+
+    lanes = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((members, rows, SCORE_LANES), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * members,
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, rows, words), jnp.uint32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=4 * rows * (2 * words + members * SCORE_LANES) + (8 << 20)
+        ),
+        interpret=interpret,
+    )(src_slots, *planes)
+    counts = jnp.sum(lanes, axis=-1)
+    return jnp.take_along_axis(counts, slots, axis=1, mode="clip")
+
+
+def self_src_scorer(platform: str, plane_shape: tuple, n_slots: int):
+    """The self-src scorer program for planes of ``plane_shape`` and
+    ``n_slots`` candidate slots on ``platform``."""
+    if platform == "tpu" and kernel_scores(plane_shape, n_slots):
+        return _score_planes_kernel
+    return _score_planes_self_src
+
+
 @jax.jit
 def _score_planes_host_src(planes, slots, srcs):
     outs = []
@@ -755,6 +852,9 @@ def _score_planes_host_src(planes, slots, srcs):
             )
         )
     return jnp.stack(outs)
+
+
+SCORE_PROGRAMS = (_score_planes_self_src, _score_planes_kernel, _score_planes_host_src)
 
 
 # Fragments one compiled scorer program takes.  The program is unrolled
@@ -817,7 +917,9 @@ def score_planes(planes, slots, src_slots=None, srcs=None, first_call=None) -> l
     plane (the common TopN(Bitmap(frame=f), frame=f) shape; zero src
     bytes host->device, and no extra leaf shapes enter the jit key) — or
     ``srcs`` uint32[n_frag, words] host-snapshot rows.  Gathers fuse
-    into the popcount reduce, so each candidate row is read once.
+    into the popcount reduce, so each candidate row is read once; on
+    the TPU a plane no taller than its slots is read whole by one
+    kernel a launch (``kernel_scores``).
 
     The fragments are scored ``score_group_bucket(n_frag)`` at a time:
     every launch is dispatched without waiting and the device arrays
@@ -843,26 +945,34 @@ def score_planes(planes, slots, src_slots=None, srcs=None, first_call=None) -> l
         score_slots=int(slots.shape[-1]),
         plane_words=int(planes[0].shape[1]),
     )
+    devs = getattr(planes[0], "devices", None)
+    devices = devs() if callable(devs) else ()
+    platform = next(iter(devices)).platform if devices else "cpu"
     fn, src = (
-        (_score_planes_self_src, src_slots)
+        (self_src_scorer(platform, tuple(planes[0].shape), int(slots.shape[-1])), src_slots)
         if srcs is None
         else (_score_planes_host_src, srcs)
     )
-    devs = getattr(planes[0], "devices", None)
     shape = (
         "self" if srcs is None else "host",
         bucket,
         tuple(planes[0].shape),
         int(slots.shape[-1]),
-        str(sorted(map(str, devs()))) if callable(devs) else "",
+        str(sorted(map(str, devices))) if devices else "",
     )
     outs = []
-    for lo in range(0, n, bucket):
-        idx = np.minimum(np.arange(lo, lo + bucket), n - 1)
-        group = tuple(planes[i] for i in idx)
-        outs.append(
-            _first_call(fn, shape, first_call, group, slots[idx], src[idx])
-        )
+    # The kernel, as the aggregate's DMA kernel, carries its source
+    # locations into the compile cache's key: without whole call stacks
+    # in them the prewarm and a request lower one program, not two.
+    from jax._src import config as jax_config
+
+    with jax_config.include_full_tracebacks_in_locations(False):
+        for lo in range(0, n, bucket):
+            idx = np.minimum(np.arange(lo, lo + bucket), n - 1)
+            group = tuple(planes[i] for i in idx)
+            outs.append(
+                _first_call(fn, shape, first_call, group, slots[idx], src[idx])
+            )
     return outs
 
 

@@ -92,13 +92,20 @@ def test_row_counts_and_top_counts(rng):
         assert tc[r] == np_popcount(plane[r] & src)
 
 
-def test_score_planes_parity(rng):
+@pytest.mark.parametrize(
+    "plane_rows, cand",
+    [(16, 8), (16, 16), (8, 16)],
+    ids=["gathers_its_candidates", "reads_the_plane_whole", "shorter_than_its_slots"],
+)
+def test_score_planes_parity(rng, plane_rows, cand):
     """The fused cross-fragment TopN scorer (gather + AND + popcount +
     rowsum straight from plane mirrors) matches numpy bit-for-bit, in
-    both src modes."""
+    both src modes; and where a plane is no taller than its slots, so
+    does the TPU's kernel, which reads the plane whole and picks after
+    (interpreted here)."""
     import jax.numpy as jnp
 
-    n_frag, plane_rows, cand = 3, 16, 8
+    n_frag = 3
     planes_np = [
         rng.integers(0, 2**32, size=(plane_rows, bp.WORDS_PER_SLICE), dtype=np.uint32)
         for _ in range(n_frag)
@@ -125,6 +132,11 @@ def test_score_planes_parity(rng):
     srcs = np.stack([planes_np[f][src_slots[f]] for f in range(n_frag)])
     got2 = fetched(bp.score_planes(planes, slots, srcs=srcs))
     np.testing.assert_array_equal(got2, want)
+
+    assert bp.kernel_scores((plane_rows, bp.WORDS_PER_SLICE), cand) == (plane_rows <= cand)
+    if plane_rows <= cand:
+        got3 = bp._score_planes_kernel(planes, slots, src_slots, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got3), want)
 
 
 def test_top_k_tie_break(rng):

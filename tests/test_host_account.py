@@ -428,8 +428,9 @@ def test_the_new_reducers_read_a_real_trace_and_metrics(server):
     ev = {"traces": _traces(c, 4), "metrics": {"before": before, "after": scrape()},
           "window": (t0, time.monotonic())}
     bench = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-    new = [m["name"] for m in bench["per_layer"][47:]]
-    assert len(new) == 10
+    # the ten the host's account added, after the 47 that stood before it
+    new = [m["name"] for m in bench["per_layer"][47:57]]
+    assert len(new) == 10 and new[0] == "exec.handoff_queue_ms"
     got = {name: metrics.layer_metric(name, ev) for name in new}
     # a cached Count has no plan.leaves, and nothing here is a TopN or a Sum
     nothing = {"exec.plan_leaves_gil_wait_share", "exec.topn_prep_gil_wait_share",

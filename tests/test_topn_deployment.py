@@ -267,9 +267,18 @@ def test_the_scorer_compiles_for_the_chip_at_the_cells_own_width(one_v5e_chip):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e_chip)
 
     planes = tuple(shape((64, bp.WORDS_PER_SLICE), jnp.uint32) for _ in range(G))
-    lowered = bp._score_planes_self_src.lower(
-        planes, shape((G, 64), jnp.int32), shape((G,), jnp.int32))
+    scorer = bp.self_src_scorer("tpu", (64, bp.WORDS_PER_SLICE), 64)
+    assert scorer is bp._score_planes_kernel
+    lowered = scorer.lower(planes, shape((G, 64), jnp.int32), shape((G,), jnp.int32))
     compiled = lowered.compile()
+    # What a traced run's profile holds of each launch: the kernel and
+    # the pick of the candidates' counts, a handful of operations where
+    # the fused XLA program has ~18 a member (1,141 a launch)
+    entry = compiled.as_text().split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    ops = re.findall(r"\s([a-z][a-z-]*)\((?:%|\))", entry)
+    executed = [op for op in ops if op not in (
+        "parameter", "constant", "get-tuple-element", "bitcast", "tuple")]
+    assert ops.count("custom-call") == 1 and len(executed) <= 8
     mem = compiled.memory_analysis()
     mirrors = G * 64 * bp.WORDS_PER_SLICE * 4
     # the operands are the mirrors themselves and two small index arrays
@@ -550,7 +559,8 @@ def test_the_cells_files_are_found_by_name(tiny_bench, kind):
     listed = {m["name"] for m in cell.per_layer}
     assert {"exec.topn_prep_ms", "exec.topn_select_ms", "device.topn_dispatch_ms",
             "device.topn_fetch_ms", "exec.topn_scored_share",
-            "exec.topn_select_stacked_share", "device.topn_roofline"} <= listed
+            "exec.topn_select_stacked_share", "device.topn_roofline",
+            "exec.topn_prep_kept_share"} <= listed
     assert not {"exec.plan_ms", "exec.map_local_self_ms", "device.count_roofline"} & listed
 
 

@@ -411,10 +411,11 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
     assert len(first["spans"]) <= 26
     prep = _span(first, "topn.prep")
     # every fragment ranks the union itself and holds the src in its
-    # plane: no fragment walked, no host copy of the src (``build``);
-    # the union is rows 1, 2 and the row that holds the far column
+    # plane: no fragment walked, no host copy of the src (``build``), and
+    # the view's stack is kept for the next text (``stack``); the union
+    # is rows 1, 2 and the row that holds the far column
     assert prep["tags"] == {"slices": slices, "prep_cache": "built", "union": 3,
-                            "build": "direct"}
+                            "build": "direct", "stack": "made"}
     disp = _span(first, "topn.dispatch")
     launches = -(-slices // bp.SCORE_GROUP)
     assert disp["tags"]["launches"] == launches and disp["tags"]["groups"] == 1
@@ -440,12 +441,15 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
     assert _span(again, "topn.score")["tags"]["score_cache"] == "shared"
     assert not {"topn.dispatch", "compile"} & {s["name"] for s in again["spans"]}
 
-    # another src: scored by the program that is there
+    # another src: built from the kept stack, whose layout no text
+    # changes, and scored by the program that is there
     assert topn(TOPN_SRC.replace("rowID=1", "rowID=2")) == [
         (1, slices), (2, slices)]
     other = _last_trace(c)
     assert _span(other, "topn.score")["tags"]["score_cache"] == "computed"
-    assert _span(other, "topn.prep")["tags"]["build"] == "direct"
+    assert _tags(_span(other, "topn.prep")) == {
+        "slices": slices, "prep_cache": "built", "union": 3, "build": "direct",
+        "stack": "kept"}
     assert "compile" not in {s["name"] for s in other["spans"]}
     # a TopN(src) that is scored has these 13 spans whatever the slice count
     assert sorted(s["name"] for s in other["spans"]) == sorted([

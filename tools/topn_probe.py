@@ -88,10 +88,12 @@ def spans_of(server: Server, trace_id: str) -> list[dict]:
 def stages(spans: list[dict]) -> dict:
     """A request's stages on one line: the milliseconds of each TopN
     span, how the prep entry was come by (``prep_cache``), where it
-    was built, whether any fragment was walked (``build``), and whether
-    the winners were selected from the entry's stacked arrays
-    (``topn.select``'s ``way``: ``stacked``); a program from before a
-    tag prints None for it."""
+    was built, whether any fragment was walked (``build``), whether a
+    direct build was served from its view's kept stack or made it
+    (``stack``: ``kept`` / ``made``), and whether the winners were
+    selected from the entry's stacked arrays (``topn.select``'s
+    ``way``: ``stacked``); a program from before a tag prints None for
+    it."""
     by_name = {s["name"]: s for s in spans}
     out = {name.removeprefix("topn."): by_name[name]["ms"]
            for name in ("topn.prep", "topn.dispatch", "topn.fetch", "topn.select")
@@ -99,7 +101,7 @@ def stages(spans: list[dict]) -> dict:
     tags = by_name.get("topn.prep", {}).get("tags", {})
     way = by_name.get("topn.select", {}).get("tags", {}).get("way")
     return dict(out, prep_cache=tags.get("prep_cache"), build=tags.get("build"),
-                way=way)
+                stack=tags.get("stack"), way=way)
 
 
 def probe(server: Server, cell, ref, srcs, deadline_ms: int) -> list[dict]:

@@ -3,9 +3,10 @@ scorer, by the number of fragments one program takes (ROADMAP M5).
 
     python tools/topn_scorer_sweep.py [--slices 954] [--rows 64] [--groups 8,16,32,64,128]
 
-The scorer (``ops/bitplane._score_planes_self_src``) is one jitted
-program over a *tuple* of plane mirrors, unrolled once per member, so
-its compile time grows with the tuple.  This holds ``--slices`` planes
+The scorer (``ops/bitplane.self_src_scorer``: on the TPU the kernel of
+``_score_planes_kernel`` where the planes are no taller than the slots)
+is one jitted program over a *tuple* of plane mirrors, unrolled once per
+member, so its compile time grows with the tuple.  This holds ``--slices`` planes
 of ``--rows`` rows on the device, as an index of that size does, and for
 each group size G times (a) the first call, with the persistent compile
 cache off, and (b) a whole answer: ceil(slices / G) launches of that one
@@ -43,6 +44,8 @@ def main(argv=None) -> int:
     from pilosa_tpu.ops import bitplane as bp
 
     dev = jax.devices()[0]
+    kernel = bp.kernel_scores((args.rows, bp.WORDS_PER_SLICE), args.rows)
+    score = bp.self_src_scorer(dev.platform, (args.rows, bp.WORDS_PER_SLICE), args.rows)
     make = jax.jit(
         lambda key: jax.random.bits(key, (args.rows, bp.WORDS_PER_SLICE), "uint32")
     )
@@ -55,6 +58,7 @@ def main(argv=None) -> int:
         "device": {"platform": dev.platform, "kind": dev.device_kind,
                    "count": jax.device_count()},
         "slices": args.slices, "rows": args.rows,
+        "kernel": kernel and dev.platform == "tpu",
         "bytes_per_answer": (args.rows + 1) * args.slices * bp.WORDS_PER_SLICE * 4,
         "groups": [],
     }
@@ -67,13 +71,13 @@ def main(argv=None) -> int:
         src_slots = np.full(g, 3, dtype=np.int32)
 
         t0 = time.monotonic()
-        bp._score_planes_self_src(chunks[0], slots, src_slots).block_until_ready()
+        score(chunks[0], slots, src_slots).block_until_ready()
         first_call_s = time.monotonic() - t0
 
         dispatch_ms, answer_ms = [], []
         for _ in range(args.repeats):
             t0 = time.monotonic()
-            outs = [bp._score_planes_self_src(c, slots, src_slots) for c in chunks]
+            outs = [score(c, slots, src_slots) for c in chunks]
             t1 = time.monotonic()
             jax.device_get(outs)
             t2 = time.monotonic()
