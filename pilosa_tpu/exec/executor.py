@@ -386,10 +386,12 @@ class Executor:
         # to, byte-identical by construction (exec/hosteval.py).
         self.hosteval = hosteval_mod.HostEvaluator(self)
         # Candidate rows a TopN scored on the host (the sparse tier's
-        # probes, the host fallback): published at 0, so that a reader
-        # tells "none" from "not counted".
+        # probes, the host fallback) and bytes of TopN src words made on
+        # the host (``_topn_src_host``): published at 0, so that a
+        # reader tells "none" from "not counted".
         if getattr(holder, "stats", None) is not None:
             holder.stats.count("topn.host_scored_rows", 0)
+            holder.stats.count("topn.srcHostBytes", 0)
         self._pool = _DaemonPool(
             max_workers=16, stats=getattr(holder, "stats", None)
         )
@@ -3609,7 +3611,7 @@ class Executor:
                 return None
             score = topn_stack.for_src_row(score, slots, leaf[2])
         device_mod.pool().touch_many(kept["pins"])
-        return {
+        ent = {
             "parts": kept["parts"],
             "union": len(kept["union"]),
             "build": "direct",
@@ -3619,6 +3621,9 @@ class Executor:
             "pins": kept["pins"],
             "kept_stack": kept,
         }
+        if kept["src"] is not None:
+            ent["src"] = "plane"
+        return ent
 
     def _topn_keep(self, kept_key, index, epoch, versions, per, parts, union,
                    score, stack, pins) -> dict:
@@ -3737,7 +3742,7 @@ class Executor:
         if has_src and topt.tanimoto_threshold > 0:
             # Tanimoto count-windows depend on the src count, so
             # re-derive candidates (and the union) with the real src.
-            src_rows = self._eval_tree_slices_host(
+            src_rows = self._topn_src_host(
                 index, c.children[0], [f.slice for f in frags]
             )
             per = []
@@ -3786,7 +3791,7 @@ class Executor:
             # Without tanimoto, candidate filtering never reads the
             # src — only the scorer does.  Attach it to the pass-1
             # options instead of re-walking every candidate list.
-            src_rows = self._eval_tree_slices_host(
+            src_rows = self._topn_src_host(
                 index, c.children[0], [per[i][0].slice for i in walk]
             )
         for i in walk:
@@ -3840,9 +3845,33 @@ class Executor:
             "stack": stack,
             "pins": pins,
         }
+        if has_src:
+            # where the scorer reads the src: a row of the ranked plane,
+            # or host words copied for a part that has no slot for it
+            host = any(p[4] is not None and p[6] is None for p in parts)
+            ent["src"] = "host" if host else "plane"
         if kept is not None:
             ent.update(stack_way="made", kept_stack=kept)
         return ent
+
+    def _topn_src_host(self, index: str, src: Call, slices: list[int]):
+        """The src tree's host words over ``slices``
+        (``_eval_tree_slices_host``), under a ``topn.src`` span: the
+        leaves, the slices evaluated, those the src reaches (not empty)
+        and the bytes of words made, which ``topn.srcHostBytes`` counts.
+        A slice where the tree is empty may come back as None."""
+        with self.tracer.span("topn.src") as sp:
+            rows = self._eval_tree_slices_host(index, src, slices)
+            words = [w for w in rows.values() if w is not None]
+            n_bytes = sum(int(w.nbytes) for w in words)
+            sp.annotate(
+                leaves=len(plan.decompose(self._rewrite_bsi(index, src))[1]),
+                slices=len(rows),
+                reached=sum(1 for w in words if w.any()),
+                bytes=n_bytes,
+            )
+        self.holder.stats.count("topn.srcHostBytes", n_bytes)
+        return rows
 
     def _topn_rows_entry(self, index: str, c: Call, view, frags, topt) -> dict | None:
         """The prep entry of a TopN(src) over a view that is ONE
@@ -3889,6 +3918,7 @@ class Executor:
             "parts": (),
             "union": kept,
             "build": "rows",
+            "src": "plane",
             "pins": (frag._pool_key, frag._rows_pool_key),
         }
 
@@ -3988,6 +4018,11 @@ class Executor:
             )
             if how == "built" and "build" in ent:
                 sp.annotate(build=ent["build"])
+                if "src" in ent:
+                    # ``src``: the scorer reads the src as a row of the
+                    # ranked plane (``plane``) or as host words copied
+                    # for the parts (``host``)
+                    sp.annotate(src=ent["src"])
                 if "stack_way" in ent:
                     # ``stack``: a direct build served from its view's
                     # kept stack (``kept``), or one that made it

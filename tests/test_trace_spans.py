@@ -414,10 +414,12 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
     prep = _span(first, "topn.prep")
     # every fragment ranks the union itself and holds the src in its
     # plane: no fragment walked, no host copy of the src (``build``), and
-    # the view's stack is kept for the next text (``stack``); the union
-    # is rows 1, 2 and the row that holds the far column
+    # the view's stack is kept for the next text (``stack``), the scorer
+    # reads the src from the plane (``src``); the union is rows 1, 2 and
+    # the row that holds the far column
     assert prep["tags"] == {"slices": slices, "prep_cache": "built", "union": 3,
-                            "build": "direct", "stack": "made"}
+                            "build": "direct", "src": "plane", "stack": "made"}
+    assert "topn.src" not in {s["name"] for s in first["spans"]}
     disp = _span(first, "topn.dispatch")
     launches = -(-slices // bp.SCORE_GROUP)
     assert disp["tags"]["launches"] == launches and disp["tags"]["groups"] == 1
@@ -451,13 +453,92 @@ def test_the_stages_of_a_served_topn_and_no_span_in_a_loop_over_slices(
     assert _span(other, "topn.score")["tags"]["score_cache"] == "computed"
     assert _tags(_span(other, "topn.prep")) == {
         "slices": slices, "prep_cache": "built", "union": 3, "build": "direct",
-        "stack": "kept"}
+        "src": "plane", "stack": "kept"}
     assert "compile" not in {s["name"] for s in other["spans"]}
     # a TopN(src) that is scored has these 13 spans whatever the slice count
     assert sorted(s["name"] for s in other["spans"]) == sorted([
         "query", "parse", "admission", "execute", "call.TopN", "topn.prep",
         "topn.score", "topn.dispatch", "topn.fetch", "launch", *HANDOFFS,
         "topn.select"])
+
+
+def _populate_rides(s, slices):
+    """Three frames that partition the columns, as a trip index's do: a
+    year a slice (2011 in the even slices, 2012 in the odd ones), a
+    passenger count and a rounded distance a column.  Passenger count 2
+    is held in every fourth slice alone."""
+    idx = s.holder.create_index_if_not_exists("i")
+    year, pc, dist = (idx.create_frame_if_not_exists(f)
+                      for f in ("year", "pc", "dist"))
+    for sl in range(slices):
+        cols = sl * SLICE_WIDTH + np.array([5, 9, 17, SLICE_WIDTH - 9])
+        year.import_bulk(np.full(4, 2011 + sl % 2), cols)
+        pc.import_bulk(np.array([1, 1, 2 if sl % 4 == 0 else 1, 3]), cols)
+        dist.import_bulk(np.array([0, 2, 2, 7]), cols)
+    for f in ("year", "pc", "dist"):
+        for frag in s.holder.view("i", f, "standard").fragments():
+            frag.device_plane()  # resident, as on a node that has served
+
+
+YEAR = "Bitmap(frame=year, rowID=2011)"
+YEAR_PC = f"Intersect({YEAR}, Bitmap(frame=pc, rowID=2))"
+
+
+@pytest.mark.parametrize("slices", [2, 70])
+def test_a_walked_topn_evaluates_its_src_once_under_one_span(
+    one_chip, server, slices, monkeypatch
+):
+    """A src from other frames than the ranked one is evaluated on the
+    host for every slice: one ``topn.src`` under ``topn.prep`` with its
+    leaves, the slices evaluated, those it reaches and the bytes of
+    words made, which ``topn.srcHostBytes`` counts; ``topn.prep`` says
+    the scorer reads it from host words.  As many spans at 70 slices as
+    at 2; a src that is a row of the ranked plane has no such span."""
+    _populate_rides(server, slices)
+    monkeypatch.setattr(cache_mod, "RECALCULATE_INTERVAL_S", 600.0)
+    c = InternalClient(server.host, timeout=120.0)
+    counts = server.holder.stats.snapshot()["counts"]
+    assert counts["topn.srcHostBytes"] == 0  # published before any TopN
+
+    def topn(src):
+        got = [(p.id, p.count) for p in c.execute_pql(
+            "i", f"TopN({src}, frame=dist, n=10)")]
+        return got, _last_trace(c)
+
+    even, fourth = (slices + 1) // 2, (slices + 3) // 4
+    for src, leaves, reached, want in (
+        (YEAR, 1, even, [(2, 2 * even), (0, even), (7, even)]),
+        (YEAR_PC, 2, fourth, [(2, fourth)]),
+    ):
+        before = server.holder.stats.snapshot()["counts"]["topn.srcHostBytes"]
+        got, t = topn(src)
+        assert got == want
+        prep = _span(t, "topn.prep")
+        assert prep["tags"]["build"] == "walked" and prep["tags"]["src"] == "host"
+        (sp,) = [s for s in t["spans"] if s["name"] == "topn.src"]
+        assert sp["parent_id"] == prep["span_id"]
+        tags = _tags(sp)
+        # a slice where the tree is empty makes no words
+        assert tags == {"leaves": leaves, "slices": slices, "reached": reached,
+                        "bytes": reached * bp.WORDS_PER_SLICE * 4}
+        grew = server.holder.stats.snapshot()["counts"]["topn.srcHostBytes"] - before
+        assert grew == tags["bytes"]
+        # a scored TopN(src) of the walked build: 14 spans whatever the
+        # slice count
+        assert sorted(s["name"] for s in t["spans"] if s["name"] != "compile") == sorted([
+            "query", "parse", "admission", "execute", "call.TopN", "topn.prep",
+            "topn.src", "topn.score", "topn.dispatch", "topn.fetch", "launch",
+            *HANDOFFS, "topn.select"])
+
+    # a view that is ONE fragment, src a row of it: the rows build reads
+    # the src in place
+    _populate(server, 1)
+    assert [(p.id, p.count) for p in c.execute_pql(
+        "i", 'TopN(Bitmap(frame="f", rowID=1), frame="f", n=10)')] == [(1, 2), (2, 1)]
+    t = _last_trace(c)
+    assert _span(t, "topn.prep")["tags"]["build"] == "rows"
+    assert _span(t, "topn.prep")["tags"]["src"] == "plane"
+    assert "topn.src" not in {s["name"] for s in t["spans"]}
 
 
 SUM_FILTERED = 'Sum(Intersect(Bitmap(frame="f", rowID=1), Range(frame="v", q >< [2, 6])), frame="v", field="q")'
